@@ -18,9 +18,9 @@
   lm_throughput    framework health: LM train/decode throughput (smoke)
 
 Prints ``name,us_per_call,derived`` CSV rows (derived = GFLOPS under the
-paper's Eq. (2) op-count model where applicable).  Wall times are CPU
-(this container); the TPU-target numbers live in EXPERIMENTS.md
-section Roofline, derived from the compiled dry-run.
+paper's Eq. (2) op-count model where applicable).  Wall times are those
+of the backend it runs on -- the CPU, in the CI ladders -- and are not
+chip numbers; ``chip_smoke.py`` at the repo root is the run on a TPU.
 """
 from __future__ import annotations
 
@@ -35,6 +35,7 @@ sys.path.insert(0, "src")
 
 from repro.cfd import operators, reference  # noqa: E402
 from repro.cfd.simulation import SimConfig, run_simulation  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.precision import POLICIES, enable_x64  # noqa: E402
 
 
@@ -304,8 +305,8 @@ def _sharded_worker(p: int, E: int, n_b: int) -> None:
     under a 2-device placement (gradient stage element-sharded over both
     devices, handoffs resharded between groups) and prints one JSON line
     with the measurement.  Launched with
-    ``--xla_force_host_platform_device_count=2`` by the parent ladder --
-    the only way to exercise multi-device execution on a CPU container.
+    ``--xla_force_host_platform_device_count=2`` and ``JAX_PLATFORMS=cpu``
+    by the parent ladder: two virtual CPU devices, never the TPU.
     """
     import json
 
@@ -359,7 +360,8 @@ def _hetero_worker(p: int, E: int, n_b: int) -> None:
     stage 0 placed on the host group at half the chain E so the 0->1
     handoff crosses both an E change and a kind change and exercises the
     re-blocking path.  Both devices are really CPU host devices (forced
-    by the parent), so the rung tracks the re-block machinery's wall
+    by the parent, which also sets ``JAX_PLATFORMS=cpu`` so the child
+    never reaches for the TPU), so the rung tracks the re-block machinery's wall
     cost, not a speedup -- and the declared-kind pricing is meaningless
     on this silicon, so no prediction fields are reported.
     """
@@ -415,13 +417,16 @@ def _hetero_worker(p: int, E: int, n_b: int) -> None:
 def _run_sharded_rung(p: int, E: int, n_b: int,
                       worker: str = "_sharded_worker") -> dict:
     """Launch a forced-2-host-device worker subprocess (the only way to
-    exercise multi-device placement on a CPU container)."""
+    exercise multi-device placement without several chips).  The child
+    runs with ``JAX_PLATFORMS=cpu``: this process has already touched
+    JAX and may hold the TPU, so the child must never reach for it."""
     import json
     import os
     import subprocess
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run(
         [sys.executable, __file__, worker, str(p), str(E), str(n_b)],
@@ -690,16 +695,6 @@ def flow_ladder() -> None:
     measure("chain_auto_fused", fused.chain, fused.plan, E=E, n_b=n_b)
     rows[-1].update({"max_ratio_vs": "hand_stage_cuts", "max_ratio": 1.2})
 
-    # the same fused pipeline dispatched to the tiled GEMM-chain Pallas
-    # kernel class (on this CPU container the class's XLA reference path
-    # runs; the kernel itself is gated by interpret-mode unit tests)
-    tiled = flow.compile(
-        source, name=f"cfd_pipeline_p{p}", target=target,
-        batch_elements=E, prefetch_depth=1, n_eq=n_eq, fuse="auto",
-        max_stages=3, backend="pallas",
-    )
-    measure("gemm_tiled", tiled.chain, tiled.plan, E=E, n_b=n_b)
-
     # the stage-pipelining acceptance ladder: small batches on the
     # 3-stage chain so per-batch dispatch/sync latency -- exactly what
     # staging and the skewed dispatch rings hide -- dominates.  Three
@@ -846,6 +841,7 @@ def main() -> None:
             int(sys.argv[2]), int(sys.argv[3]), int(sys.argv[4])
         )
         return
+    enable_compile_cache()
     names = sys.argv[1:] or list(BENCHES)
     print("name,us_per_call,derived")
     for n in names:

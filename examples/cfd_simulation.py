@@ -16,12 +16,14 @@ sys.path.insert(0, "src")
 import jax  # noqa: E402
 
 from repro import flow  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.cfd import reference  # noqa: E402
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--program",
                     default=os.path.join(_HERE, "cfd_pipeline.cfd"),

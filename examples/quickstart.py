@@ -12,8 +12,10 @@ sys.path.insert(0, "src")
 import numpy as np  # noqa: E402
 
 from repro.cfd import reference  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import api, dsl, rewrite, schedule  # noqa: E402
 
+enable_compile_cache()
 P = 11
 SRC = dsl.INVERSE_HELMHOLTZ_SRC.format(p=P)
 

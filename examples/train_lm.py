@@ -16,6 +16,7 @@ sys.path.insert(0, "src")
 import jax  # noqa: E402
 
 from repro import configs  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.checkpoint import CheckpointManager  # noqa: E402
 from repro.data import PrefetchPipeline, TokenStream  # noqa: E402
 from repro.models import build_model  # noqa: E402
@@ -36,6 +37,7 @@ def profile_100m() -> ModelConfig:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

@@ -18,29 +18,19 @@ from ..core import api, dsl
 from ..core.emit import CompiledProgram
 from ..core.precision import POLICIES
 from ..kernels.helmholtz import ops as helmholtz_ops
+from ..memory import layout
 from ..memory.chain import ChainPlan, ProgramChain
 from ..memory.plan import MemoryPlan
 
 
-def pallas_block_elements(
-    p: int,
-    plan: Optional[MemoryPlan] = None,
-    *,
-    vmem_bytes: Optional[int] = None,
-    bytes_per_scalar: int = 4,
-) -> int:
+def pallas_block_elements(plan: Optional[MemoryPlan] = None) -> int:
     """Resolve the Pallas kernel's block size from a MemoryPlan.
 
-    The plan already carries the VMEM-budgeted block (``block_elements``,
-    a divisor of its E); without one, the block is derived directly from
-    the given VMEM capacity, and with neither the kernel default stands.
+    The plan carries the VMEM-budgeted block (``block_elements``, a
+    divisor of its E); without one the kernel default stands.
     """
     if plan is not None and plan.block_elements:
         return plan.block_elements
-    if vmem_bytes is not None:
-        return helmholtz_ops.block_elements_for_vmem(
-            p, vmem_bytes, bytes_per_scalar=bytes_per_scalar
-        )
     return helmholtz_ops.DEFAULT_BLOCK_ELEMENTS
 
 
@@ -69,9 +59,15 @@ def build_inverse_helmholtz(
     if backend == "pallas":
         be = (
             block_elements if block_elements is not None
-            else pallas_block_elements(p, plan)
+            else pallas_block_elements(plan)
         )
-        pallas_impl = helmholtz_ops.make_pallas_impl(block_elements=be)
+        pallas_impl = helmholtz_ops.make_pallas_impl(
+            block_elements=be,
+            vmem_limit_bytes=(
+                layout.vmem_budget_bytes(plan.target) if plan is not None
+                else None
+            ),
+        )
     return api.compile_cfdlang(
         dsl.INVERSE_HELMHOLTZ_SRC.format(p=p),
         element_vars=("u", "D", "v"),

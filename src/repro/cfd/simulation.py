@@ -284,6 +284,66 @@ def _chain_batch_inputs(
             }
 
 
+def chain_stage_fns(
+    chain: memchain.ProgramChain,
+    shared_for_stage: List[Dict[str, jax.Array]],
+    out_names: List[str],
+    *,
+    stage_es: Optional[List[int]] = None,
+    batch_elements: int = 0,
+) -> List:
+    """One ring callable per stage, ``(staged, carry) -> carry``.
+
+    Stage i reads its bound inputs from the carry (HBM-resident
+    handoffs), its shared operands from ``shared_for_stage[i]`` and its
+    host streams from ``staged``.  Afterwards the batch keeps only what
+    a later stage or the host still reads: a host stream leaves
+    ``staged`` once its stage has read it, and a handoff leaves the
+    carry after its last consumer -- otherwise every batch in the
+    skewed ring would hold every stream of the chain until it retires.
+    ``stage_es`` gives stages that run at a smaller E than the chain's
+    ``batch_elements`` (re-blocked on device).
+    """
+    n = len(chain.stages)
+    keep_after = []
+    for i in range(n):
+        keep = set(out_names)
+        for j in range(i + 1, n):
+            keep.update(f"{chain.stages[p].name}.{o}"
+                        for p, o in chain.resolved[j].values())
+        keep_after.append(keep)
+
+    def make_stage_fn(i: int, s: memchain.ChainStage):
+        batched_fn = s.compiled.batched_fn
+        if stage_es and 0 < stage_es[i] < batch_elements:
+            batched_fn = mempipe.reblock_batched_fn(
+                batched_fn, tuple(s.program.element_vars), stage_es[i]
+            )
+        keep = keep_after[i]
+
+        def run_stage(staged, carry):
+            live: Dict[str, jax.Array] = dict(carry) if carry else {}
+            env: Dict[str, jax.Array] = {}
+            for name in s.program.inputs:
+                if name in chain.resolved[i]:
+                    p_idx, out_name = chain.resolved[i][name]
+                    env[name] = live[
+                        f"{chain.stages[p_idx].name}.{out_name}"
+                    ]
+                elif name in shared_for_stage[i]:
+                    env[name] = shared_for_stage[i][name]
+                else:
+                    env[name] = staged.pop(f"{s.name}.{name}")
+            outs = batched_fn(env)
+            for out_name, val in outs.items():
+                live[f"{s.name}.{out_name}"] = val
+            return {q: v for q, v in live.items() if q in keep}
+
+        return run_stage
+
+    return [make_stage_fn(i, s) for i, s in enumerate(chain.stages)]
+
+
 def run_chain(
     chain: memchain.ProgramChain,
     plan: Optional[memchain.ChainPlan] = None,
@@ -506,38 +566,8 @@ def run_chain(
     ]
     if len(stage_es) != len(chain.stages):
         stage_es = [E] * len(chain.stages)
-
-    def make_stage_fn(i: int, s: memchain.ChainStage):
-        batched_fn = s.compiled.batched_fn
-        e_s = stage_es[i]
-        if 0 < e_s < E:
-            batched_fn = mempipe.reblock_batched_fn(
-                batched_fn, tuple(s.program.element_vars), e_s
-            )
-
-        def run_stage(staged, carry):
-            live: Dict[str, jax.Array] = dict(carry) if carry else {}
-            env: Dict[str, jax.Array] = {}
-            for name in s.program.inputs:
-                if name in chain.resolved[i]:
-                    p_idx, out_name = chain.resolved[i][name]
-                    env[name] = live[
-                        f"{chain.stages[p_idx].name}.{out_name}"
-                    ]
-                elif name in shared_for_stage[i]:
-                    env[name] = shared_for_stage[i][name]
-                else:
-                    env[name] = staged[f"{s.name}.{name}"]
-            outs = batched_fn(env)
-            for out_name, val in outs.items():
-                live[f"{s.name}.{out_name}"] = val
-            return live
-
-        return run_stage
-
-    stage_fns = [
-        make_stage_fn(i, s) for i, s in enumerate(chain.stages)
-    ]
+    stage_fns = chain_stage_fns(chain, shared_for_stage, out_names,
+                                stage_es=stage_es, batch_elements=E)
 
     # multi-group handoff: before stage i consumes a batch, reshard the
     # HBM-resident streams it reads from producers on *other* groups

@@ -10,9 +10,9 @@ the TPU program.  Three backends, mirroring the paper's design space:
     sequence with materialized intermediates.  This models the FIFO-
     streamed dataflow CU and is what the per-stage analysis/benchmarks
     inspect (paper's Dataflow 1/2/3/7-compute experiments).
-  * ``pallas``  -- groups whose pattern matches a hand-tiled kernel are
-    dispatched to it (the fused Inverse-Helmholtz CU); everything else
-    falls back to ``xla``.
+  * ``pallas``  -- the whole program as one hand-tiled kernel
+    (``pallas_impl``, found by ``flow.patterns``); ``flow.compile``
+    compiles a stage no kernel matches with ``xla`` and warns.
 
 Batching over the implicit element loop is vmap over axis 0 of the
 element-marked inputs/outputs; sharding the element axis over the mesh is
@@ -58,7 +58,7 @@ def einsum_spec(node: ir.Einsum) -> str:
 
 def _eval_einsum_float(node: ir.Einsum, args: Sequence[jax.Array], policy: FloatPolicy):
     spec = einsum_spec(node)
-    kwargs = {}
+    kwargs = {"precision": policy.precision}
     if policy.accum_dtype is not None:
         kwargs["preferred_element_type"] = jnp.dtype(policy.accum_dtype)
     out = jnp.einsum(spec, *args, **kwargs)

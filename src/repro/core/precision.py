@@ -21,10 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # newer jax exposes the x64 context manager at top level
-    enable_x64 = jax.enable_x64
-except AttributeError:
-    from jax.experimental import enable_x64
+enable_x64 = jax.enable_x64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +42,16 @@ class FloatPolicy:
     @property
     def bits(self) -> int:
         return jnp.dtype(self.dtype).itemsize * 8
+
+    @property
+    def precision(self) -> jax.lax.Precision:
+        """Matmul precision that keeps the policy's word: a TPU runs a
+        float32 matmul at DEFAULT precision as one bfloat16 pass, so
+        float32 and float64 ask for HIGHEST (full float32 products);
+        bfloat16 is the one pass it names."""
+        if self.dtype == "bfloat16":
+            return jax.lax.Precision.DEFAULT
+        return jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)

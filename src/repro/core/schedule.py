@@ -14,8 +14,7 @@ The paper's heuristic, ported to the TPU cost model:
 On TPU the "streams" between groups are HBM round-trips (group boundary =
 materialized intermediate), while everything inside one group stays in
 VMEM of a single fused kernel.  So the schedule directly controls the
-memory-roofline term; the perf loop (EXPERIMENTS.md section Perf) iterates
-on this structure.
+memory-roofline term, which ``memory.chain`` prices per stage.
 """
 from __future__ import annotations
 

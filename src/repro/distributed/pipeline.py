@@ -18,31 +18,11 @@ from __future__ import annotations
 import functools
 from typing import Any, Callable
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 
-try:  # jax <= 0.4.x ships shard_map under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # newer jax promoted it to the top level
-    from jax import shard_map as _shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-#: replication-check kwarg was renamed check_rep -> check_vma
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, *args, check_vma=None, **kwargs):
-    """Version-portable ``shard_map``: forwards positionals untouched and
-    renames the replication-check kwarg to whatever this jax expects."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, *args, **kwargs)
 
 
 def pipeline_forward(

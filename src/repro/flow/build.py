@@ -27,6 +27,7 @@ executes the artifact through the K-deep chain pipeline driver.
 from __future__ import annotations
 
 import dataclasses
+import warnings
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 from ..core import dsl, emit, ir, liveness, rewrite
@@ -418,19 +419,32 @@ def _compile_stages(
     policy,
     backends: Sequence[str],
     stage_blocks: Mapping[str, int],
-) -> Tuple[List[ChainStage], Tuple[str, ...]]:
+    target: channels.MemoryTarget,
+) -> Tuple[List[ChainStage], Tuple[str, ...], Dict[str, Optional[int]]]:
     """Compile every stage program; ``pallas`` stages are structurally
-    matched against hand-tiled kernels and fall back to ``xla`` when no
-    kernel fits.  Returns the chain stages + effective backends."""
+    matched against hand-tiled kernels, which are compiled with the
+    target's VMEM budget (the one their blocks were sized against).  A
+    ``pallas`` stage that no kernel matches is compiled with ``xla``
+    under a RuntimeWarning that names it -- never silently.  Returns the
+    chain stages, the effective backends and the block each Pallas stage
+    was compiled at."""
     chain_stages: List[ChainStage] = []
     effective: List[str] = []
+    blocks: Dict[str, Optional[int]] = {}
     for st, backend in zip(stages, backends):
         pallas_impl = None
         if backend == "pallas":
+            blocks[st.name] = stage_blocks.get(st.name)
             pallas_impl = patterns.pallas_impl_for(
-                st.program, block_elements=stage_blocks.get(st.name)
+                st.program, block_elements=blocks[st.name],
+                vmem_limit_bytes=layout.vmem_budget_bytes(target),
             )
             if pallas_impl is None:
+                warnings.warn(
+                    f"flow: no Pallas kernel matches stage {st.name!r}; "
+                    "it is compiled with xla",
+                    RuntimeWarning, stacklevel=3,
+                )
                 backend = "xla"
         compiled = emit.compile_program(
             st.program, policy=policy, backend=backend,
@@ -438,7 +452,7 @@ def _compile_stages(
         )
         chain_stages.append(ChainStage(st.name, compiled, dict(st.bindings)))
         effective.append(backend)
-    return chain_stages, tuple(effective)
+    return chain_stages, tuple(effective), blocks
 
 
 def _tune_stage_blocks(
@@ -481,10 +495,8 @@ def _tune_stage_blocks(
         if recipe is not None:
             cands = [
                 c.block_elements for c in gemm.tile_candidates(
-                    recipe, vmem_bytes=target.vmem_bytes,
-                    peak_flops=target.peak_flops,
-                    hbm_bandwidth=target.hbm_bw,
-                    bytes_per_scalar=bps, batch_elements=e,
+                    recipe, target, bytes_per_scalar=bps,
+                    batch_elements=e,
                 )
             ]
         else:
@@ -514,7 +526,8 @@ def _tune_stage_blocks(
         best = None
         for be in cands:
             impl = patterns.pallas_impl_for(
-                st.program, block_elements=be
+                st.program, block_elements=be,
+                vmem_limit_bytes=layout.vmem_budget_bytes(target),
             )
             if impl is None:
                 break
@@ -729,8 +742,8 @@ def compile(
         policy: Numeric precision policy name (or policy object).
         backend: Backend for every stage unless ``backends`` is given.
         backends: Per-stage backend overrides; ``pallas`` stages are
-            structurally matched to hand-tiled kernels and fall back to
-            ``xla`` when nothing fits.
+            structurally matched to hand-tiled kernels and compiled with
+            ``xla``, under a RuntimeWarning, when nothing fits.
         stage_blocks: Per-stage VMEM ``block_elements`` pins for Pallas
             kernels (e.g. from a prior plan).
         optimize: Run the middle-end rewrites (factorize/CSE) first.
@@ -837,8 +850,8 @@ def compile(
             f"got {len(backends)}"
         )
     stage_blocks = dict(stage_blocks or {})
-    chain_stages, effective = _compile_stages(
-        stage_specs, pol, backends, stage_blocks
+    chain_stages, effective, compiled_blocks = _compile_stages(
+        stage_specs, pol, backends, stage_blocks, target
     )
     chain = ProgramChain(chain_stages)
 
@@ -914,10 +927,10 @@ def compile(
                 stage_specs, streams = _extract_stages(prog, parts, bps)
                 prefetch_depth = _collapse(prefetch_depth, groups_idx)
                 cu_count = _collapse(cu_count, groups_idx)
-                chain_stages, effective = _compile_stages(
+                chain_stages, effective, compiled_blocks = _compile_stages(
                     stage_specs, pol,
                     _collapse_backends(list(backends), groups_idx),
-                    stage_blocks,
+                    stage_blocks, target,
                 )
                 chain = ProgramChain(chain_stages)
                 plan = plan_chain(
@@ -962,8 +975,8 @@ def compile(
                 for sp in plan.stages:
                     if sp.block_elements:
                         blocks.setdefault(sp.name, sp.block_elements)
-                chain_stages, effective = _compile_stages(
-                    stage_specs, won_pol, won, blocks
+                chain_stages, effective, compiled_blocks = _compile_stages(
+                    stage_specs, won_pol, won, blocks, target
                 )
                 chain = ProgramChain(chain_stages)
                 pol = won_pol
@@ -994,8 +1007,8 @@ def compile(
         if stale:
             blocks = dict(stage_blocks)
             blocks.update(winners)
-            chain_stages, effective = _compile_stages(
-                stage_specs, pol, effective, blocks
+            chain_stages, effective, compiled_blocks = _compile_stages(
+                stage_specs, pol, effective, blocks, target
             )
             chain = ProgramChain(chain_stages)
             plan = dataclasses.replace(plan, stages=tuple(
@@ -1003,11 +1016,25 @@ def compile(
                     sp,
                     block_elements=stale[sp.name],
                     block_working_set_bytes=layout.block_working_set_bytes(
-                        st.program, stale[sp.name], bytes_per_scalar=bps
+                        st.program, stale[sp.name], target,
+                        bytes_per_scalar=bps,
                     ),
                 ) if sp.name in stale else sp
                 for sp, st in zip(plan.stages, stage_specs)
             ))
+
+    # Pallas kernels run at the plan's VMEM-budgeted block (a pinned
+    # stage_blocks entry wins): rebuild stages compiled at another one
+    want = {
+        sp.name: stage_blocks.get(sp.name, sp.block_elements or None)
+        for sp in plan.stages
+    }
+    if any(compiled_blocks.get(name, want[name]) != want[name]
+           for name in compiled_blocks):
+        chain_stages, effective, compiled_blocks = _compile_stages(
+            stage_specs, pol, effective, want, target
+        )
+        chain = ProgramChain(chain_stages)
 
     if fusion_spec is not None:
         plan = dataclasses.replace(

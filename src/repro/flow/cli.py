@@ -13,6 +13,7 @@ import argparse
 import sys
 from typing import Optional, Sequence
 
+from ..compile_cache import enable_compile_cache
 from ..core.dsl import ParseError
 from ..core.ir import IRError
 from . import build
@@ -44,8 +45,9 @@ def _parse_args(argv: Optional[Sequence[str]]) -> argparse.Namespace:
                     " default: detect)")
     ap.add_argument("--policy", default="float32")
     ap.add_argument("--backend", default="xla",
-                    help="stage backend: xla | staged | pallas "
-                    "(pallas falls back to xla when no kernel matches)")
+                    help="stage backend: xla | staged | pallas (a pallas "
+                    "stage no kernel matches is compiled with xla, with a "
+                    "warning)")
     ap.add_argument("--backends", default=None,
                     help="comma-separated per-stage backends")
     ap.add_argument("--element-vars", default="",
@@ -135,6 +137,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     """CLI driver: compile/plan, then --dse/--run/--trace/--metrics as
     requested.  Exit 0 ok, 1 flow failure, 2 usage error."""
     args = _parse_args(argv)
+    enable_compile_cache()
     try:
         if args.source == "-":
             source = sys.stdin.read()

@@ -5,8 +5,9 @@ kernels (the paper's "Optimize" step picking a specialized CU).
 ``pallas_impl``; this module supplies it by *structural* matching -- a
 stage program whose IR is isomorphic to a known kernel's program (same
 einsum/ewise graph, same shapes, any input names) is dispatched to that
-kernel, with the stage's actual input/output names adapted.  Unmatched
-stages fall back to ``xla``, exactly as emit's docstring promises.
+kernel, with the stage's actual input/output names adapted.  For an
+unmatched stage this module returns None, and ``flow.compile`` compiles
+it with ``xla`` under a warning that names the stage.
 
 Matching is name-insensitive: the flow's stage extraction renames
 streams (the Fig. 2 ``u`` arrives as ``gx`` inside the CFD pipeline), so
@@ -107,8 +108,7 @@ def match_gemm_chain(
     and gradient stages, every schedule-derived single-contraction
     stage, and the stages the fusion pass merges.  Returns the kernel's
     :class:`~repro.kernels.gemm.GemmRecipe` (slots in topological
-    order), or None when any node falls outside the class (the stage
-    then falls back to ``xla``).
+    order), or None when any node falls outside the class.
     """
     elem_dep = prog.element_dependent_uids()
     input_name = {v.uid: k for k, v in prog.inputs.items()}
@@ -183,7 +183,9 @@ def match_gemm_chain(
             if n.op not in gemm_kernels.EWISE_OPS or n.uid not in elem_dep:
                 return None
             operands = n.operands()
-            if any(o.uid not in slots for o in operands):
+            # shared inputs live in SMEM as contraction weights only
+            if any(o.uid not in slots or o.uid not in elem_dep
+                   for o in operands):
                 return None
             rhs = slots[operands[1].uid] if len(operands) > 1 else -1
             ops.append((
@@ -208,9 +210,11 @@ def pallas_impl_for(
     prog: ir.Program,
     *,
     block_elements: Optional[int] = None,
+    vmem_limit_bytes: Optional[int] = None,
 ) -> Optional[Callable]:
     """A batched ``pallas_impl`` for ``core.emit.compile_program``, or
-    None when no hand-tiled kernel matches the program.
+    None when no hand-tiled kernel matches the program.  The kernel is
+    compiled with ``vmem_limit_bytes`` (``kernels.tiling.compiler_params``).
 
     Dispatch order: the hand-fused Inverse-Helmholtz kernel first (its
     Mnemosyne-style scratch sharing is tighter than the generic chain),
@@ -224,7 +228,8 @@ def pallas_impl_for(
             block_elements=(
                 block_elements if block_elements
                 else helmholtz_ops.DEFAULT_BLOCK_ELEMENTS
-            )
+            ),
+            vmem_limit_bytes=vmem_limit_bytes,
         )
 
         def impl(env):
@@ -246,4 +251,5 @@ def pallas_impl_for(
             block_elements if block_elements
             else gemm_kernels.DEFAULT_BLOCK_ELEMENTS
         ),
+        vmem_limit_bytes=vmem_limit_bytes,
     )

@@ -5,8 +5,8 @@ enumerates *large* tile configurations that maximize steady-state
 throughput for big operands, CDAC keeps *small* dedicated accelerators
 whose latency (fill cost) stays low for small operands.  The TPU analog
 of a tile configuration is the kernel's ``block_elements``: big blocks
-amortize dispatch overhead and fill the MXU minor dimension, small
-blocks keep the VMEM working set (and the per-dispatch latency) low.
+amortize the grid's per-step overhead, small blocks keep the VMEM
+working set (and the per-dispatch latency) low.
 
 ``tile_candidates`` enumerates power-of-two blocks, filters them by the
 plan's VMEM budget (the resource constraint), splits them into the two
@@ -17,8 +17,11 @@ before depositing the measured winner in the profile store.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Optional
 
+from ...memory import layout
+from ...memory.channels import MemoryTarget
 from .gemm import GemmRecipe
 from .ops import block_working_set_bytes
 
@@ -45,40 +48,37 @@ class TileCandidate:
 
 def tile_candidates(
     recipe: GemmRecipe,
+    target: MemoryTarget,
     *,
-    vmem_bytes: int,
-    peak_flops: float,
-    hbm_bandwidth: float,
     bytes_per_scalar: int = 4,
     overhead_s: float = DEFAULT_OVERHEAD_S,
-    reserve_fraction: float = 0.5,
     max_block: int = 2048,
     batch_elements: Optional[int] = None,
 ) -> List[TileCandidate]:
     """Enumerate, filter, and throughput-rank block-size candidates.
 
     Power-of-two blocks up to ``max_block`` are kept when their VMEM
-    working set fits ``vmem_bytes * reserve_fraction`` (the other half
-    is the grid pipeline's DMA double buffer) and, when
-    ``batch_elements`` is given, when they divide the batch (the Pallas
-    grid requires it).  Each survivor is classed large ("cdse") or small
-    ("cdac") by working-set fraction and ranked by modeled throughput:
-    ``be / (overhead + flops/peak + io_bytes/bw)``.  Returns candidates
-    sorted best-first; empty when even a 1-element block exceeds VMEM.
+    working set on ``target`` fits its ``layout.vmem_budget_bytes`` (the
+    budget the kernel is compiled with) and, when ``batch_elements`` is
+    given, when they divide the batch (the Pallas grid requires it).
+    Each survivor is classed large ("cdse") or small ("cdac") by
+    working-set fraction and ranked by modeled throughput on the
+    target's roofline: ``be / (overhead + flops/peak + io_bytes/bw)``.
+    Returns candidates sorted best-first; empty when even a 1-element
+    block exceeds VMEM.
     """
-    budget = int(vmem_bytes * reserve_fraction)
+    budget = layout.vmem_budget_bytes(target)
     flops = recipe.flops_per_element()
     out_slots = {slot for _, slot in recipe.outputs}
-    import math as _math
     io_scalars = sum(
-        _math.prod(shape) for _, shape, is_elem in recipe.inputs if is_elem
-    ) + sum(_math.prod(recipe.slot_shape(s)) for s in out_slots)
+        math.prod(shape) for _, shape, is_elem in recipe.inputs if is_elem
+    ) + sum(math.prod(recipe.slot_shape(s)) for s in out_slots)
 
     out: List[TileCandidate] = []
     be = 1
     while be <= max_block:
         ws = block_working_set_bytes(
-            recipe, be, bytes_per_scalar=bytes_per_scalar
+            recipe, be, target, bytes_per_scalar=bytes_per_scalar
         )
         divides = batch_elements is None or batch_elements % be == 0
         fits = ws <= budget and (
@@ -87,8 +87,8 @@ def tile_candidates(
         if fits and divides:
             t = (
                 overhead_s
-                + be * flops / peak_flops
-                + be * io_scalars * bytes_per_scalar / hbm_bandwidth
+                + be * flops / target.peak_flops
+                + be * io_scalars * bytes_per_scalar / target.hbm_bw
             )
             out.append(TileCandidate(
                 klass=(

@@ -2,39 +2,39 @@
 made of shared-matrix mode contractions and elementwise ops.
 
 The Helmholtz kernel hand-fuses one fixed 7-stage dataflow.  This module
-generalizes the same tiling idiom -- a block of BE elements flows through
-the whole op chain inside VMEM, every mode contraction is one
-``(p x p) x (p x BE*p^(r-1))`` MXU GEMM -- to *any* recipe extracted from
-a stage program by ``flow.patterns.match_gemm_chain``:
+generalizes the same idiom -- a block of elements flows through the
+whole op chain inside VMEM, laid out element-minor as
+``(p, ..., p, rows, 128)`` (``kernels.tiling``) -- to *any* recipe
+extracted from a stage program by ``flow.patterns.match_gemm_chain``:
 
   * **contract**: ``y[.., a at mode m, ..] = sum_l M[l, a] * x[.., l, ..]``
-    realized by rotating mode ``m`` to the front, packing the remaining
-    axes (element axis included) into the GEMM minor dimension, and
-    rotating back -- so index order is restored exactly and recipes
-    compose without bookkeeping.
+    as p^2 scalar-weighted sums of whole ``(rows, 128)`` slabs, with
+    ``M`` in SMEM; the recipe's ``perm`` re-stacks leading axes, so no
+    value ever changes its tiled minor dimensions.
   * **ewise**: add/sub/mul/div between element values, plus unary
     neg/scale -- the Hadamard steps of the CFD chain.
 
 One recipe covers the interpolation stage (3 contractions), the gradient
 stage (3 outputs sharing an input), any single schedule-derived stage,
 and the fully fused pipeline -- which is exactly what the cost-driven
-stage fusion pass needs: fused stages re-match to this kernel class
-instead of falling back to XLA.
+stage fusion pass needs: fused stages re-match to this kernel class.
 
-Grid: ``(E // BE,)``.  Shared matrices are pinned to block 0 (Mosaic
-keeps them VMEM-resident); element tensors stream one block per step
-with the grid pipeline double-buffering the HBM DMA.
+Grid: one step per block of element rows.  Shared matrices stay in
+SMEM for the whole grid; element tensors stream one block per step with
+the grid pipeline double-buffering the HBM DMA.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import tiling
 
 
 DEFAULT_BLOCK_ELEMENTS = 128
@@ -96,107 +96,129 @@ class GemmRecipe:
         return total
 
 
+def _ewise(eop: str, a, b, const):
+    if eop == "add":
+        return a + b
+    if eop == "sub":
+        return a - b
+    if eop == "mul":
+        return a * b
+    if eop == "div":
+        return a / b
+    if eop == "neg":
+        return -a
+    if eop == "scale":
+        return a * const
+    raise ValueError(f"unknown ewise op {eop!r}")  # matcher emits EWISE_OPS
+
+
 def apply_recipe(recipe: GemmRecipe, vals, *, f32=jnp.float32):
-    """Run the op chain over loaded values (index 0 is the batch/block
-    axis of element values).  Shared by the Pallas kernel body and the
-    XLA reference path -- a block of BE elements and a full batch of E
-    elements have the same layout, so the code is identical."""
-    p = recipe.p
+    """Run the op chain over whole-batch values (axis 0 is the element
+    axis) with plain einsums: the XLA reference the kernel is checked
+    against.  Contractions run at full float32 precision."""
     vals = list(vals)
+    letters = "bcdefghijkmnopqrstuvwxyz"      # 'a' is new, 'l' the batch
     for op in recipe.ops:
         if op[0] == "contract":
             _, src, mat, mode, mat_dim, perm = op
-            x, m = vals[src], vals[mat]
-            mc = m if mat_dim == 0 else m.T
-            ax = mode + 1                       # skip the batch/block axis
-            xt = jnp.moveaxis(x, ax, 0)         # (p_l, BE, p, ...)
-            xm = xt.reshape(p, -1)              # (p_l, BE * p^(r-1))
-            ym = jax.lax.dot_general(
-                mc, xm, (((0,), (0,)), ((), ())),
+            x = vals[src]
+            r = x.ndim - 1
+            ax = letters[:r]
+            new = "a"
+            m_sub = (ax[mode] + new) if mat_dim == 0 else (new + ax[mode])
+            in_place = ax[:mode] + new + ax[mode + 1:]
+            out = "".join(in_place[q] for q in perm)
+            vals.append(jnp.einsum(
+                f"{m_sub},l{ax}->l{out}", vals[mat], x,
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=f32,
-            )
-            y = jnp.moveaxis(ym.reshape(xt.shape), 0, ax)
-            if tuple(perm) != tuple(range(len(perm))):
-                y = jnp.transpose(y, (0,) + tuple(q + 1 for q in perm))
-            vals.append(y)
+            ))
         else:
             _, eop, lhs, rhs, const = op
-            a = vals[lhs]
-            if eop == "add":
-                y = a + vals[rhs]
-            elif eop == "sub":
-                y = a - vals[rhs]
-            elif eop == "mul":
-                y = a * vals[rhs]
-            elif eop == "div":
-                y = a / vals[rhs]
-            elif eop == "neg":
-                y = -a
-            elif eop == "scale":
-                y = a * const
-            else:  # pragma: no cover - matcher only emits EWISE_OPS
-                raise ValueError(f"unknown ewise op {eop!r}")
-            vals.append(y)
+            vals.append(_ewise(
+                eop, vals[lhs], vals[rhs] if rhs >= 0 else None, const
+            ))
     return vals
 
 
-def _kernel(*refs, recipe: GemmRecipe):
+def _kernel(*refs, recipe: GemmRecipe, rows: int):
+    """One grid step: every element input's window and the SMEM
+    matrices in, every output's window out, one 8-row chunk at a time."""
     n_in = recipe.n_inputs
-    vals = [refs[i][...].astype(jnp.float32) for i in range(n_in)]
-    vals = apply_recipe(recipe, vals)
-    for j, (_, slot) in enumerate(recipe.outputs):
-        out_ref = refs[n_in + j]
-        out_ref[...] = vals[slot].astype(out_ref.dtype)
+    in_refs, out_refs = refs[:n_in], refs[n_in:]
+
+    def chunk(start):
+        ch = tiling.chunk_rows(rows)
+        vals = []
+        for (_, shape, is_elem), ref in zip(recipe.inputs, in_refs):
+            vals.append(
+                ref[tiling.chunk_index(len(shape), start, ch)]
+                .astype(jnp.float32) if is_elem else ref
+            )
+        for op in recipe.ops:
+            if op[0] == "contract":
+                _, src, mat, mode, mat_dim, perm = op
+                vals.append(tiling.contract(
+                    vals[src], vals[mat], mode, mat_dim, recipe.p, perm
+                ))
+            else:
+                _, eop, lhs, rhs, const = op
+                vals.append(_ewise(
+                    eop, vals[lhs], vals[rhs] if rhs >= 0 else None, const
+                ))
+        for (_, slot), ref in zip(recipe.outputs, out_refs):
+            y = vals[slot]
+            ref[tiling.chunk_index(y.ndim - 2, start, ch)] = (
+                y.astype(ref.dtype)
+            )
+
+    tiling.for_each_chunk(rows, chunk)
 
 
 @functools.lru_cache(maxsize=None)
-def _pallas_fn(recipe: GemmRecipe, block_elements: int, interpret: bool):
+def _pallas_fn(recipe: GemmRecipe, block_elements: int, interpret: bool,
+               vmem_limit_bytes: Optional[int]):
     """Build (and cache) the jitted pallas_call for one recipe/block."""
 
     def call(*arrays):
-        e = None
-        for (_, _, is_elem), a in zip(recipe.inputs, arrays):
-            if is_elem:
-                e = a.shape[0]
-                break
+        e = next(
+            a.shape[0]
+            for (_, _, is_elem), a in zip(recipe.inputs, arrays) if is_elem
+        )
         be = min(block_elements, e)
         if e % be != 0:
             raise ValueError(
                 f"element count {e} not divisible by block {be}"
             )
-        in_specs = []
-        for (_, shape, is_elem) in recipe.inputs:
+        rows, padded = tiling.block_rows(e, be)
+        in_specs, operands = [], []
+        for (_, shape, is_elem), a in zip(recipe.inputs, arrays):
             if is_elem:
-                zeros = (0,) * len(shape)
-                in_specs.append(pl.BlockSpec(
-                    (be,) + tuple(shape),
-                    lambda g, _z=zeros: (g,) + _z,
-                ))
-            else:                               # shared: pinned to block 0
-                zeros = (0,) * len(shape)
-                in_specs.append(pl.BlockSpec(
-                    tuple(shape), lambda g, _z=zeros: _z,
-                ))
+                in_specs.append(tiling.element_spec(shape, rows))
+                operands.append(tiling.to_tiles(a, padded))
+            else:
+                in_specs.append(tiling.smem_spec())
+                operands.append(a.astype(jnp.float32))
         out_dtype = arrays[0].dtype
-        out_specs, out_shape = [], []
-        for _, slot in recipe.outputs:
-            shape = recipe.slot_shape(slot)
-            zeros = (0,) * len(shape)
-            out_specs.append(pl.BlockSpec(
-                (be,) + tuple(shape), lambda g, _z=zeros: (g,) + _z,
-            ))
-            out_shape.append(
-                jax.ShapeDtypeStruct((e,) + tuple(shape), out_dtype)
-            )
+        shapes = [recipe.slot_shape(slot) for _, slot in recipe.outputs]
         got = pl.pallas_call(
-            functools.partial(_kernel, recipe=recipe),
-            grid=(e // be,),
+            functools.partial(_kernel, recipe=recipe, rows=rows),
+            grid=(padded // rows,),
             in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
+            out_specs=[tiling.element_spec(s, rows) for s in shapes],
+            out_shape=[
+                jax.ShapeDtypeStruct(
+                    tuple(s) + (padded, tiling.LANES), out_dtype
+                )
+                for s in shapes
+            ],
+            compiler_params=tiling.compiler_params(
+                vmem_limit_bytes, interpret
+            ),
             interpret=interpret,
-        )(*arrays)
-        return got
+            name="gemm_chain",
+        )(*operands)
+        return [tiling.from_tiles(y, e) for y in got]
 
     return jax.jit(call)
 
@@ -207,11 +229,15 @@ def gemm_chain_pallas(
     *,
     block_elements: int = DEFAULT_BLOCK_ELEMENTS,
     interpret: bool = False,
+    vmem_limit_bytes: Optional[int] = None,
 ) -> Dict[str, jax.Array]:
     """Run one recipe through the tiled Pallas kernel.  ``env`` maps the
-    recipe's input names to arrays (element tensors batched on axis 0)."""
+    recipe's input names to arrays (element tensors batched on axis 0);
+    ``vmem_limit_bytes``: see ``tiling.compiler_params``."""
     arrays = tuple(env[name] for name, _, _ in recipe.inputs)
-    got = _pallas_fn(recipe, block_elements, interpret)(*arrays)
+    got = _pallas_fn(
+        recipe, block_elements, interpret, vmem_limit_bytes
+    )(*arrays)
     return {name: out for (name, _), out in zip(recipe.outputs, got)}
 
 
@@ -232,7 +258,7 @@ def gemm_chain_ref(
     recipe: GemmRecipe, env: Dict[str, jax.Array]
 ) -> Dict[str, jax.Array]:
     """Pure-jnp reference: the same recipe applied to the whole batch
-    (element axis 0 plays the block axis)."""
+    with einsums (element axis 0)."""
     arrays = tuple(env[name] for name, _, _ in recipe.inputs)
     got = _ref_fn(recipe)(*arrays)
     return {name: out for (name, _), out in zip(recipe.outputs, got)}

@@ -1,93 +1,67 @@
 """Fused Inverse-Helmholtz Pallas TPU kernel -- the paper's dataflow CU.
 
-Adaptation notes (DESIGN.md section 2):
+Adaptation notes:
 
   * The FPGA CU streams one element through 7 pipelined loop nests with
-    FIFO links; here a *block of BE elements* flows through the same 7
+    FIFO links; here a *block of elements* flows through the same 7
     stages entirely inside VMEM -- crossing a stage boundary never touches
     HBM, which is the TPU equivalent of the FIFO stream.
   * "Lane packing" (splitting the 256-bit AXI bus into parallel lanes) is
-    realized by packing the element axis into the GEMM minor dimension:
-    every contraction is one (p x p) x (p x BE*p^2) matmul whose minor dim
-    is a multiple of 128, saturating MXU lanes instead of AXI lanes.
+    realized by packing the element axis into the vector lanes: the block
+    is ``(p, p, p, rows, 128)`` (``kernels.tiling``), so every mode
+    contraction is p^2 scalar-weighted sums of whole ``(rows, 128)``
+    slabs -- one element per lane, exact float32 on the VPU.
   * Host<->HBM double buffering is Pallas grid pipelining: while block g
     computes, block g+1's DMA from HBM is in flight (automatic ping/pong).
-  * Mnemosyne-style sharing: the t/r intermediates reuse one VMEM scratch
-    allocation (disjoint lifetimes inside a stage chain).
+  * S sits in SMEM for the whole grid: its entries are the contractions'
+    scalar weights.
 
-Grid: (E // BE,).  Refs carry one element block; S is re-fetched per step
-(index_map pins block 0) which Mosaic keeps resident in VMEM.
+Grid: (padded rows // rows per block,).  Each step computes its block
+one 8-row chunk at a time.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import tiling
+
 
 DEFAULT_BLOCK_ELEMENTS = 128
 
 
-def _contract_first(S, x, p: int, be: int):
-    """y[a, e, m, n] = sum_l S[l, a] * x[l, e, m, n] as one MXU GEMM.
-
-    x arrives as (l, BE*p*p) row-major with l major; lhs is (p, p).
-    dot_general: contract S dim 0 with x dim 0 -> (a, BE*p*p).
-    """
-    return jax.lax.dot_general(
-        S, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-
-def _helmholtz_block(S, D, u, p: int, be: int):
-    """Compute one element block entirely in registers/VMEM.
-
-    u, D: (BE, p, p, p). Returns v: (BE, p, p, p).
-
-    Each contraction rotates the contracted axis to the front and packs
-    (BE, remaining p^2) into the GEMM minor dimension.
-    """
-    f32 = jnp.float32
-
-    def rotate_contract(M, x):
-        # x: (BE, p, p, p) contracting over axis 1 (current leading p).
-        # -> (p_l, BE * p * p) GEMM, result axis becomes the *last* p axis,
-        # so three applications restore the original axis order.
-        xt = jnp.transpose(x, (1, 0, 2, 3))          # (l, BE, p, p)
-        xm = xt.reshape(p, be * p * p)               # (l, BE*p*p)
-        ym = jax.lax.dot_general(
-            M, xm, (((0,), (0,)), ((), ())), preferred_element_type=f32
-        )                                            # (a, BE*p*p)
-        y = ym.reshape(p, be, p, p)
-        return jnp.transpose(y, (1, 2, 3, 0))        # (BE, p, p, a)
-
-    # ---- stage 1-3: t = (S^T (x)3) u  (t_ijk = sum S_il S_jm S_kn u_lmn)
-    # contract l with S_il => lhs must be S with its *second* axis as the
-    # contracted one: pass S and contract dim 1 == use S^T in rotate form.
-    t = u.astype(f32)
-    for _ in range(3):
-        t = rotate_contract(jnp.transpose(S), t)     # contracts S_il over l
-    # ---- stage 4: Hadamard
-    r = D.astype(f32) * t
-    # ---- stage 5-7: v = (S (x)3) r   (v_ijk = sum S_li S_mj S_nk r_lmn)
-    v = r
-    for _ in range(3):
-        v = rotate_contract(S, v)                    # contracts S_li over l
+def _helmholtz_chunk(s_ref, D, u, p: int):
+    """t = (S^T (x)3) u, r = D * t, v = (S (x)3) r on one tiled chunk."""
+    t = u
+    for mode in range(3):                 # t_ijk = sum S_il S_jm S_kn u_lmn
+        t = tiling.contract(t, s_ref, mode, 1, p, (0, 1, 2))
+    v = D * t
+    for mode in range(3):                 # v_ijk = sum S_li S_mj S_nk r_lmn
+        v = tiling.contract(v, s_ref, mode, 0, p, (0, 1, 2))
     return v
 
 
-def _kernel(S_ref, D_ref, u_ref, v_ref, *, p: int, be: int):
-    S = S_ref[...]
-    D = D_ref[...]
-    u = u_ref[...]
-    v_ref[...] = _helmholtz_block(S, D, u, p, be).astype(v_ref.dtype)
+def _kernel(s_ref, d_ref, u_ref, v_ref, *, p: int, rows: int):
+    def chunk(start):
+        idx = tiling.chunk_index(3, start, tiling.chunk_rows(rows))
+        v = _helmholtz_chunk(
+            s_ref,
+            d_ref[idx].astype(jnp.float32),
+            u_ref[idx].astype(jnp.float32),
+            p,
+        )
+        v_ref[idx] = v.astype(v_ref.dtype)
+
+    tiling.for_each_chunk(rows, chunk)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_elements", "interpret")
+    jax.jit,
+    static_argnames=("block_elements", "interpret", "vmem_limit_bytes"),
 )
 def inverse_helmholtz_pallas(
     S: jax.Array,
@@ -96,24 +70,26 @@ def inverse_helmholtz_pallas(
     *,
     block_elements: int = DEFAULT_BLOCK_ELEMENTS,
     interpret: bool = False,
+    vmem_limit_bytes: Optional[int] = None,
 ) -> jax.Array:
-    """Batched fused Inverse Helmholtz.  S: (p,p); D,u: (E,p,p,p)."""
+    """Batched fused Inverse Helmholtz.  S: (p,p); D,u: (E,p,p,p).
+    ``vmem_limit_bytes``: see ``tiling.compiler_params``."""
     E, p = u.shape[0], u.shape[1]
     be = min(block_elements, E)
     if E % be != 0:
         raise ValueError(f"element count {E} not divisible by block {be}")
-
-    grid = (E // be,)
-    kernel = functools.partial(_kernel, p=p, be=be)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((p, p), lambda g: (0, 0)),          # S resident
-            pl.BlockSpec((be, p, p, p), lambda g: (g, 0, 0, 0)),
-            pl.BlockSpec((be, p, p, p), lambda g: (g, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((be, p, p, p), lambda g: (g, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
+    rows, padded = tiling.block_rows(E, be)
+    spec = tiling.element_spec((p, p, p), rows)
+    out = pl.pallas_call(
+        functools.partial(_kernel, p=p, rows=rows),
+        grid=(padded // rows,),
+        in_specs=[tiling.smem_spec(), spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((p, p, p, padded, tiling.LANES),
+                                       u.dtype),
+        compiler_params=tiling.compiler_params(vmem_limit_bytes, interpret),
         interpret=interpret,
-    )(S, D, u)
+        name="inverse_helmholtz",
+    )(S.astype(jnp.float32), tiling.to_tiles(D, padded),
+      tiling.to_tiles(u, padded))
+    return tiling.from_tiles(out, E)

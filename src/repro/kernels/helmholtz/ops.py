@@ -1,58 +1,40 @@
 """Jit'd public wrappers for the fused Inverse-Helmholtz kernel.
 
-``inverse_helmholtz(S, D, u)`` picks the best available implementation:
-the Pallas kernel on TPU, interpret-mode Pallas when explicitly requested
-(CPU validation), and the pure-jnp reference otherwise.  The signature is
+``inverse_helmholtz(S, D, u)`` runs the Pallas kernel: compiled by
+Mosaic on a TPU, in interpret mode on the CPU (``impl="auto"``, see
+``tiling.resolve_impl``); ``impl="xla"`` asks for the pure-jnp reference
+by name.  The signature is
 what ``repro.core.emit.compile_program(backend='pallas')`` expects as
 ``pallas_impl`` for the Inverse-Helmholtz program.
 """
 from __future__ import annotations
 
-import functools
-from typing import Literal
+from typing import Literal, Optional
 
 import jax
-import jax.numpy as jnp
 
+from ...memory import layout
+from ...memory.channels import MemoryTarget
+from .. import tiling
 from .helmholtz import inverse_helmholtz_pallas, DEFAULT_BLOCK_ELEMENTS
 from .ref import inverse_helmholtz_ref
 
 Impl = Literal["auto", "pallas", "interpret", "xla"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def block_working_set_bytes(
-    p: int, block_elements: int, *, bytes_per_scalar: int = 4
-) -> int:
-    """VMEM bytes while one element block flows through the fused kernel:
-    the u/D/v block slices plus the double-buffered t/r scratch pair
-    (Mnemosyne-style sharing keeps two intermediates live), plus the
-    resident S operator.  Matches ``memory.layout.block_working_set_bytes``
-    on the Inverse-Helmholtz program."""
-    return (p * p + 5 * block_elements * p ** 3) * bytes_per_scalar
-
-
-def block_elements_for_vmem(
-    p: int,
-    vmem_bytes: int,
-    *,
+    p: int, block_elements: int, target: MemoryTarget, *,
     bytes_per_scalar: int = 4,
-    reserve_fraction: float = 0.5,
 ) -> int:
-    """Largest power-of-two element block whose working set fits the
-    given on-chip memory (half reserved for the Pallas grid pipeline's
-    DMA double buffering).  This is how a MemoryPlan's VMEM budget
-    becomes the kernel's ``block_elements``."""
-    budget = int(vmem_bytes * reserve_fraction)
-    be = 1
-    while block_working_set_bytes(
-        p, be * 2, bytes_per_scalar=bytes_per_scalar
-    ) <= budget:
-        be *= 2
-    return be
+    """VMEM bytes while one element block flows through the fused
+    kernel on ``target``: the u/D/v windows, the live t/r intermediates
+    and the resident S (``memory.layout.working_set_bytes``).  Matches
+    ``memory.layout.block_working_set_bytes`` on the Inverse-Helmholtz
+    program."""
+    return layout.working_set_bytes(
+        (p ** 3,) * 3, p ** 3, p * p, block_elements, bytes_per_scalar,
+        target,
+    )
 
 
 def inverse_helmholtz(
@@ -62,27 +44,28 @@ def inverse_helmholtz(
     *,
     impl: Impl = "auto",
     block_elements: int = DEFAULT_BLOCK_ELEMENTS,
+    vmem_limit_bytes: Optional[int] = None,
 ) -> jax.Array:
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "xla"
-    if impl == "pallas":
-        return inverse_helmholtz_pallas(
-            S, D, u, block_elements=block_elements
-        )
-    if impl == "interpret":
-        return inverse_helmholtz_pallas(
-            S, D, u, block_elements=block_elements, interpret=True
-        )
-    return jax.jit(inverse_helmholtz_ref)(S, D, u)
+    impl = tiling.resolve_impl(impl)
+    if impl == "xla":
+        return jax.jit(inverse_helmholtz_ref)(S, D, u)
+    return inverse_helmholtz_pallas(
+        S, D, u, block_elements=block_elements,
+        interpret=impl == "interpret", vmem_limit_bytes=vmem_limit_bytes,
+    )
 
 
-def make_pallas_impl(impl: Impl = "auto", block_elements: int = DEFAULT_BLOCK_ELEMENTS):
+def make_pallas_impl(
+    impl: Impl = "auto", block_elements: int = DEFAULT_BLOCK_ELEMENTS,
+    vmem_limit_bytes: Optional[int] = None,
+):
     """Adapter for core.emit.compile_program(backend='pallas')."""
 
     def batched_fn(env):
         v = inverse_helmholtz(
             env["S"], env["D"], env["u"], impl=impl,
             block_elements=block_elements,
+            vmem_limit_bytes=vmem_limit_bytes,
         )
         return {"v": v}
 

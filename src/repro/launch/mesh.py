@@ -17,17 +17,12 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-try:  # AxisType landed after jax 0.4.x; Auto matches the old default
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
         shape, axes, axis_types=(AxisType.Auto,) * len(axes)
     )

@@ -1160,7 +1160,7 @@ def plan_chain(
                 buffers=tuple(bufs), cost=cost,
                 block_elements=blk,
                 block_working_set_bytes=layout.block_working_set_bytes(
-                    prog, blk, bytes_per_scalar=bps
+                    prog, blk, stage_t, bytes_per_scalar=bps
                 ),
                 cu_count=place.stages[i].cu_count,
                 devices=place.stages[i].devices,

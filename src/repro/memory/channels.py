@@ -38,6 +38,9 @@ class MemoryTarget:
     burst_bytes: int = 64      # transfer/pack quantum (AXI burst, TPU lane)
     usable_hbm_fraction: float = 0.9   # leave headroom for the runtime
     dispatch_overhead_s: float = 20e-6  # per-batch launch/sync cost
+    #: on-chip memory is (8, 128)-tiled vector memory that the Pallas
+    #: grid pipeline double-buffers (a TPU's VMEM, not a dataflow PLM)
+    tiled_vmem: bool = False
 
     @property
     def channel_bytes(self) -> int:
@@ -88,6 +91,7 @@ TPU_V5E = MemoryTarget(
     ici_bw=50e9,
     burst_bytes=512,          # 128-lane f32 vector
     dispatch_overhead_s=20e-6,
+    tiled_vmem=True,
 )
 
 #: The CPU container the tests run on: host RAM plays HBM, a memcpy
@@ -143,14 +147,33 @@ def resolve_target(target) -> MemoryTarget:
     return TARGETS[key]
 
 
+#: TPU ``device_kind`` strings (as JAX reports them) -> datasheet.
+TPU_KINDS = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def target_for_device(device) -> MemoryTarget:
+    """The datasheet of one JAX device: a TPU by its ``device_kind``
+    (an unknown kind raises -- no other chip's peaks stand in), the CPU
+    backend the tests run on as :data:`CPU_HOST`."""
+    platform = device.platform
+    if platform == "tpu":
+        kind = device.device_kind
+        if kind not in TPU_KINDS:
+            raise UnknownTargetError(
+                f"TPU device_kind {kind!r} has no datasheet; known kinds: "
+                f"{', '.join(sorted(TPU_KINDS))}"
+            )
+        return TPU_KINDS[kind]
+    if platform == "cpu":
+        return CPU_HOST
+    raise UnknownTargetError(f"no datasheet for the {platform!r} backend")
+
+
 def detect_target() -> MemoryTarget:
-    """Pick the target matching the current JAX backend."""
+    """The target of the first local JAX device."""
     import jax
 
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return TPU_V5E
-    return CPU_HOST
+    return target_for_device(jax.devices()[0])
 
 
 def pad_to_burst(nbytes: int, target: MemoryTarget) -> int:

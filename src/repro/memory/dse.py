@@ -174,7 +174,9 @@ def make_plan(
     # on-chip block: largest divisor of E whose fused-kernel working set
     # fits the VMEM budget (drives the Pallas kernel's block_elements)
     blk = layout.largest_divisor_leq(e, blk_cap)
-    blk_ws = layout.block_working_set_bytes(prog, blk, bytes_per_scalar=bps)
+    blk_ws = layout.block_working_set_bytes(
+        prog, blk, target, bytes_per_scalar=bps
+    )
 
     feasible, reason = True, ""
     resident = sum(b.resident_bytes for b in bufs)
@@ -484,8 +486,9 @@ def measure_chain_plan(
     per element.  Returns None when the plan is not runnable here (the
     placement spans more devices than are local -- run_chain would fall
     back to the single mesh and the measurement would belong to a
-    different configuration -- planned backends differ from how the
-    chain was compiled, or the runtime rejects it)."""
+    different configuration -- or planned backends differ from how the
+    chain was compiled), or when the CPU runtime rejects it.  Any other
+    backend's failure propagates."""
     import jax
 
     from ..cfd.simulation import run_chain  # lazy: no cycle
@@ -499,7 +502,9 @@ def measure_chain_plan(
         run_chain(chain, plan, max_batches=1)  # warm compile
         res = run_chain(chain, plan, max_batches=max_batches)
     except Exception:
-        return None
+        if jax.default_backend() != "cpu":
+            raise  # a chip that refuses a plan is a fault, not a rank
+        return None  # e.g. bf16 dot unsupported on the CPU runtime
     return res.wall_s / res.elements if res.elements else None
 
 
@@ -1030,7 +1035,8 @@ def measure_plan(
     """Verify a plan by running the real driver; seconds per element.
 
     Returns None when the plan is not runnable here (CU count exceeds
-    local devices, or the policy has no runtime on this backend).
+    local devices, or the policy has no runtime on the CPU); any other
+    backend's failure propagates.
     """
     import jax
 
@@ -1047,6 +1053,8 @@ def measure_plan(
         run_simulation(cfg, plan=plan, max_batches=1)  # warm compile
         res = run_simulation(cfg, plan=plan, max_batches=max_batches)
     except Exception:
+        if jax.default_backend() != "cpu":
+            raise  # a chip that refuses a plan is a fault, not a rank
         return None  # e.g. bf16 dot unsupported on the CPU runtime
     return res.wall_s / res.elements if res.elements else None
 

@@ -191,21 +191,87 @@ def build_buffers(
 # ---------------------------------------------------------------------------
 
 
-def block_working_set_bytes(
-    prog: ir.Program, block_elements: int, *, bytes_per_scalar: int
+#: Lanes of a TPU vector register: the minor split of a tiled element axis.
+LANES = 128
+#: Sublanes of a 32-bit (sublane, lane) tile: rows per in-kernel chunk.
+SUBLANES = 8
+
+
+def sublane_tile(itemsize: int) -> int:
+    """Rows of one (sublane, lane) tile for a dtype: 8 for 32-bit, 16
+    for 16-bit (two values pack into one 32-bit sublane)."""
+    return SUBLANES * max(1, 4 // itemsize)
+
+
+def padded_block_bytes(size: int, rows: int, itemsize: int) -> int:
+    """VMEM bytes of one tiled element window of ``size`` scalars per
+    element, ``(*shape, rows, 128)``, after (sublane, lane) padding."""
+    t = sublane_tile(itemsize)
+    return size * (-(-rows // t) * t) * LANES * itemsize
+
+
+def working_set_bytes(
+    stream_sizes: Sequence[int],
+    intermediate_size: int,
+    shared_size: int,
+    block_elements: int,
+    itemsize: int,
+    target: MemoryTarget,
 ) -> int:
-    """On-chip bytes while one element block flows through the fused
-    kernel: every element stream's block slice, double-buffered scratch
-    for the largest intermediate (Mnemosyne-style t/r sharing keeps two
-    live), plus the batch-invariant operands held resident."""
+    """On-chip bytes while one element block flows through a fused kernel.
+
+    ``stream_sizes`` are the scalars per element of every element input
+    and output, ``intermediate_size`` those of the largest in-kernel
+    value, ``shared_size`` the batch-invariant operands' scalars.
+
+    A ``tiled_vmem`` target (a TPU) holds the element axis as
+    ``(rows, 128)`` tiles (``kernels.tiling``): every stream window is
+    padded to whole (sublane, lane) tiles and double-buffered by the grid
+    pipeline, and the kernel keeps two chunk-sized copies of the largest
+    value live (a contraction's input and output).  A dataflow PLM holds
+    one unpadded slice per stream plus two block-sized intermediates --
+    its double buffer is the half of on-chip memory that
+    :func:`vmem_budget_bytes` holds back.
+    """
+    if not target.tiled_vmem:
+        return (shared_size + block_elements * (
+            sum(stream_sizes) + 2 * intermediate_size)) * itemsize
+    rows = -(-max(1, block_elements) // LANES)
+    windows = 2 * sum(
+        padded_block_bytes(s, rows, itemsize) for s in stream_sizes
+    )
+    scratch = 2 * padded_block_bytes(
+        intermediate_size, min(SUBLANES, rows), itemsize
+    )
+    return shared_size * itemsize + windows + scratch
+
+
+def block_working_set_bytes(
+    prog: ir.Program, block_elements: int, target: MemoryTarget, *,
+    bytes_per_scalar: int,
+) -> int:
+    """:func:`working_set_bytes` of one element block of ``prog``: every
+    element stream's block window, the live copies of the largest
+    intermediate, plus the batch-invariant operands held resident."""
     ins, outs, shared = element_streams(prog)
-    elem = sum(v.size for _, v in ins + outs)
-    scratch = 2 * max(
+    inter = max(
         (n.size for n in prog.toposort() if not isinstance(n, ir.Input)),
         default=0,
     )
-    shared_b = sum(v.size for _, v in shared)
-    return (shared_b + block_elements * (elem + scratch)) * bytes_per_scalar
+    return working_set_bytes(
+        [v.size for _, v in ins + outs], inter,
+        sum(v.size for _, v in shared), block_elements, bytes_per_scalar,
+        target,
+    )
+
+
+def vmem_budget_bytes(target: MemoryTarget) -> int:
+    """The on-chip bytes one kernel block may use: half the target's
+    VMEM/PLM.  On a TPU this is the ``vmem_limit_bytes`` the Pallas
+    kernels are compiled with (the working set counts the double
+    buffers itself); on a dataflow PLM the other half is its ping/pong
+    copy."""
+    return target.vmem_bytes // 2
 
 
 def vmem_block_elements(
@@ -213,15 +279,14 @@ def vmem_block_elements(
     target: MemoryTarget,
     *,
     bytes_per_scalar: int,
-    reserve_fraction: float = 0.5,
 ) -> int:
     """Largest power-of-two element block whose working set fits the
-    target's on-chip memory (half is reserved for the grid pipeline's
-    DMA double buffering, mirroring ``core.schedule``'s VMEM budget)."""
-    budget = int(target.vmem_bytes * reserve_fraction)
+    target's :func:`vmem_budget_bytes` (mirroring ``core.schedule``'s
+    VMEM budget)."""
+    budget = vmem_budget_bytes(target)
     be = 1
     while block_working_set_bytes(
-        prog, be * 2, bytes_per_scalar=bytes_per_scalar
+        prog, be * 2, target, bytes_per_scalar=bytes_per_scalar
     ) <= budget:
         be *= 2
     return be

@@ -18,6 +18,7 @@ import json
 import sys
 from typing import Any, Dict, List
 
+from ..compile_cache import enable_compile_cache
 from .check import (check_snapshot, check_structure, diff_snapshots,
                     index_metrics)
 from .registry import MetricsError
@@ -59,6 +60,7 @@ def _pretty(snap: Dict[str, Any]) -> List[str]:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.metrics",
         description="Validate / check / diff repro metrics snapshots.",

@@ -18,6 +18,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..core.dsl import ParseError
 from ..core.ir import IRError
 from ..flow import build
@@ -89,6 +90,7 @@ def _synth_requests(engine: ServeEngine, n: int, seed: int):
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     args = _parse_args(argv)
+    enable_compile_cache()
     try:
         with open(args.source) as f:
             source = f.read()

@@ -96,7 +96,8 @@ class ServeEngine:
                  tracer=None, monitor=None, latency=None, seed: int = 0,
                  shared: Optional[Dict[str, np.ndarray]] = None,
                  clock=time.monotonic, metrics=None, slo=None) -> None:
-        from ..cfd.simulation import element_mesh  # lazy: cfd builds on flow
+        # lazy: cfd builds on flow
+        from ..cfd.simulation import chain_stage_fns, element_mesh
 
         self.system = system
         chain: memchain.ProgramChain = system.chain
@@ -233,30 +234,10 @@ class ServeEngine:
                 for k, v in batch.items()
             }
 
-        def make_stage_fn(i: int, s: memchain.ChainStage):
-            def run_stage(staged, carry):
-                live: Dict[str, jax.Array] = dict(carry) if carry else {}
-                env: Dict[str, jax.Array] = {}
-                for name in s.program.inputs:
-                    if name in chain.resolved[i]:
-                        p_idx, out_name = chain.resolved[i][name]
-                        env[name] = live[
-                            f"{chain.stages[p_idx].name}.{out_name}"
-                        ]
-                    elif name in shared_dev:
-                        env[name] = shared_dev[name]
-                    else:
-                        env[name] = staged[f"{s.name}.{name}"]
-                outs = s.compiled.batched_fn(env)
-                for out_name, val in outs.items():
-                    live[f"{s.name}.{out_name}"] = val
-                return live
-
-            return run_stage
-
         out_names = self.out_names
         self.driver = StagePipelineDriver(
-            [make_stage_fn(i, s) for i, s in enumerate(chain.stages)],
+            chain_stage_fns(chain, [shared_dev] * len(chain.stages),
+                            out_names),
             stage_fn=stage_batch,
             depths=depths,
             reduce_fn=lambda live: {q: live[q] for q in out_names},

@@ -395,23 +395,51 @@ def test_plan_block_elements_fits_vmem():
     # maximal: the next power of two would blow the reserve budget
     from repro.kernels.helmholtz import ops as hops
     assert hops.block_working_set_bytes(
-        11, plan.block_elements
+        11, plan.block_elements, t
     ) == plan.block_working_set_bytes
     bigger = min(plan.block_elements * 2, plan.batch_elements)
     if bigger > plan.block_elements:
-        assert hops.block_working_set_bytes(11, bigger) > t.vmem_bytes // 2
+        assert hops.block_working_set_bytes(
+            11, bigger, t
+        ) > layout.vmem_budget_bytes(t)
+
+
+def test_tpu_block_working_set_is_padded_and_double_buffered():
+    """On a v5e the block is sized as the kernel lays it out: element
+    windows (p,p,p, rows, 128) padded to whole (8, 128) tiles and double-
+    buffered, plus two 8-row chunks of the largest intermediate -- and
+    that working set fits the vmem_limit_bytes the kernel passes."""
+    from repro.kernels.helmholtz import ops as hops
+
+    t = channels.TPU_V5E
+    untiled = t.with_(tiled_vmem=False)
+
+    def hops_ws(be, *, tiled):
+        return hops.block_working_set_bytes(11, be, t if tiled else untiled)
+
+    plan = dse.make_plan(11, target=t, backend="pallas")
+    blk, p3 = plan.block_elements, 11 ** 3
+    rows = -(-(-(-blk // 128)) // 8) * 8          # whole sublane tiles
+    windows = 2 * 3 * p3 * rows * 128 * 4         # u, D, v; two buffers
+    scratch = 2 * p3 * 8 * 128 * 4                # t/r, one chunk each
+    assert plan.block_working_set_bytes == windows + scratch + 11 * 11 * 4
+    assert plan.block_working_set_bytes <= layout.vmem_budget_bytes(t)
+    assert blk >= 1024 and plan.batch_elements % blk == 0
+    # padding is real: the tiled count is never below the unpadded one,
+    # and a block under one tile of rows costs a whole tile
+    for be in (1, 64, blk):
+        padded = hops_ws(be, tiled=True)
+        assert padded >= hops_ws(be, tiled=False)
+    assert hops_ws(1, tiled=True) == hops_ws(1024, tiled=True)
 
 
 def test_pallas_block_resolution_prefers_plan():
     plan = dse.make_plan(
         5, target=channels.TPU_V5E, backend="pallas", batch_elements=1024
     )
-    assert operators.pallas_block_elements(5, plan) == plan.block_elements
-    assert operators.pallas_block_elements(
-        5, None, vmem_bytes=channels.TPU_V5E.vmem_bytes
-    ) >= plan.block_elements  # unconstrained by E divisibility
+    assert operators.pallas_block_elements(plan) == plan.block_elements
     from repro.kernels.helmholtz.ops import DEFAULT_BLOCK_ELEMENTS
-    assert operators.pallas_block_elements(5) == DEFAULT_BLOCK_ELEMENTS
+    assert operators.pallas_block_elements() == DEFAULT_BLOCK_ELEMENTS
 
 
 def test_pallas_backend_runs_with_plan_block(rng):
@@ -456,9 +484,9 @@ def test_chain_plan_block_reaches_pallas_stage(monkeypatch):
     from repro.kernels.helmholtz import ops as hops
     real = hops.make_pallas_impl
 
-    def spy(impl="auto", block_elements=hops.DEFAULT_BLOCK_ELEMENTS):
+    def spy(impl="auto", block_elements=hops.DEFAULT_BLOCK_ELEMENTS, **kw):
         seen["block_elements"] = block_elements
-        return real(impl=impl, block_elements=block_elements)
+        return real(impl=impl, block_elements=block_elements, **kw)
 
     monkeypatch.setattr(
         "repro.cfd.operators.helmholtz_ops.make_pallas_impl", spy
@@ -478,10 +506,11 @@ def test_layout_vmem_block_matches_kernel_formula():
 
     for p in (5, 7, 11):
         prog = rewrite.optimize(dsl.inverse_helmholtz_program(p))
-        for be in (1, 8, 64):
-            assert layout.block_working_set_bytes(
-                prog, be, bytes_per_scalar=4
-            ) == hops.block_working_set_bytes(p, be)
+        for be in (1, 8, 64, 2048):
+            for t in (channels.ALVEO_U280, channels.TPU_V5E):
+                assert layout.block_working_set_bytes(
+                    prog, be, t, bytes_per_scalar=4
+                ) == hops.block_working_set_bytes(p, be, t)
 
 
 # ---------------------------------------------------------------------------
@@ -577,3 +606,41 @@ def test_contention_fit_keeps_structural_without_evidence(cfd_chain,
     assert cost.stage_steady_times[1:] == plan.cost.stage_steady_times[1:]
     assert cost.stage_steady_times[0] == pytest.approx(
         max(c0.t_host, k0 * dev0) + c0.t_overhead)
+
+
+def test_ring_releases_streams_after_their_last_reader():
+    """Each batch in the skewed ring keeps only what a later stage or
+    the host still reads: a host stream leaves ``staged`` once read, a
+    handoff leaves the carry after its last consumer.  (Keeping every
+    stream until retire ran a 13-stage p=11 ring out of a v5e's HBM.)"""
+    import jax.numpy as jnp
+
+    from repro import flow
+
+    system = flow.compile(
+        operators.CFD_PIPELINE_SRC.format(p=3), target=channels.CPU_HOST,
+        batch_elements=4, n_eq=8,
+    )
+    chain = system.chain
+    n = len(chain.stages)
+    assert n > 3
+    out_names = [f"{s.name}.{o}" for i, s in enumerate(chain.stages)
+                 for o, _ in chain.chain_outputs(i)]
+    shared = {name: jnp.ones(v.shape, jnp.float32)
+              for name, v in chain.shared_operands().items()}
+    staged = {f"{s.name}.{o}": jnp.ones((4,) + v.shape, jnp.float32)
+              for i, s in enumerate(chain.stages)
+              for o, v in chain.host_element_inputs(i)}
+    fns = simulation.chain_stage_fns(chain, [shared] * n, out_names)
+    carry, produced = None, 0
+    for i, fn in enumerate(fns):
+        carry = fn(staged, carry)
+        produced += len(chain.stages[i].program.outputs)
+        later = {f"{chain.stages[p].name}.{o}"
+                 for j in range(i + 1, n)
+                 for p, o in chain.resolved[j].values()}
+        assert set(carry) <= later | set(out_names), i
+        assert not any(q.startswith(f"{chain.stages[i].name}.")
+                       for q in staged), i
+    assert set(carry) == set(out_names) and not staged
+    assert produced > len(carry)

@@ -88,7 +88,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     from repro.runtime.train import make_train_step, init_train_state
     from repro.distributed import sharding as sr, pipeline as pp, compression
     from repro.launch import mesh as mesh_mod
-    from repro.distributed.pipeline import shard_map  # version-portable
+    from jax import shard_map
 
     out = {}
     assert len(jax.devices()) == 8, jax.devices()

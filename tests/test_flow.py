@@ -14,7 +14,7 @@ from repro import flow
 from repro.cfd import operators, reference, simulation
 from repro.core import dsl, liveness
 from repro.memory import chain as mchain
-from repro.memory import channels, dse
+from repro.memory import channels, dse, layout
 
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
 EXAMPLES = pathlib.Path(__file__).parent.parent / "examples"
@@ -134,8 +134,8 @@ def test_flow_pallas_covers_interp_and_grad_stages():
 
 
 def test_flow_pallas_fallback_when_no_kernel_matches():
-    """A 'pallas' stage with no matching hand-tiled kernel falls back to
-    xla (emit's documented dispatch rule) instead of failing.  An
+    """A 'pallas' stage with no matching hand-tiled kernel is compiled
+    with xla under a warning that names it -- never silently.  An
     element-tensor x element-tensor product with a contraction is outside
     every kernel class (the GEMM chain needs a shared (p,p) matrix)."""
     src = (
@@ -144,10 +144,11 @@ def test_flow_pallas_fallback_when_no_kernel_matches():
         "var output elem y : [4 4]\n"
         "y = a # b . [[1 2]]\n"
     )
-    system = flow.compile(
-        src, backend="pallas", target=channels.CPU_HOST,
-        batch_elements=4, n_eq=8,
-    )
+    with pytest.warns(RuntimeWarning, match="no Pallas kernel matches"):
+        system = flow.compile(
+            src, backend="pallas", target=channels.CPU_HOST,
+            batch_elements=4, n_eq=8,
+        )
     assert system.backends == ("xla",)
 
 
@@ -290,12 +291,15 @@ def test_flow_dse_recompiles_pallas_block_on_e_change(monkeypatch):
     block_elements.  The winner's block must reach the kernel."""
     from repro.kernels.helmholtz import ops as hops
 
-    seen = []
+    seen, limits = [], set()
     real = hops.make_pallas_impl
 
-    def spy(impl="auto", block_elements=hops.DEFAULT_BLOCK_ELEMENTS):
+    def spy(impl="auto", block_elements=hops.DEFAULT_BLOCK_ELEMENTS,
+            vmem_limit_bytes=None):
         seen.append(block_elements)
-        return real(impl=impl, block_elements=block_elements)
+        limits.add(vmem_limit_bytes)
+        return real(impl=impl, block_elements=block_elements,
+                    vmem_limit_bytes=vmem_limit_bytes)
 
     monkeypatch.setattr(
         "repro.flow.patterns.helmholtz_ops.make_pallas_impl", spy
@@ -318,6 +322,37 @@ def test_flow_dse_recompiles_pallas_block_on_e_change(monkeypatch):
     assert seen[0] == hops.DEFAULT_BLOCK_ELEMENTS
     assert seen[-1] == blk
     assert system.plan.batch_elements % blk == 0
+    assert limits == {layout.vmem_budget_bytes(channels.ALVEO_U280)}
+
+
+@pytest.mark.parametrize("target", ["alveo-u280", "tpu-v5e", "cpu-host"])
+def test_pallas_stage_kernel_gets_the_target_vmem_budget(target, monkeypatch):
+    """Every Pallas kernel the flow builds is compiled with the VMEM
+    budget of the target its block was sized against -- never a
+    constant of one chip."""
+    from repro.kernels.helmholtz import ops as hops
+
+    limits = []
+    real = hops.make_pallas_impl
+
+    def spy(impl="auto", block_elements=hops.DEFAULT_BLOCK_ELEMENTS,
+            vmem_limit_bytes=None):
+        limits.append(vmem_limit_bytes)
+        return real(impl=impl, block_elements=block_elements,
+                    vmem_limit_bytes=vmem_limit_bytes)
+
+    monkeypatch.setattr(
+        "repro.flow.patterns.helmholtz_ops.make_pallas_impl", spy
+    )
+    system = flow.compile(
+        dsl.INVERSE_HELMHOLTZ_SRC.format(p=5),
+        element_vars=("u", "D", "v"), backend="pallas", max_stages=1,
+        target=target, n_eq=1 << 12,
+    )
+    assert system.backends == ("pallas",)
+    budget = layout.vmem_budget_bytes(system.target)
+    assert limits and set(limits) == {budget}
+    assert system.plan.stages[0].block_working_set_bytes <= budget
 
 
 def test_flow_tune_blocks_measures_and_records(tmp_path):

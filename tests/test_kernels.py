@@ -9,6 +9,7 @@ from repro.kernels import gemm
 from repro.kernels.attention import ops as attn_ops
 from repro.kernels.attention.ref import attention_ref
 from repro.kernels.helmholtz import ops as hh_ops
+from repro.memory import channels, layout
 
 
 # ---------------------------------------------------------------------------
@@ -246,19 +247,25 @@ def test_gemm_recipe_flops_match_ir():
 # CHARM-style tile candidates (cdse/cdac)
 # ---------------------------------------------------------------------------
 
+def _plm_target(vmem_bytes):
+    """An untiled (dataflow PLM) target with a round roofline."""
+    return channels.ALVEO_U280.with_(
+        vmem_bytes=vmem_bytes, peak_flops=1e12, hbm_bw=400e9
+    )
+
+
 def test_tile_candidates_filter_class_and_rank():
     recipe = _interp_recipe(11)
     vmem = 16 * 2 ** 20
-    cands = gemm.tile_candidates(
-        recipe, vmem_bytes=vmem, peak_flops=1e12, hbm_bandwidth=400e9,
-    )
+    target = _plm_target(vmem)
+    cands = gemm.tile_candidates(recipe, target)
     assert cands
     budget = vmem * 0.5
     for c in cands:
         # the VMEM constraint is honored and the working set is exact
         assert c.working_set_bytes <= budget
         assert c.working_set_bytes == gemm.block_working_set_bytes(
-            recipe, c.block_elements
+            recipe, c.block_elements, target
         )
         expect = (
             "cdse" if c.working_set_bytes
@@ -275,8 +282,7 @@ def test_tile_candidates_filter_class_and_rank():
 def test_tile_candidates_respect_batch_divisibility():
     recipe = _interp_recipe(5)
     cands = gemm.tile_candidates(
-        recipe, vmem_bytes=64 * 2 ** 20, peak_flops=1e12,
-        hbm_bandwidth=400e9, batch_elements=96,
+        recipe, _plm_target(64 * 2 ** 20), batch_elements=96
     )
     assert cands
     for c in cands:
@@ -286,15 +292,23 @@ def test_tile_candidates_respect_batch_divisibility():
 
 def test_tile_candidates_empty_when_vmem_too_small():
     assert gemm.tile_candidates(
-        _interp_recipe(11), vmem_bytes=4096, peak_flops=1e12,
-        hbm_bandwidth=400e9,
+        _interp_recipe(11), _plm_target(4096)
     ) == []
 
 
-def test_block_elements_for_vmem_monotone():
-    recipe = _interp_recipe(7)
-    small = gemm.block_elements_for_vmem(recipe, 2 ** 20)
-    large = gemm.block_elements_for_vmem(recipe, 2 ** 24)
-    assert 1 <= small < large
-    # the chosen block actually fits half the budget
-    assert gemm.block_working_set_bytes(recipe, large) <= 2 ** 23
+def test_tile_candidates_on_a_tpu_fit_the_kernel_budget():
+    """On a tiled target every candidate's padded, double-buffered
+    working set fits the budget the kernel is compiled with, and costs
+    at least the unpadded count."""
+    recipe = _interp_recipe(11)
+    t = channels.TPU_V5E
+    cands = gemm.tile_candidates(recipe, t)
+    assert cands
+    for c in cands:
+        assert c.working_set_bytes <= layout.vmem_budget_bytes(t)
+        assert c.working_set_bytes == gemm.block_working_set_bytes(
+            recipe, c.block_elements, t
+        )
+        assert c.working_set_bytes >= gemm.block_working_set_bytes(
+            recipe, c.block_elements, t.with_(tiled_vmem=False)
+        )

@@ -257,12 +257,16 @@ def _chain_batch_inputs(
     n_batches: int,
     seed: int,
     inputs: Optional[Dict[str, np.ndarray]],
+    *,
+    tracer=None,
 ) -> Iterator[Dict[str, np.ndarray]]:
     """Per-batch host-streamed inputs, qualified "stage.input".
 
     ``inputs`` supplies full arrays (element-axis leading) to slice;
     otherwise a deterministic synthetic stream is generated, matching
-    ``_batch_generator``'s [-1, 1] normalization."""
+    ``_batch_generator``'s [-1, 1] normalization.  ``tracer`` records a
+    ``slice`` span around building each batch, closed before the batch
+    is yielded."""
     names = [
         f"{s.name}.{n}"
         for i, s in enumerate(chain.stages)
@@ -273,15 +277,26 @@ def _chain_batch_inputs(
         for i, s in enumerate(chain.stages)
         for n, v in chain.host_element_inputs(i)
     }
-    for b in range(n_batches):
+
+    def batch(b: int) -> Dict[str, np.ndarray]:
         if inputs is not None:
-            yield {q: inputs[q][b * E:(b + 1) * E] for q in names}
-        else:
-            rng = np.random.default_rng(seed + b)
-            yield {
-                q: rng.uniform(-1, 1, (E,) + shapes[q]).astype(np.float32)
-                for q in names
-            }
+            return {q: inputs[q][b * E:(b + 1) * E] for q in names}
+        rng = np.random.default_rng(seed + b)
+        return {
+            q: rng.uniform(-1, 1, (E,) + shapes[q]).astype(np.float32)
+            for q in names
+        }
+
+    if tracer:
+        from ..trace.attribution import CAT_SLICE
+        from ..trace.tracer import HOST_TRACK
+    for b in range(n_batches):
+        if not tracer:
+            yield batch(b)
+            continue
+        with tracer.span(f"slice b{b}", CAT_SLICE, HOST_TRACK, batch=b):
+            sliced = batch(b)
+        yield sliced
 
 
 def chain_stage_fns(
@@ -393,7 +408,26 @@ def run_chain(
     ``ChainResult.straggler_batches``.  ``metrics`` (a ``repro.metrics``
     registry) records the driver's always-on per-stage dispatch/stall
     histograms keyed by the plan signature.  None changes results.
+
+    Around the pipeline, the tracer also records ``run_chain``'s own host
+    work: ``prepare`` (entry to the root span: plan check, shared
+    operands put on the device, stage callables built), a ``slice`` per
+    batch, the retire's ``wait`` for and ``transfer`` of each batch's
+    outputs with ``collect_outputs`` (bytes brought back in the
+    ``host_out_bytes`` counter), then ``concat`` and ``checksum``.
     """
+    prepare = None
+    if tracer:
+        from ..trace.attribution import (CAT_CHECKSUM, CAT_CONCAT,
+                                         CAT_PREPARE, CAT_TRANSFER, CAT_WAIT,
+                                         COUNTER_CHANNEL_BYTES,
+                                         COUNTER_HOST_OUT_BYTES,
+                                         COUNTER_OCCUPANCY,
+                                         COUNTER_PAD_ELEMENTS,
+                                         host_channel_bytes)
+        from ..trace.tracer import HOST_TRACK
+
+        prepare = tracer.begin("prepare", CAT_PREPARE, HOST_TRACK)
     mesh = mesh or element_mesh()
     if n_eq is None and inputs:
         # the data bounds the problem -- derive n_eq before planning so
@@ -530,11 +564,6 @@ def run_chain(
         }
 
     if tracer:
-        from ..trace.attribution import (COUNTER_CHANNEL_BYTES,
-                                         COUNTER_OCCUPANCY,
-                                         COUNTER_PAD_ELEMENTS,
-                                         host_channel_bytes)
-
         tracer.meta.update({
             "chain": plan.chain, "target": plan.target.name,
             "policy": plan.policy, "signature": plan.signature,
@@ -593,7 +622,22 @@ def run_chain(
 
         place_fns = [make_place_fn(i) for i in range(len(chain.stages))]
 
-    if collect_outputs:
+    if collect_outputs and tracer:
+        retired = [0]
+
+        def reduce_fn(live):
+            k = retired[0]
+            retired[0] += 1
+            outs = {q: live[q] for q in out_names}
+            with tracer.span(f"wait b{k}", CAT_WAIT, HOST_TRACK, batch=k):
+                jax.block_until_ready(outs)
+            with tracer.span(f"transfer b{k}", CAT_TRANSFER, HOST_TRACK,
+                             batch=k):
+                got = jax.device_get(outs)
+            tracer.bump(COUNTER_HOST_OUT_BYTES,
+                        {q: float(v.nbytes) for q, v in got.items()})
+            return got
+    elif collect_outputs:
         reduce_fn = lambda live: jax.device_get(
             {q: live[q] for q in out_names}
         )
@@ -604,6 +648,8 @@ def run_chain(
 
     m_count0 = monitor.count if monitor is not None else 0
     m_flags0 = len(monitor.flags) if monitor is not None else 0
+    if prepare is not None:
+        tracer.end(prepare)
     root = (tracer.begin("run_chain", "run", 0, chain=plan.chain,
                          batches=n, batch_elements=E,
                          pipelined=bool(pipeline_stages))
@@ -611,7 +657,7 @@ def run_chain(
     t0 = time.perf_counter()
     per_batch = mempipe.run_stage_pipelined(
         stage_fns,
-        _chain_batch_inputs(chain, E, n, seed, inputs),
+        _chain_batch_inputs(chain, E, n, seed, inputs, tracer=tracer),
         stage_fn=stage_batch,
         depths=depths,
         reduce_fn=reduce_fn,
@@ -636,16 +682,23 @@ def run_chain(
     checksums: Dict[str, float] = {q: 0.0 for q in out_names}
     outputs: Optional[Dict[str, np.ndarray]] = None
     if collect_outputs:
+        sp = tracer.begin("concat", CAT_CONCAT, HOST_TRACK) if tracer else None
         outputs = {
             q: np.concatenate([np.asarray(b[q]) for b in per_batch])
             for q in out_names
         }
+        if sp is not None:
+            tracer.end(sp)
+    sp = tracer.begin("checksum", CAT_CHECKSUM, HOST_TRACK) if tracer else None
+    if outputs is not None:
         for q in out_names:
             checksums[q] = float(np.sum(outputs[q], dtype=np.float64))
     else:
         for b in per_batch:
             for q, v in b.items():
                 checksums[q] += float(v)
+    if sp is not None:
+        tracer.end(sp)
     return ChainResult(
         batches=n, elements=n * E, wall_s=wall, checksums=checksums,
         plan=plan, outputs=outputs, pipelined_stages=bool(pipeline_stages),

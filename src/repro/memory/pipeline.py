@@ -51,6 +51,7 @@ _CAT_SLOT = "slot"
 _CAT_DISPATCH = "dispatch"
 _CAT_HANDOFF = "handoff"
 _CAT_STAGE_HOST = "stage-host"
+_CAT_RETIRE = "retire"
 _CAT_SYNC = "sync"
 _HOST_TRACK = 0
 
@@ -283,8 +284,9 @@ def run_stage_pipelined(
     ``tracer`` (``repro.trace.Tracer``; None/NULL = off) gives each
     stage its own track: every (stage, batch) dispatch becomes a *slot*
     span carrying ``stage``/``batch``/``tick`` args, with the reshard
-    handoff and the stage-fn dispatch as nested children; host staging
-    and retire syncs land on the host track.  ``monitor`` (a
+    handoff and the stage-fn dispatch as nested children; host staging,
+    each retire's ``reduce_fn`` and the syncs after it land on the host
+    track.  ``monitor`` (a
     ``runtime.StepMonitor``) is fed the wall time between consecutive
     batch retirements; flagged steps annotate the retire's sync span
     with ``straggler=True``.  Both only observe -- per-batch results are
@@ -590,9 +592,16 @@ class StagePipelineDriver:
         if isinstance(carry, _Poison):
             self._out.append((k, carry.error))
             return
+        tracer = self.tracer
         try:
-            value = (self.reduce_fn(carry)
-                     if self.reduce_fn is not None else carry)
+            if self.reduce_fn is None:
+                value = carry
+            elif tracer:
+                with tracer.span(f"retire b{k}", _CAT_RETIRE, _HOST_TRACK,
+                                 batch=k):
+                    value = self.reduce_fn(carry)
+            else:
+                value = self.reduce_fn(carry)
         except Exception as e:
             if not self.capture_errors:
                 raise

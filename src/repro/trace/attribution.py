@@ -23,17 +23,27 @@ from .tracer import HOST_TRACK, Tracer
 
 # -- the span vocabulary the executors emit ---------------------------------
 CAT_RUN = "run"            # root span, host track
-CAT_STAGE = "stage"        # per-stage umbrella span, track 1+i
 CAT_SLOT = "slot"          # one (stage, batch) dispatch slot
 CAT_DISPATCH = "dispatch"  # the stage-fn call inside a slot
 CAT_HANDOFF = "handoff"    # cross-group reshard inside a slot
 CAT_STAGE_HOST = "stage-host"  # host-side staging of one batch
+CAT_RETIRE = "retire"      # the reduce_fn retiring a batch
 CAT_SYNC = "sync"          # host sync (device_get) retiring a batch
 CAT_REQUEST = "request"    # serve-layer per-request span, submit->finish
+#: ``run_chain``'s own host work around the pipeline, on the host track.
+CAT_PREPARE = "prepare"    # entry to the root span: plan check, shared
+                           # operands put on the device, stage callables
+CAT_SLICE = "slice"        # one batch's host inputs sliced or generated
+CAT_WAIT = "wait"          # inside retire: a batch's outputs computed
+CAT_TRANSFER = "transfer"  # inside retire: its outputs brought to the host
+CAT_CONCAT = "concat"      # the collected outputs concatenated
+CAT_CHECKSUM = "checksum"  # the per-output checksums of one call
 
 #: Counter names (``Tracer.bump`` series).
 COUNTER_CHANNEL_BYTES = "channel_bytes"
 COUNTER_PAD_ELEMENTS = "pad_elements"
+#: bytes of outputs brought back to the host, by qualified output name
+COUNTER_HOST_OUT_BYTES = "host_out_bytes"
 COUNTER_OCCUPANCY = "cu_occupancy"
 #: Serving-layer series (``repro.serve``).  All cumulative, like every
 #: counter here: queue depth at time t is submitted - admitted, plan-
@@ -92,7 +102,7 @@ class Attribution:
     wall_s: float
     n_batches: int
     pred_s_per_batch: float
-    host_s: float               # staging + retire syncs on the host track
+    host_s: float               # staging, retires and syncs on the host track
     fill_s: float               # slot time inside the fill/drain window
     stages: List[StageAttribution]
     #: end-of-run counter totals (str channel id -> bytes)
@@ -172,7 +182,7 @@ def attribute(tracer: Tracer, plan) -> Attribution:
 
     host_s = sum(
         s.duration for s in tracer.spans
-        if s.cat in (CAT_STAGE_HOST, CAT_SYNC) and not s.open
+        if s.cat in (CAT_STAGE_HOST, CAT_RETIRE, CAT_SYNC) and not s.open
     )
     fill_s = sum(s.duration for s in slots if in_fill(s))
     runs = [s for s in tracer.spans if s.cat == CAT_RUN and not s.open]

@@ -22,8 +22,10 @@ from repro.memory import chain as mchain
 from repro.memory import channels, dse
 from repro.runtime.monitor import StepMonitor
 from repro.trace.attribution import (
-    CAT_DISPATCH, CAT_SLOT, CAT_SYNC, COUNTER_CHANNEL_BYTES,
-    COUNTER_OCCUPANCY, host_channel_bytes,
+    CAT_CHECKSUM, CAT_CONCAT, CAT_DISPATCH, CAT_PREPARE, CAT_RETIRE,
+    CAT_SLICE, CAT_SLOT, CAT_SYNC, CAT_TRANSFER, CAT_WAIT,
+    COUNTER_CHANNEL_BYTES, COUNTER_HOST_OUT_BYTES, COUNTER_OCCUPANCY,
+    host_channel_bytes,
 )
 
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
@@ -170,7 +172,9 @@ def test_occupancy_counter_matches_plan(traced_run):
     }
 
 
-def test_tracer_off_is_bitwise_identical(cfd_chain):
+@pytest.mark.parametrize("collect_outputs", [False, True],
+                         ids=["checksums", "outputs"])
+def test_tracer_off_is_bitwise_identical(cfd_chain, collect_outputs):
     plan = mchain.plan_chain(
         cfd_chain, target=channels.ALVEO_U280, batch_elements=64,
         prefetch_depth=1, n_eq=128,
@@ -178,11 +182,154 @@ def test_tracer_off_is_bitwise_identical(cfd_chain):
     rng = np.random.default_rng(5)
     inputs, shared = _chain_data(cfd_chain, 128, rng)
     kw = dict(inputs=inputs, shared=shared, n_eq=128, max_batches=2,
-              pipeline_stages=True)
+              pipeline_stages=True, collect_outputs=collect_outputs)
     plain = run_chain(cfd_chain, plan, **kw)
     traced = run_chain(cfd_chain, plan, tracer=trace.Tracer(), **kw)
     nulled = run_chain(cfd_chain, plan, tracer=trace.NULL, **kw)
     assert plain.checksums == traced.checksums == nulled.checksums
+    if collect_outputs:
+        for q, want in plain.outputs.items():
+            for res in (traced, nulled):
+                assert res.outputs[q].dtype == want.dtype
+                assert res.outputs[q].tobytes() == want.tobytes(), q
+    else:
+        assert plain.outputs is traced.outputs is nulled.outputs is None
+
+
+# ---------------------------------------------------------------------------
+# run_chain's own host work: prepare, slice, retire, concat, checksum
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def collected_run(cfd_chain):
+    """A traced 3-batch run that brings every output back to the host."""
+    plan = mchain.plan_chain(
+        cfd_chain, target=channels.ALVEO_U280, batch_elements=E,
+        prefetch_depth=1, n_eq=E * N_B,
+    )
+    rng = np.random.default_rng(4)
+    inputs, shared = _chain_data(cfd_chain, E * N_B, rng)
+    tracer = trace.Tracer()
+    res = run_chain(
+        cfd_chain, plan, inputs=inputs, shared=shared, n_eq=E * N_B,
+        max_batches=N_B, pipeline_stages=True, tracer=tracer,
+        collect_outputs=True,
+    )
+    return tracer, res
+
+
+def _count(tracer, cat):
+    return len(tracer.spans_by(cat=cat, track=trace.HOST_TRACK))
+
+
+@pytest.mark.parametrize("cat,per_batch", [
+    (CAT_RETIRE, True), (CAT_WAIT, True), (CAT_TRANSFER, True),
+    (CAT_SLICE, True), (CAT_PREPARE, False), (CAT_CONCAT, False),
+    (CAT_CHECKSUM, False),
+])
+def test_collected_run_host_spans(collected_run, cat, per_batch):
+    tracer, res = collected_run
+    assert _count(tracer, cat) == (res.batches if per_batch else 1)
+
+
+def test_collected_run_retire_holds_wait_then_transfer(collected_run):
+    tracer, _ = collected_run
+    retires = tracer.spans_by(cat=CAT_RETIRE)
+    for cat in (CAT_WAIT, CAT_TRANSFER):
+        for sp in tracer.spans_by(cat=cat):
+            (outer,) = [r for r in retires
+                        if r.args["batch"] == sp.args["batch"]]
+            assert outer.t0 <= sp.t0 and sp.t1 <= outer.t1
+    for w, t in zip(tracer.spans_by(cat=CAT_WAIT),
+                    tracer.spans_by(cat=CAT_TRANSFER)):
+        assert w.args["batch"] == t.args["batch"] and w.t1 <= t.t0
+    # prepare closes before the root run span, concat and checksum
+    # follow it, in that order
+    (prep,), (root,) = (tracer.spans_by(cat=CAT_PREPARE),
+                        tracer.spans_by(cat="run"))
+    (concat,), (checksum,) = (tracer.spans_by(cat=CAT_CONCAT),
+                              tracer.spans_by(cat=CAT_CHECKSUM))
+    assert prep.t1 <= root.t0 and root.t1 <= concat.t0
+    assert concat.t1 <= checksum.t0
+
+
+def test_host_out_bytes_are_the_returned_outputs(collected_run):
+    tracer, res = collected_run
+    assert tracer.totals(COUNTER_HOST_OUT_BYTES) == {
+        q: float(v.nbytes) for q, v in res.outputs.items()
+    }
+
+
+def test_collected_run_is_lifo_and_schema_valid(collected_run):
+    tracer, _ = collected_run
+    assert tracer.open_spans() == []
+    assert trace.validate(trace.to_chrome(tracer)) == []
+
+
+def test_reduced_run_transfers_nothing(traced_run):
+    """Without ``collect_outputs`` the retire dispatches a reduction on
+    the device: no wait or transfer span, no bytes counted back."""
+    _, tracer, res = traced_run
+    assert _count(tracer, CAT_WAIT) == _count(tracer, CAT_TRANSFER) == 0
+    assert tracer.totals(COUNTER_HOST_OUT_BYTES) == {}
+    assert _count(tracer, CAT_RETIRE) == _count(tracer, CAT_SLICE) == (
+        res.batches)
+    assert _count(tracer, CAT_CHECKSUM) == _count(tracer, CAT_PREPARE) == 1
+    assert _count(tracer, CAT_CONCAT) == 0
+
+
+def test_spans_land_in_the_profiler_trace_nested(cfd_chain, tmp_path):
+    """Under ``jax.profiler``, every span of a traced run is an
+    ``@<cat>:<name>`` event on the host plane, and two events nest
+    exactly where their spans do."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    plan = mchain.plan_chain(
+        cfd_chain, target=channels.ALVEO_U280, batch_elements=64,
+        prefetch_depth=1, n_eq=128,
+    )
+    inputs, shared = _chain_data(cfd_chain, 128, np.random.default_rng(6))
+    tracer = trace.Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run_chain(cfd_chain, plan, inputs=inputs, shared=shared, n_eq=128,
+                  max_batches=2, pipeline_stages=True, tracer=tracer,
+                  collect_outputs=True)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("@"):
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    spans = sorted(tracer.spans, key=lambda s: s.t0)
+    by_label = {}
+    for s in spans:
+        by_label.setdefault(f"@{s.cat}:{s.name}", []).append(s)
+    assert sorted(events) == sorted(by_label)
+    matched = []
+    for label, group in by_label.items():
+        assert len(events[label]) == len(group), label
+        matched += zip(group, sorted(events[label]))
+
+    def holds(a, b):
+        return a[0] <= b[0] and b[1] <= a[1]
+
+    for sa, ea in matched:
+        for sb, eb in matched:
+            if sa is not sb:
+                assert holds((sa.t0, sa.t1), (sb.t0, sb.t1)) == holds(
+                    ea, eb), (sa.name, sb.name)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,10 @@ Implements the paper's section 3.1 quantities on the TPU mesh:
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
+import os
+import threading
 import time
 import warnings
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -238,7 +241,8 @@ class ChainResult:
     wall_s: float
     checksums: Dict[str, float]
     plan: Optional[memchain.ChainPlan] = None
-    #: full chain outputs, qualified "stage.output" (collect_outputs=True)
+    #: full chain outputs, qualified "stage.output" (collect_outputs=True):
+    #: one contiguous array per stream, assembled in place as batches retire
     outputs: Optional[Dict[str, np.ndarray]] = None
     #: whether stages were cross-batch pipelined (one dispatch ring per
     #: stage) or run back-to-back per batch (the serial baseline)
@@ -249,6 +253,85 @@ class ChainResult:
     #: batch indices the StepMonitor flagged as stragglers (empty when no
     #: monitor was passed or nothing was flagged)
     straggler_batches: Tuple[int, ...] = ()
+
+
+#: rows of one copy task: a few MB, so several workers share one batch
+_COPY_BLOCK_BYTES = 4 * 2 ** 20
+_copy_pool: Optional[concurrent.futures.ThreadPoolExecutor] = None
+_copy_pool_lock = threading.Lock()
+
+
+def _copy_executor() -> concurrent.futures.ThreadPoolExecutor:
+    """The process's output-copy workers, started on first use: one per
+    core but the one the dispatch thread keeps (``np.copyto`` releases
+    the GIL, so the copies run in parallel)."""
+    global _copy_pool
+    with _copy_pool_lock:
+        if _copy_pool is None:
+            _copy_pool = concurrent.futures.ThreadPoolExecutor(
+                max_workers=max(1, (os.cpu_count() or 1) - 1),
+                thread_name_prefix="run_chain-copy",
+            )
+        return _copy_pool
+
+
+class _OutputAssembly:
+    """One call's collected outputs, assembled in place as batches retire.
+
+    :meth:`put` (on the dispatch thread, after a batch's transfer) hands
+    the batch's host arrays to the copy workers, which write them into
+    rows ``[k*E, (k+1)*E)`` of one array per output stream, allocated
+    when the first batch arrives; the batch's arrays are released as its
+    copies finish.  :meth:`join` waits for the copies still in flight;
+    :meth:`abort` cancels or waits for them when the call fails, so no
+    worker outlives the call."""
+
+    def __init__(self, n_batches: int, batch_elements: int) -> None:
+        self.n = n_batches
+        self.E = batch_elements
+        self.outputs: Optional[Dict[str, np.ndarray]] = None
+        #: (qualified output, bytes, future) per copy task
+        self._copies: List[Tuple[str, int, concurrent.futures.Future]] = []
+
+    def put(self, k: int, got: Dict[str, np.ndarray]) -> None:
+        E = self.E
+        got = {q: np.asarray(v) for q, v in got.items()}
+        if self.outputs is None:
+            self.outputs = {
+                q: np.empty((self.n * E,) + v.shape[1:], v.dtype)
+                for q, v in got.items()
+            }
+        pool = _copy_executor()
+        for q, v in got.items():
+            dst = self.outputs[q][k * E:(k + 1) * E]
+            rows = max(1, _COPY_BLOCK_BYTES // max(1, v[:1].nbytes))
+            for lo in range(0, E, rows):
+                src = v[lo:lo + rows]
+                self._copies.append((q, src.nbytes, pool.submit(
+                    np.copyto, dst[lo:lo + rows], src)))
+
+    def hidden_bytes(self) -> Dict[str, float]:
+        """Per output stream, the bytes whose copy has finished."""
+        done: Dict[str, float] = {}
+        for q, nbytes, f in self._copies:
+            done[q] = done.get(q, 0.0) + (float(nbytes) if f.done() else 0.0)
+        return done
+
+    def join(self) -> Dict[str, np.ndarray]:
+        """Wait for every copy; returns the assembled outputs."""
+        concurrent.futures.wait([f for _, _, f in self._copies])
+        copies, self._copies = self._copies, []
+        for _, _, f in copies:
+            f.result()  # a failed copy raises here
+        return self.outputs
+
+    def abort(self) -> None:
+        """Cancel the copies not started and wait for the rest."""
+        for _, _, f in self._copies:
+            f.cancel()
+        concurrent.futures.wait([f for _, _, f in self._copies])
+        self._copies = []
+        self.outputs = None
 
 
 def _chain_batch_inputs(
@@ -393,11 +476,17 @@ def run_chain(
     supplies the batch-invariant operands by bare name (synthesized when
     omitted).
 
-    ``collect_outputs`` returns the concatenated chain outputs for
-    verification against an unchained reference; by default only a
-    checksum per output crosses back (the plan's host-out streams are
-    still priced -- the reduction is a measurement convenience, as in
-    ``run_simulation``).
+    ``collect_outputs`` returns the whole chain outputs, one contiguous
+    array per stream, for verification against an unchained reference;
+    by default only a checksum per output crosses back (the plan's
+    host-out streams are still priced -- the reduction is a measurement
+    convenience, as in ``run_simulation``).  The outputs are assembled in
+    place as batches retire: each retired batch's transfer stays on the
+    dispatch thread (device memory is freed at retire), and its copy
+    into the call's output rows runs on a pool of worker threads while
+    the ring goes on; the end of the call waits for the copies still in
+    flight.  Outputs and checksums are bitwise those of concatenating
+    the per-batch results.
 
     ``tracer`` (``repro.trace.Tracer``; None = off) records the full
     span hierarchy -- chain run -> per-stage slot -> dispatch/handoff --
@@ -415,6 +504,11 @@ def run_chain(
     batch, the retire's ``wait`` for and ``transfer`` of each batch's
     outputs with ``collect_outputs`` (bytes brought back in the
     ``host_out_bytes`` counter), then ``concat`` and ``checksum``.
+    ``concat`` times the join: the wait for the output copies still in
+    flight when the ring drained.  As it begins, the
+    ``host_out_hidden_bytes`` counter records, per output stream, the
+    bytes whose copy had already finished -- the share of the copy that
+    the ring's own work hid.
     """
     prepare = None
     if tracer:
@@ -422,6 +516,7 @@ def run_chain(
                                          CAT_PREPARE, CAT_TRANSFER, CAT_WAIT,
                                          COUNTER_CHANNEL_BYTES,
                                          COUNTER_HOST_OUT_BYTES,
+                                         COUNTER_HOST_OUT_HIDDEN_BYTES,
                                          COUNTER_OCCUPANCY,
                                          COUNTER_PAD_ELEMENTS,
                                          host_channel_bytes)
@@ -622,25 +717,26 @@ def run_chain(
 
         place_fns = [make_place_fn(i) for i in range(len(chain.stages))]
 
-    if collect_outputs and tracer:
+    assembly = _OutputAssembly(n, E) if collect_outputs else None
+    if collect_outputs:
         retired = [0]
 
         def reduce_fn(live):
             k = retired[0]
             retired[0] += 1
             outs = {q: live[q] for q in out_names}
-            with tracer.span(f"wait b{k}", CAT_WAIT, HOST_TRACK, batch=k):
-                jax.block_until_ready(outs)
-            with tracer.span(f"transfer b{k}", CAT_TRANSFER, HOST_TRACK,
-                             batch=k):
+            if tracer:
+                with tracer.span(f"wait b{k}", CAT_WAIT, HOST_TRACK,
+                                 batch=k):
+                    jax.block_until_ready(outs)
+                with tracer.span(f"transfer b{k}", CAT_TRANSFER, HOST_TRACK,
+                                 batch=k):
+                    got = jax.device_get(outs)
+                tracer.bump(COUNTER_HOST_OUT_BYTES,
+                            {q: float(v.nbytes) for q, v in got.items()})
+            else:
                 got = jax.device_get(outs)
-            tracer.bump(COUNTER_HOST_OUT_BYTES,
-                        {q: float(v.nbytes) for q, v in got.items()})
-            return got
-    elif collect_outputs:
-        reduce_fn = lambda live: jax.device_get(
-            {q: live[q] for q in out_names}
-        )
+            assembly.put(k, got)
     else:
         reduce_fn = lambda live: {
             q: jnp.sum(live[q]) for q in out_names
@@ -655,19 +751,24 @@ def run_chain(
                          pipelined=bool(pipeline_stages))
             if tracer else None)
     t0 = time.perf_counter()
-    per_batch = mempipe.run_stage_pipelined(
-        stage_fns,
-        _chain_batch_inputs(chain, E, n, seed, inputs, tracer=tracer),
-        stage_fn=stage_batch,
-        depths=depths,
-        reduce_fn=reduce_fn,
-        place_fns=place_fns,
-        tracer=tracer,
-        monitor=monitor,
-        stage_names=[s.name for s in chain.stages],
-        metrics=metrics,
-        metrics_labels={"plan": plan.signature[:12]} if metrics else None,
-    )
+    try:
+        per_batch = mempipe.run_stage_pipelined(
+            stage_fns,
+            _chain_batch_inputs(chain, E, n, seed, inputs, tracer=tracer),
+            stage_fn=stage_batch,
+            depths=depths,
+            reduce_fn=reduce_fn,
+            place_fns=place_fns,
+            tracer=tracer,
+            monitor=monitor,
+            stage_names=[s.name for s in chain.stages],
+            metrics=metrics,
+            metrics_labels={"plan": plan.signature[:12]} if metrics else None,
+        )
+    except BaseException:
+        if assembly is not None:
+            assembly.abort()
+        raise
     wall = time.perf_counter() - t0
     if root is not None:
         tracer.end(root)
@@ -681,12 +782,13 @@ def run_chain(
 
     checksums: Dict[str, float] = {q: 0.0 for q in out_names}
     outputs: Optional[Dict[str, np.ndarray]] = None
-    if collect_outputs:
-        sp = tracer.begin("concat", CAT_CONCAT, HOST_TRACK) if tracer else None
-        outputs = {
-            q: np.concatenate([np.asarray(b[q]) for b in per_batch])
-            for q in out_names
-        }
+    if assembly is not None:
+        sp = None
+        if tracer:
+            sp = tracer.begin("concat", CAT_CONCAT, HOST_TRACK)
+            tracer.bump(COUNTER_HOST_OUT_HIDDEN_BYTES,
+                        assembly.hidden_bytes())
+        outputs = assembly.join()
         if sp is not None:
             tracer.end(sp)
     sp = tracer.begin("checksum", CAT_CHECKSUM, HOST_TRACK) if tracer else None

@@ -36,7 +36,7 @@ CAT_PREPARE = "prepare"    # entry to the root span: plan check, shared
 CAT_SLICE = "slice"        # one batch's host inputs sliced or generated
 CAT_WAIT = "wait"          # inside retire: a batch's outputs computed
 CAT_TRANSFER = "transfer"  # inside retire: its outputs brought to the host
-CAT_CONCAT = "concat"      # the collected outputs concatenated
+CAT_CONCAT = "concat"      # the join of the collected outputs' copies
 CAT_CHECKSUM = "checksum"  # the per-output checksums of one call
 
 #: Counter names (``Tracer.bump`` series).
@@ -44,6 +44,9 @@ COUNTER_CHANNEL_BYTES = "channel_bytes"
 COUNTER_PAD_ELEMENTS = "pad_elements"
 #: bytes of outputs brought back to the host, by qualified output name
 COUNTER_HOST_OUT_BYTES = "host_out_bytes"
+#: of those, the bytes already copied into the call's outputs when the
+#: end-of-call join began, by qualified output name
+COUNTER_HOST_OUT_HIDDEN_BYTES = "host_out_hidden_bytes"
 COUNTER_OCCUPANCY = "cu_occupancy"
 #: Serving-layer series (``repro.serve``).  All cumulative, like every
 #: counter here: queue depth at time t is submitted - admitted, plan-

@@ -1,9 +1,15 @@
 """Multi-operator chain planning + the run_chain driver: residency,
 co-sized E, conflict-free placement, bitwise equivalence with the
 unchained reference, and the plan-driven Pallas block size."""
+import concurrent.futures
+import os
+import sys
+import time
+
 import numpy as np
 import pytest
 
+from repro import trace
 from repro.cfd import operators, simulation
 from repro.memory import chain as mchain
 from repro.memory import channels, dse, layout
@@ -190,23 +196,9 @@ def test_chain_per_stage_depths_and_backends(cfd_chain):
 # ---------------------------------------------------------------------------
 
 
-def test_run_chain_bitwise_matches_unchained(cfd_chain, rng):
-    """Acceptance: chained execution (intermediates resident on device)
-    is bitwise-identical at float32 to running the three compiled
-    operators separately with host round-trips between them."""
-    p, E, n_b = 5, 16, 3
-    n = E * n_b
-    chain = cfd_chain
-    inputs, shared = _chain_inputs(chain, n, p, rng)
-    plan = mchain.plan_chain(
-        chain, target=channels.CPU_HOST, batch_elements=E, n_eq=n,
-        prefetch_depth=2,
-    )
-    res = simulation.run_chain(
-        chain, plan, inputs=inputs, shared=shared, collect_outputs=True
-    )
-    assert res.batches == n_b and res.elements == n
-
+def _unchained_per_batch(chain, inputs, shared, E, n_b):
+    """Each batch's outputs from the three compiled operators run
+    separately, with host round-trips between them."""
     interp, grad, helm = (s.compiled for s in chain.stages)
     ref = {"grad.gy": [], "grad.gz": [], "helmholtz.v": []}
     for b in range(n_b):
@@ -224,6 +216,27 @@ def test_run_chain_bitwise_matches_unchained(cfd_chain, rng):
             "gx": np.asarray(g["gx"]),
         })["v"]
         ref["helmholtz.v"].append(np.asarray(hv))
+    return ref
+
+
+def test_run_chain_bitwise_matches_unchained(cfd_chain, rng):
+    """Acceptance: chained execution (intermediates resident on device)
+    is bitwise-identical at float32 to running the three compiled
+    operators separately with host round-trips between them."""
+    p, E, n_b = 5, 16, 3
+    n = E * n_b
+    chain = cfd_chain
+    inputs, shared = _chain_inputs(chain, n, p, rng)
+    plan = mchain.plan_chain(
+        chain, target=channels.CPU_HOST, batch_elements=E, n_eq=n,
+        prefetch_depth=2,
+    )
+    res = simulation.run_chain(
+        chain, plan, inputs=inputs, shared=shared, collect_outputs=True
+    )
+    assert res.batches == n_b and res.elements == n
+
+    ref = _unchained_per_batch(chain, inputs, shared, E, n_b)
     for q in ref:
         want = np.concatenate(ref[q])
         assert want.dtype == res.outputs[q].dtype == np.float32
@@ -294,6 +307,131 @@ def test_run_chain_checksums_invariant_to_prefetch(cfd_chain, rng):
     assert sums[0].keys() == sums[2].keys()
     for q in sums[0]:
         assert sums[0][q] == pytest.approx(sums[2][q], abs=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# collect_outputs: each retired batch copied into the call's outputs on
+# worker threads
+# ---------------------------------------------------------------------------
+
+
+class _DelayedExecutor:
+    """A thread pool that records every copy task and runs each one
+    ``delay_s`` late, so copies are still in flight when the ring ends."""
+
+    def __init__(self, workers: int, delay_s: float = 0.0):
+        self.pool = concurrent.futures.ThreadPoolExecutor(workers)
+        self.delay_s = delay_s
+        self.futures = []
+
+    def submit(self, fn, *args):
+        def late():
+            time.sleep(self.delay_s)
+            return fn(*args)
+
+        f = self.pool.submit(late)
+        self.futures.append(f)
+        return f
+
+
+def _collect_plan(chain, E, n, depth=1):
+    return mchain.plan_chain(
+        chain, target=channels.CPU_HOST, batch_elements=E, n_eq=n,
+        prefetch_depth=depth,
+    )
+
+
+@pytest.mark.parametrize("tracer", ["off", "on", "null"])
+def test_collected_outputs_bitwise_concatenation(cfd_chain, rng,
+                                                 monkeypatch, tracer):
+    """Outputs assembled in place from row blocks are bitwise the
+    concatenation of the per-batch reference outputs, traced or not, and
+    the checksums are the float64 sums of that concatenation."""
+    p, E, n_b = 5, 16, 5
+    inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
+    # 3 rows a copy task: every batch in 6 blocks, the last a remainder
+    monkeypatch.setattr(simulation, "_COPY_BLOCK_BYTES", 3 * 4 * p ** 3)
+    tr = {"off": None, "on": trace.Tracer(), "null": trace.NULL}[tracer]
+    res = simulation.run_chain(
+        cfd_chain, _collect_plan(cfd_chain, E, E * n_b), inputs=inputs,
+        shared=shared, collect_outputs=True, tracer=tr,
+    )
+    assert res.batches == n_b
+    ref = _unchained_per_batch(cfd_chain, inputs, shared, E, n_b)
+    assert sorted(res.outputs) == sorted(ref)
+    for q, parts in ref.items():
+        want = np.concatenate(parts)
+        got = res.outputs[q]
+        assert got.dtype == want.dtype and got.shape == want.shape, q
+        assert got.tobytes() == want.tobytes(), q
+        assert res.checksums[q] == float(np.sum(want, dtype=np.float64)), q
+
+
+def test_collected_outputs_many_workers_tiny_blocks(cfd_chain, rng,
+                                                    monkeypatch):
+    """Stress: one row a copy task on more workers than cores, with a
+    short switch interval, still assembles every row in its place."""
+    p, E, n_b = 5, 16, 4
+    inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
+    plan = _collect_plan(cfd_chain, E, E * n_b)
+    want = simulation.run_chain(cfd_chain, plan, inputs=inputs,
+                                shared=shared, collect_outputs=True)
+    ex = _DelayedExecutor(2 * (os.cpu_count() or 1) + 1)
+    monkeypatch.setattr(simulation, "_copy_executor", lambda: ex)
+    monkeypatch.setattr(simulation, "_COPY_BLOCK_BYTES", 1)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        got = simulation.run_chain(cfd_chain, plan, inputs=inputs,
+                                   shared=shared, collect_outputs=True)
+    finally:
+        sys.setswitchinterval(interval)
+        ex.pool.shutdown(wait=True)
+    assert len(ex.futures) == 3 * n_b * E  # three streams, E rows a batch
+    assert got.checksums == want.checksums
+    for q in want.outputs:
+        assert got.outputs[q].tobytes() == want.outputs[q].tobytes(), q
+
+
+def test_collected_run_failure_waits_for_copies(cfd_chain, rng, monkeypatch):
+    """A stage that raises on batch 2 propagates its exception, and only
+    after every copy of the batches retired before it has ended."""
+    p, E, n_b = 5, 16, 4
+
+    class Boom(RuntimeError):
+        pass
+
+    inner = simulation.chain_stage_fns
+
+    def failing_stage_fns(*args, **kwargs):
+        fns = inner(*args, **kwargs)
+        last = fns[-1]
+        calls = [0]
+
+        def run_stage(staged, carry):
+            calls[0] += 1
+            if calls[0] == 3:
+                raise Boom("batch 2")
+            return last(staged, carry)
+
+        return fns[:-1] + [run_stage]
+
+    ex = _DelayedExecutor(2, delay_s=0.02)
+    monkeypatch.setattr(simulation, "chain_stage_fns", failing_stage_fns)
+    monkeypatch.setattr(simulation, "_copy_executor", lambda: ex)
+    monkeypatch.setattr(simulation, "_COPY_BLOCK_BYTES", 4 * 4 * p ** 3)
+    inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
+    try:
+        with pytest.raises(Boom, match="batch 2"):
+            simulation.run_chain(
+                cfd_chain, _collect_plan(cfd_chain, E, E * n_b),
+                inputs=inputs, shared=shared, collect_outputs=True,
+            )
+        # batches 0 and 1 retired: 4 blocks of each of three streams
+        assert len(ex.futures) == 2 * 3 * 4
+        assert all(f.done() for f in ex.futures)
+    finally:
+        ex.pool.shutdown(wait=True)
 
 
 def test_run_chain_warns_on_backend_mismatch(cfd_chain, rng):

@@ -8,15 +8,17 @@ bitwise, and the Chrome JSON round-trips ``json.loads``.  The golden
 test locks the deterministic (non-timing) fields of the ``measured:``
 report section.
 """
+import concurrent.futures
 import json
 import os
 import pathlib
+import threading
 
 import numpy as np
 import pytest
 
 from repro import trace
-from repro.cfd import operators
+from repro.cfd import operators, simulation
 from repro.cfd.simulation import run_chain
 from repro.memory import chain as mchain
 from repro.memory import channels, dse
@@ -24,8 +26,8 @@ from repro.runtime.monitor import StepMonitor
 from repro.trace.attribution import (
     CAT_CHECKSUM, CAT_CONCAT, CAT_DISPATCH, CAT_PREPARE, CAT_RETIRE,
     CAT_SLICE, CAT_SLOT, CAT_SYNC, CAT_TRANSFER, CAT_WAIT,
-    COUNTER_CHANNEL_BYTES, COUNTER_HOST_OUT_BYTES, COUNTER_OCCUPANCY,
-    host_channel_bytes,
+    COUNTER_CHANNEL_BYTES, COUNTER_HOST_OUT_BYTES,
+    COUNTER_HOST_OUT_HIDDEN_BYTES, COUNTER_OCCUPANCY, host_channel_bytes,
 )
 
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
@@ -252,6 +254,10 @@ def test_collected_run_retire_holds_wait_then_transfer(collected_run):
                               tracer.spans_by(cat=CAT_CHECKSUM))
     assert prep.t1 <= root.t0 and root.t1 <= concat.t0
     assert concat.t1 <= checksum.t0
+    # concat covers the join: the hidden-bytes sample taken as it begins
+    (hidden,) = [c for c in tracer.counters
+                 if c.name == COUNTER_HOST_OUT_HIDDEN_BYTES]
+    assert concat.t0 <= hidden.t <= concat.t1
 
 
 def test_host_out_bytes_are_the_returned_outputs(collected_run):
@@ -259,6 +265,78 @@ def test_host_out_bytes_are_the_returned_outputs(collected_run):
     assert tracer.totals(COUNTER_HOST_OUT_BYTES) == {
         q: float(v.nbytes) for q, v in res.outputs.items()
     }
+
+
+def test_host_out_hidden_bytes_within_host_out_bytes(collected_run):
+    tracer, res = collected_run
+    out = tracer.totals(COUNTER_HOST_OUT_BYTES)
+    hidden = tracer.totals(COUNTER_HOST_OUT_HIDDEN_BYTES)
+    assert sorted(hidden) == sorted(out) == sorted(res.outputs)
+    for q, nbytes in out.items():
+        assert 0 <= hidden[q] <= nbytes, q
+
+
+class _InlineExecutor:
+    """Runs each copy as it is submitted."""
+
+    def submit(self, fn, *args):
+        f = concurrent.futures.Future()
+        f.set_result(fn(*args))
+        return f
+
+
+class _GatedExecutor:
+    """Holds every copy until the join opens the gate."""
+
+    def __init__(self):
+        self.gate = threading.Event()
+        self.pool = concurrent.futures.ThreadPoolExecutor(2)
+
+    def submit(self, fn, *args):
+        def gated():
+            assert self.gate.wait(timeout=60)
+            return fn(*args)
+
+        return self.pool.submit(gated)
+
+
+@pytest.mark.parametrize("copies", ["inline", "gated"])
+def test_host_out_hidden_bytes_counts_finished_copies(cfd_chain, monkeypatch,
+                                                      copies):
+    """Copies all done before the join count every byte as hidden;
+    copies all held until the join count none."""
+    plan = mchain.plan_chain(
+        cfd_chain, target=channels.ALVEO_U280, batch_elements=64,
+        prefetch_depth=1, n_eq=256,
+    )
+    inputs, shared = _chain_data(cfd_chain, 256, np.random.default_rng(8))
+    if copies == "inline":
+        ex = _InlineExecutor()
+    else:
+        ex = _GatedExecutor()
+        join = simulation._OutputAssembly.join
+
+        def open_then_join(self):
+            ex.gate.set()
+            return join(self)
+
+        monkeypatch.setattr(simulation._OutputAssembly, "join",
+                            open_then_join)
+    monkeypatch.setattr(simulation, "_copy_executor", lambda: ex)
+    tracer = trace.Tracer()
+    try:
+        res = run_chain(cfd_chain, plan, inputs=inputs, shared=shared,
+                        n_eq=256, max_batches=4, pipeline_stages=True,
+                        tracer=tracer, collect_outputs=True)
+    finally:
+        if copies == "gated":
+            ex.gate.set()
+            ex.pool.shutdown(wait=True)
+    out = tracer.totals(COUNTER_HOST_OUT_BYTES)
+    hidden = tracer.totals(COUNTER_HOST_OUT_HIDDEN_BYTES)
+    assert res.batches == 4
+    want = out if copies == "inline" else {q: 0.0 for q in out}
+    assert hidden == want
 
 
 def test_collected_run_is_lifo_and_schema_valid(collected_run):

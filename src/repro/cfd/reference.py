@@ -48,3 +48,27 @@ def gradient(Dx, Dy, Dz, u):
 def paper_flops_per_element(p: int) -> int:
     """Paper Eq. (2): N_op_el = (12p + 1) * p^3."""
     return (12 * p + 1) * p ** 3
+
+
+def ceed_bp3(B: np.ndarray, Bd: np.ndarray, G, u: np.ndarray) -> np.ndarray:
+    """CEED bake-off problem BP3, the stiffness operator at q quadrature
+    points: y = ∇ᵀ G ∇ u with ∇ = (Bd⊗B⊗B, B⊗Bd⊗B, B⊗B⊗Bd).
+
+    ``B`` and ``Bd`` (q, p) are the basis and its derivative at the
+    quadrature points; ``G`` holds the six components of the symmetric
+    3 x 3 geometric factor, (Grr, Grs, Grt, Gss, Gst, Gtt), each
+    (.., q, q, q); ``u`` is (.., p, p, p), with any leading element
+    axes.  The mode products are those of the CFDlang contraction pairs
+    [[1 6][3 7][5 8]] (to the quadrature points) and [[0 6][2 7][4 8]]
+    (back to the nodes).
+    """
+    B, Bd, u = (np.asarray(a, np.float64) for a in (B, Bd, u))
+    Grr, Grs, Grt, Gss, Gst, Gtt = (np.asarray(g, np.float64) for g in G)
+    grad = [(Bd, B, B), (B, Bd, B), (B, B, Bd)]
+    ur, us, ut = (np.einsum("il,jm,kn,...lmn->...ijk", X, Y, Z, u)
+                  for X, Y, Z in grad)
+    v = (Grr * ur + Grs * us + Grt * ut,
+         Grs * ur + Gss * us + Gst * ut,
+         Grt * ur + Gst * us + Gtt * ut)
+    return sum(np.einsum("li,mj,nk,...lmn->...ijk", X, Y, Z, vc)
+               for (X, Y, Z), vc in zip(grad, v))

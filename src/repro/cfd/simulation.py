@@ -349,7 +349,9 @@ def _chain_batch_inputs(
     otherwise a deterministic synthetic stream is generated, matching
     ``_batch_generator``'s [-1, 1] normalization.  ``tracer`` records a
     ``slice`` span around building each batch, closed before the batch
-    is yielded."""
+    is yielded, and adds the batch's bytes of every input that already
+    lives on the device (a ``jax.Array``) to the ``resident_in_bytes``
+    counter."""
     names = [
         f"{s.name}.{n}"
         for i, s in enumerate(chain.stages)
@@ -371,14 +373,22 @@ def _chain_batch_inputs(
         }
 
     if tracer:
-        from ..trace.attribution import CAT_SLICE
+        from ..trace.attribution import CAT_SLICE, COUNTER_RESIDENT_IN_BYTES
         from ..trace.tracer import HOST_TRACK
+
+        resident = [
+            q for q in names
+            if inputs is not None and isinstance(inputs[q], jax.Array)
+        ]
     for b in range(n_batches):
         if not tracer:
             yield batch(b)
             continue
         with tracer.span(f"slice b{b}", CAT_SLICE, HOST_TRACK, batch=b):
             sliced = batch(b)
+        if resident:
+            tracer.bump(COUNTER_RESIDENT_IN_BYTES,
+                        {q: float(sliced[q].nbytes) for q in resident})
         yield sliced
 
 

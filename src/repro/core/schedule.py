@@ -19,7 +19,8 @@ memory-roofline term, which ``memory.chain`` prices per stage.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Set, Tuple
+import heapq
+from typing import Dict, Iterable, List, Sequence, Set, Tuple
 
 from . import ir
 
@@ -152,6 +153,10 @@ def schedule(
 
     ``max_groups`` optionally forces further collapsing (the paper's
     1/2/3/7-compute-module experiments are reproduced by sweeping it).
+    A producer joins its consumer's group only when no path between the
+    two passes through a third group, so the groups' dataflow stays
+    acyclic even where values fan out and meet again, and the groups
+    come out in a topological order of it.
     """
     order = [n for n in prog.toposort() if not isinstance(n, ir.Input)]
     if not order:
@@ -185,6 +190,28 @@ def schedule(
 
     critical = max(group_flops(i) for i in members)
 
+    def successors(gid: int) -> Set[int]:
+        return {
+            group_of[u.uid] for m in members[gid] for u in uses.get(m.uid, [])
+        } - {gid}
+
+    def reaches_through_third(src: int, dst: int) -> bool:
+        """A path src -> .. -> dst through some other group: merging
+        src and dst would then put that group both before and after
+        the merged one."""
+        seen: Set[int] = set()
+        todo = [g for g in successors(src) if g != dst]
+        while todo:
+            g = todo.pop()
+            if g in seen:
+                continue
+            seen.add(g)
+            nxt = successors(g)
+            if dst in nxt:
+                return True
+            todo.extend(nxt - seen)
+        return False
+
     # --- collapse chains: producer feeding a single consumer -------------
     def try_merge(budget_flops: int) -> bool:
         merged_any = False
@@ -195,6 +222,8 @@ def schedule(
             if len(distinct) != 1 or n.uid in outputs:
                 continue
             tgt = distinct.pop()
+            if reaches_through_third(gid, tgt):
+                continue  # the group graph would gain a cycle
             combined_flops = group_flops(gid) + group_flops(tgt)
             if combined_flops > budget_flops:
                 continue
@@ -229,11 +258,18 @@ def schedule(
                     break
 
     # --- build Group objects in topo order --------------------------------
-    gids_in_order: List[int] = []
+    first_seen: List[int] = []
     for n in order:
         gid = group_of[n.uid]
-        if gid not in gids_in_order:
-            gids_in_order.append(gid)
+        if gid not in first_seen:
+            first_seen.append(gid)
+    rank = {gid: i for i, gid in enumerate(first_seen)}
+    gids_in_order = [
+        first_seen[i] for i in topological_order(
+            len(first_seen),
+            ((rank[g], rank[h]) for g in first_seen for h in successors(g)),
+        )
+    ]
 
     groups: List[Group] = []
     for idx, gid in enumerate(gids_in_order):
@@ -268,6 +304,33 @@ def schedule(
     )
 
 
+def topological_order(n: int,
+                      edges: Iterable[Tuple[int, int]]) -> List[int]:
+    """``range(n)`` ordered so that every edge ``(a, b)`` puts ``a``
+    before ``b``; among the items free to go next, the lowest index goes
+    first, so an order that already respects the edges is kept as it
+    is.  Raises ``ValueError`` on a cycle."""
+    succ: Dict[int, Set[int]] = {i: set() for i in range(n)}
+    indegree = [0] * n
+    for a, b in edges:
+        if b not in succ[a]:
+            succ[a].add(b)
+            indegree[b] += 1
+    ready = [i for i in range(n) if not indegree[i]]
+    heapq.heapify(ready)
+    out: List[int] = []
+    while ready:
+        i = heapq.heappop(ready)
+        out.append(i)
+        for j in succ[i]:
+            indegree[j] -= 1
+            if not indegree[j]:
+                heapq.heappush(ready, j)
+    if len(out) != n:
+        raise ValueError("the groups' dataflow graph has a cycle")
+    return out
+
+
 def stage_partition(sched: Schedule) -> List[List[ir.Node]]:
     """Scheduled groups as pipeline-stage node lists (the ``repro.flow``
     stage-extraction hook).
@@ -280,8 +343,10 @@ def stage_partition(sched: Schedule) -> List[List[ir.Node]]:
     consumer would leave the later consumers reading an element-free
     cross-stage stream, which the flow rejects (it pipelines element
     streams only).  The recompute is batch-invariant and tiny, exactly
-    the paper's precomputed-operand case.  Node order inside each stage
-    follows the program's topological order.
+    the paper's precomputed-operand case.  Stages keep the groups'
+    order, which :func:`schedule` makes a topological order of their
+    dataflow, so every stage's producers come before it; node order
+    inside each stage follows the program's topological order.
     """
     prog = sched.program
     elem_dep = prog.element_dependent_uids()

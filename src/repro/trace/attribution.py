@@ -47,6 +47,9 @@ COUNTER_HOST_OUT_BYTES = "host_out_bytes"
 #: of those, the bytes already copied into the call's outputs when the
 #: end-of-call join began, by qualified output name
 COUNTER_HOST_OUT_HIDDEN_BYTES = "host_out_hidden_bytes"
+#: bytes each batch's stages take from inputs that already live on the
+#: device (no host link crossed), by qualified input name
+COUNTER_RESIDENT_IN_BYTES = "resident_in_bytes"
 COUNTER_OCCUPANCY = "cu_occupancy"
 #: Serving-layer series (``repro.serve``).  All cumulative, like every
 #: counter here: queue depth at time t is submitted - admitted, plan-

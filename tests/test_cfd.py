@@ -82,3 +82,83 @@ def test_batch_for_channel_matches_paper_sizing():
 def test_opcount_model():
     assert reference.paper_flops_per_element(11) == 177023
     assert reference.paper_flops_per_element(7) == 29155
+
+
+# ---------------------------------------------------------------------------
+# CEED bake-off problem BP3 through the flow, every staging
+# ---------------------------------------------------------------------------
+
+BP3_G = ("Grr", "Grs", "Grt", "Gss", "Gst", "Gtt")
+
+
+def _bp3_source(n, q):
+    """``examples/ceed_bp3.cfd`` at n nodes and q points a direction."""
+    import pathlib
+
+    src = (pathlib.Path(__file__).parent.parent / "examples"
+           / "ceed_bp3.cfd").read_text()
+    return (src.replace("[12 11]", f"[{q} {n}]")
+            .replace("[12 12 12]", f"[{q} {q} {q}]")
+            .replace("[11 11 11]", f"[{n} {n} {n}]"))
+
+
+#: float32 with full-precision products reads ~1.2e-7 here, max |err| over
+#: max |y| (each y sums 3 q^3 products of two rounded contractions); 1e-6
+#: leaves 8x room, and a bfloat16 rounding of the stage (8 significant
+#: bits, ~2e-3 relative) fails it a thousandfold
+BP3_TOL = 1e-6
+
+
+@pytest.mark.parametrize("max_stages", [None, 1, 2, 3, 4, 5, 6, 7, 8],
+                         ids=lambda m: f"max_stages={m}")
+def test_ceed_bp3_through_flow_matches_reference(max_stages, rng):
+    import jax.numpy as jnp
+
+    from repro import flow
+    from repro.memory import channels
+
+    n, q, E, n_b = 4, 5, 8, 3
+    N = E * n_b
+    system = flow.compile(_bp3_source(n, q), name="bp3",
+                          max_stages=max_stages, target=channels.CPU_HOST,
+                          batch_elements=E, n_eq=N)
+    if max_stages is not None:
+        assert len(system.chain.stages) <= max_stages
+    B, Bd = (rng.uniform(-1, 1, (q, n)).astype(np.float32) for _ in "ab")
+    by_var = {"u": rng.uniform(-1, 1, (N, n, n, n)).astype(np.float32)}
+    by_var.update({g: rng.uniform(-1, 1, (N, q, q, q)).astype(np.float32)
+                   for g in BP3_G})
+    chain = system.chain
+    inputs = {f"{s.name}.{v}": by_var[v]
+              for i, s in enumerate(chain.stages)
+              for v, _ in chain.host_element_inputs(i)}
+    res = system.run(inputs=inputs, shared={"B": B, "Bd": Bd},
+                     collect_outputs=True)
+    (got,) = [a for k, a in res.outputs.items() if k.endswith(".y")]
+    want = reference.ceed_bp3(B, Bd, [by_var[g] for g in BP3_G],
+                              by_var["u"])
+    scale = np.max(np.abs(want))
+    assert np.max(np.abs(got - want)) / scale < BP3_TOL
+    rounded = np.asarray(jnp.asarray(got, jnp.bfloat16), np.float64)
+    assert np.max(np.abs(rounded - want)) / scale > 100 * BP3_TOL
+
+
+def test_ceed_bp3_reference_is_the_stiffness_form(rng):
+    """w . y(u) is the quadrature sum of (grad w) . G (grad u): the
+    stiffness form, symmetric in u and w for a symmetric G."""
+    n, q = 3, 4
+    B, Bd = rng.uniform(-1, 1, (q, n)), rng.uniform(-1, 1, (q, n))
+    u, w = rng.uniform(-1, 1, (2, n, n, n))
+    G = rng.uniform(-1, 1, (6, q, q, q))
+    rr, rs, rt, ss, st, tt = G
+    full = [[rr, rs, rt], [rs, ss, st], [rt, st, tt]]
+
+    def grad(x):
+        return [np.einsum("il,jm,kn,lmn->ijk", X, Y, Z, x)
+                for X, Y, Z in ((Bd, B, B), (B, Bd, B), (B, B, Bd))]
+
+    gw, gu = grad(w), grad(u)
+    form = sum(np.sum(gw[a] * full[a][b] * gu[b])
+               for a in range(3) for b in range(3))
+    assert np.sum(w * reference.ceed_bp3(B, Bd, G, u)) == pytest.approx(form)
+    assert np.sum(u * reference.ceed_bp3(B, Bd, G, w)) == pytest.approx(form)

@@ -442,6 +442,18 @@ def test_flow_cli_report_golden(example, capsys):
     _check_golden(f"flow_{example}.txt", out)
 
 
+def test_flow_cli_report_golden_ceed_bp3(capsys):
+    """The CLI's report of the BP3 example, whose default staging needs
+    stages in dataflow order: 20 stages, the three gradients' values
+    each read by three later stages."""
+    rc = flow.cli.main([
+        str(EXAMPLES / "ceed_bp3.cfd"), "--target", "alveo-u280",
+    ])
+    out = capsys.readouterr().out
+    assert rc == 0
+    _check_golden("flow_ceed_bp3.txt", out)
+
+
 def test_example_sources_match_library_constants():
     """The checked-in .cfd examples compute the library's source
     constants at p=11 (same structure, names, and element streams), so

@@ -1,4 +1,7 @@
 """Operator scheduling (dataflow groups) + Mnemosyne-style liveness."""
+import pathlib
+
+import numpy as np
 import pytest
 
 from repro.core import dsl, ir, liveness, rewrite, schedule
@@ -138,3 +141,174 @@ def test_stage_partition_duplicates_element_free_group_into_all_consumers():
     assert q_uid not in classes
     for nodes in parts:
         assert any(n.uid in elem_dep for n in nodes)
+
+
+# ---------------------------------------------------------------------------
+# branching programs: the group graph stays acyclic, stages come in order
+# ---------------------------------------------------------------------------
+
+EXAMPLES = pathlib.Path(__file__).parent.parent / "examples"
+
+
+def _bp3_source(n=4, q=5):
+    src = (EXAMPLES / "ceed_bp3.cfd").read_text()
+    return (src.replace("[12 11]", f"[{q} {n}]")
+            .replace("[12 12 12]", f"[{q} {q} {q}]")
+            .replace("[11 11 11]", f"[{n} {n} {n}]"))
+
+
+def _random_branching_program(seed, n=3):
+    """A CFDlang program whose values fan out to, and fan in from, random
+    earlier values -- contractions, products and sums of two or three
+    terms -- with its outputs computed in float64 numpy beside it."""
+    rng = np.random.default_rng(seed)
+    mats = {"S": rng.uniform(-1, 1, (n, n)), "T": rng.uniform(-1, 1, (n, n))}
+    decl = [f"var input  {m} : [{n} {n}]" for m in mats]
+    decl += [f"var input elem {v} : [{n} {n} {n}]" for v in "ab"]
+    decl += [f"var output elem {v} : [{n} {n} {n}]" for v in "yz"]
+    vals, stmts, ops = ["a", "b"], [], []
+    for i in range(int(rng.integers(6, 11))):
+        picked = [vals[j] for j in rng.choice(len(vals), 3)]
+        kind = int(rng.integers(3))
+        if kind == 0:
+            m = "ST"[rng.integers(2)]
+            rhs = f"{m} # {m} # {m} # {picked[0]} . [[1 6][3 7][5 8]]"
+            ops.append(("contract", m, picked[:1]))
+        elif kind == 1:
+            rhs = " * ".join(picked[:2])
+            ops.append(("mul", None, picked[:2]))
+        else:
+            terms = picked[:int(rng.integers(2, 4))]
+            rhs = " + ".join(terms)
+            ops.append(("add", None, terms))
+        decl.append(f"var x{i} : [{n} {n} {n}]")
+        stmts.append(f"x{i} = {rhs}")
+        vals.append(f"x{i}")
+    last, mid = vals[-3:], vals[len(vals) // 2]
+    stmts.append(f"y = {' + '.join(last)}")
+    stmts.append(f"z = S # S # S # {mid} . [[0 6][2 7][4 8]]")
+
+    def outputs(a, b):
+        env = {"a": a, "b": b}
+        for i, (kind, m, args) in enumerate(ops):
+            xs = [env[v] for v in args]
+            if kind == "contract":
+                M = mats[m]
+                env[f"x{i}"] = np.einsum("il,jm,kn,elmn->eijk", M, M, M,
+                                         xs[0])
+            elif kind == "mul":
+                env[f"x{i}"] = xs[0] * xs[1]
+            else:
+                env[f"x{i}"] = sum(xs)
+        S = mats["S"]
+        return {"y": sum(env[v] for v in last),
+                "z": np.einsum("li,mj,nk,elmn->eijk", S, S, S, env[mid])}
+
+    return "\n".join(decl + stmts) + "\n", mats, outputs
+
+
+def _assert_stages_in_dataflow_order(system):
+    """Every group's operands come from itself or an earlier group, and
+    every stage's bound inputs from an earlier stage."""
+    seen = set()
+    for g in system.schedule.groups:
+        mine = {n.uid for n in g.nodes}
+        for n in g.nodes:
+            for op in n.operands():
+                assert isinstance(op, ir.Input) or op.uid in seen | mine
+        seen |= mine
+    chain = system.chain
+    for i in range(len(chain.stages)):
+        for producer, _ in chain.resolved[i].values():
+            assert producer < i
+
+
+STAGINGS = [None, 1, 2, 3, 4, 5, 6, 7, 8]
+
+
+@pytest.mark.parametrize("program", ["bp3"] + [f"random-{s}"
+                                                for s in range(12)])
+def test_branching_stage_graph_is_acyclic(program):
+    from repro import flow
+    from repro.memory import channels
+
+    if program == "bp3":
+        src, run_with = _bp3_source(), None
+    else:
+        src, mats, outputs = _random_branching_program(
+            int(program.split("-")[1]))
+        run_with = (mats, outputs)
+    for max_stages in STAGINGS:
+        system = flow.compile(src, max_stages=max_stages,
+                              target=channels.CPU_HOST, batch_elements=4,
+                              n_eq=8)
+        _assert_stages_in_dataflow_order(system)
+        if run_with is None and max_stages is not None:
+            # BP3 reaches every cap; a value that feeds two sibling
+            # groups keeps them apart, so a random program may not
+            assert len(system.chain.stages) <= max_stages
+        if run_with is None or max_stages not in (None, 2):
+            continue
+        mats, outputs = run_with
+        rng = np.random.default_rng(7)
+        ab = {v: rng.uniform(-1, 1, (8, 3, 3, 3)).astype(np.float32)
+              for v in "ab"}
+        chain = system.chain
+        inputs = {f"{s.name}.{v}": ab[v]
+                  for i, s in enumerate(chain.stages)
+                  for v, _ in chain.host_element_inputs(i)}
+        res = system.run(inputs=inputs, collect_outputs=True,
+                         shared={m: a.astype(np.float32)
+                                 for m, a in mats.items()})
+        want = outputs(*(ab[v].astype(np.float64) for v in "ab"))
+        for name, w in want.items():
+            (got,) = [a for q, a in res.outputs.items()
+                      if q.endswith("." + name)]
+            assert np.max(np.abs(got - w)) <= 1e-5 * np.max(np.abs(w))
+
+
+#: the stage split the paper's programs have always had: each stage's
+#: host or bound inputs and its outputs
+PAPER_SPLITS = {
+    ("cfd_pipeline", None): [
+        (["u"], ["t0"]), (["s0.t0"], ["t1"]), (["s1.t1"], ["w"]),
+        (["s2.w"], ["gy"]), (["s2.w"], ["gz"]), (["s2.w"], ["gx"]),
+        (["s5.gx"], ["t2"]), (["s6.t2"], ["t3"]), (["s7.t3"], ["t"]),
+        (["D", "s8.t"], ["r"]), (["s9.r"], ["t4"]), (["s10.t4"], ["t5"]),
+        (["s11.t5"], ["v"]),
+    ],
+    ("inverse_helmholtz", None): [
+        (["u"], ["t0"]), (["s0.t0"], ["t1"]), (["s1.t1"], ["t"]),
+        (["D", "s2.t"], ["r"]), (["s3.r"], ["t2"]), (["s4.t2"], ["t3"]),
+        (["s5.t3"], ["v"]),
+    ],
+    ("inverse_helmholtz", 2): [(["D", "u"], ["r"]), (["s0.r"], ["v"])],
+    ("inverse_helmholtz", 1): [(["D", "u"], ["v"])],
+}
+
+
+@pytest.mark.parametrize("example,max_stages", sorted(
+    PAPER_SPLITS, key=str), ids=lambda x: str(x))
+def test_paper_programs_keep_their_stage_split(example, max_stages):
+    from repro import flow
+
+    system = flow.compile((EXAMPLES / f"{example}.cfd").read_text(),
+                          max_stages=max_stages, target="tpu-v5e",
+                          n_eq=1 << 16)
+    chain = system.chain
+    got = [
+        (sorted(v for v, _ in chain.host_element_inputs(i))
+         + sorted(f"{chain.stages[p].name}.{o}"
+                  for p, o in chain.resolved[i].values()),
+         sorted(s.program.outputs))
+        for i, s in enumerate(chain.stages)
+    ]
+    assert got == PAPER_SPLITS[(example, max_stages)]
+
+
+def test_topological_order_keeps_an_order_that_already_holds():
+    assert schedule.topological_order(4, [(0, 1), (1, 3), (2, 3)]) == [
+        0, 1, 2, 3]
+    assert schedule.topological_order(3, [(2, 0), (2, 1)]) == [2, 0, 1]
+    with pytest.raises(ValueError):
+        schedule.topological_order(2, [(0, 1), (1, 0)])

@@ -27,7 +27,8 @@ from repro.trace.attribution import (
     CAT_CHECKSUM, CAT_CONCAT, CAT_DISPATCH, CAT_PREPARE, CAT_RETIRE,
     CAT_SLICE, CAT_SLOT, CAT_SYNC, CAT_TRANSFER, CAT_WAIT,
     COUNTER_CHANNEL_BYTES, COUNTER_HOST_OUT_BYTES,
-    COUNTER_HOST_OUT_HIDDEN_BYTES, COUNTER_OCCUPANCY, host_channel_bytes,
+    COUNTER_HOST_OUT_HIDDEN_BYTES, COUNTER_OCCUPANCY,
+    COUNTER_RESIDENT_IN_BYTES, host_channel_bytes,
 )
 
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
@@ -355,6 +356,58 @@ def test_reduced_run_transfers_nothing(traced_run):
         res.batches)
     assert _count(tracer, CAT_CHECKSUM) == _count(tracer, CAT_PREPARE) == 1
     assert _count(tracer, CAT_CONCAT) == 0
+
+
+def _resident_runs(cfd_chain, on_device, collect_outputs=False):
+    """The same 3-batch chain run plain and traced, with the inputs named
+    in ``on_device`` put on the device first."""
+    import jax
+
+    plan = mchain.plan_chain(
+        cfd_chain, target=channels.ALVEO_U280, batch_elements=E,
+        prefetch_depth=1, n_eq=E * N_B,
+    )
+    rng = np.random.default_rng(6)
+    host, shared = _chain_data(cfd_chain, E * N_B, rng)
+    inputs = {q: jax.device_put(a) if q in on_device else a
+              for q, a in host.items()}
+    kw = dict(inputs=inputs, shared=shared, n_eq=E * N_B, max_batches=N_B,
+              pipeline_stages=True, collect_outputs=collect_outputs)
+    tracer = trace.Tracer()
+    return (host, run_chain(cfd_chain, plan, **kw),
+            run_chain(cfd_chain, plan, tracer=tracer, **kw), tracer)
+
+
+@pytest.mark.parametrize("on_device", [("interp.u", "helmholtz.D"),
+                                       ("helmholtz.D",)],
+                         ids=["all", "one"])
+def test_resident_in_bytes_are_each_batch_of_the_device_inputs(cfd_chain,
+                                                              on_device):
+    host, _, _, tracer = _resident_runs(cfd_chain, on_device)
+    assert tracer.totals(COUNTER_RESIDENT_IN_BYTES) == {
+        q: float(sum(host[q][b * E:(b + 1) * E].nbytes for b in range(N_B)))
+        for q in on_device
+    }
+    assert trace.validate(trace.to_chrome(tracer)) == []
+
+
+def test_host_inputs_count_no_resident_bytes(traced_run):
+    _, tracer, _ = traced_run
+    assert tracer.totals(COUNTER_RESIDENT_IN_BYTES) == {}
+    assert not [c for c in tracer.counters
+                if c.name == COUNTER_RESIDENT_IN_BYTES]
+
+
+@pytest.mark.parametrize("collect_outputs", [False, True],
+                         ids=["checksums", "outputs"])
+def test_tracer_off_is_bitwise_identical_on_resident_inputs(cfd_chain,
+                                                            collect_outputs):
+    _, plain, traced, _ = _resident_runs(
+        cfd_chain, ("interp.u", "helmholtz.D"), collect_outputs)
+    assert plain.checksums == traced.checksums
+    if collect_outputs:
+        for q, want in plain.outputs.items():
+            assert traced.outputs[q].tobytes() == want.tobytes(), q
 
 
 def test_spans_land_in_the_profiler_trace_nested(cfd_chain, tmp_path):

@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import functools
+import math
 import os
 import threading
 import time
@@ -341,17 +343,21 @@ def _chain_batch_inputs(
     seed: int,
     inputs: Optional[Dict[str, np.ndarray]],
     *,
+    in_place: frozenset = frozenset(),
     tracer=None,
 ) -> Iterator[Dict[str, np.ndarray]]:
     """Per-batch host-streamed inputs, qualified "stage.input".
 
     ``inputs`` supplies full arrays (element-axis leading) to slice;
     otherwise a deterministic synthetic stream is generated, matching
-    ``_batch_generator``'s [-1, 1] normalization.  ``tracer`` records a
-    ``slice`` span around building each batch, closed before the batch
-    is yielded, and adds the batch's bytes of every input that already
-    lives on the device (a ``jax.Array``) to the ``resident_in_bytes``
-    counter."""
+    ``_batch_generator``'s [-1, 1] normalization.  A stream in
+    ``in_place`` is not sliced: its stage program reads the batch from
+    the whole array, so the batch holds only its start row.  ``tracer``
+    records a ``slice`` span around building each batch, closed before
+    the batch is yielded, and adds the batch's bytes of every input that
+    already lives on the device (a ``jax.Array``) to the
+    ``resident_in_bytes`` counter, and one batch of each stream read in
+    place to the ``resident_in_place`` counter."""
     names = [
         f"{s.name}.{n}"
         for i, s in enumerate(chain.stages)
@@ -365,7 +371,10 @@ def _chain_batch_inputs(
 
     def batch(b: int) -> Dict[str, np.ndarray]:
         if inputs is not None:
-            return {q: inputs[q][b * E:(b + 1) * E] for q in names}
+            return {
+                q: b * E if q in in_place else inputs[q][b * E:(b + 1) * E]
+                for q in names
+            }
         rng = np.random.default_rng(seed + b)
         return {
             q: rng.uniform(-1, 1, (E,) + shapes[q]).astype(np.float32)
@@ -373,23 +382,46 @@ def _chain_batch_inputs(
         }
 
     if tracer:
-        from ..trace.attribution import CAT_SLICE, COUNTER_RESIDENT_IN_BYTES
+        from ..trace.attribution import (CAT_SLICE, COUNTER_RESIDENT_IN_BYTES,
+                                         COUNTER_RESIDENT_IN_PLACE)
         from ..trace.tracer import HOST_TRACK
 
-        resident = [
-            q for q in names
+        # from shapes: a stream read in place is never sliced here
+        resident_bytes = {
+            q: float(E * math.prod(inputs[q].shape[1:])
+                     * inputs[q].dtype.itemsize)
+            for q in names
             if inputs is not None and isinstance(inputs[q], jax.Array)
-        ]
+        }
+        read_in_place = {q: 1.0 for q in names if q in in_place}
     for b in range(n_batches):
         if not tracer:
             yield batch(b)
             continue
         with tracer.span(f"slice b{b}", CAT_SLICE, HOST_TRACK, batch=b):
-            sliced = batch(b)
-        if resident:
-            tracer.bump(COUNTER_RESIDENT_IN_BYTES,
-                        {q: float(sliced[q].nbytes) for q in resident})
-        yield sliced
+            item = batch(b)
+        if resident_bytes:
+            tracer.bump(COUNTER_RESIDENT_IN_BYTES, resident_bytes)
+        if read_in_place:
+            tracer.bump(COUNTER_RESIDENT_IN_PLACE, read_in_place)
+        yield item
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("fn", "rows", "element_keys", "sub"))
+def _read_in_place(whole, start, env, *, fn, rows, element_keys, sub):
+    """One batch of a stage program that reads some streams in place:
+    rows ``[start, start + rows)`` of each array in ``whole`` (program
+    input name -> the whole resident array, never donated), sliced
+    inside the program, so the host dispatches nothing per stream.
+    ``start`` is traced, so one compile serves every batch; ``sub`` > 0
+    re-blocks the stage at that E after the slice."""
+    env = dict(env)
+    for name, a in whole.items():
+        env[name] = jax.lax.dynamic_slice_in_dim(a, start, rows)
+    if sub:
+        fn = mempipe.reblock_batched_fn(fn, element_keys, sub)
+    return fn(env)
 
 
 def chain_stage_fns(
@@ -399,6 +431,7 @@ def chain_stage_fns(
     *,
     stage_es: Optional[List[int]] = None,
     batch_elements: int = 0,
+    resident: Optional[List[Dict[str, jax.Array]]] = None,
 ) -> List:
     """One ring callable per stage, ``(staged, carry) -> carry``.
 
@@ -410,7 +443,11 @@ def chain_stage_fns(
     carry after its last consumer -- otherwise every batch in the
     skewed ring would hold every stream of the chain until it retires.
     ``stage_es`` gives stages that run at a smaller E than the chain's
-    ``batch_elements`` (re-blocked on device).
+    ``batch_elements`` (re-blocked on device).  ``resident[i]`` maps
+    stage i's streams that it reads in place to their whole arrays on
+    its device: ``staged`` then holds the batch's start row for each,
+    and the stage runs as one program that slices ``batch_elements``
+    rows of each and calls the stage's own.
     """
     n = len(chain.stages)
     keep_after = []
@@ -423,15 +460,26 @@ def chain_stage_fns(
 
     def make_stage_fn(i: int, s: memchain.ChainStage):
         batched_fn = s.compiled.batched_fn
-        if stage_es and 0 < stage_es[i] < batch_elements:
+        element_keys = tuple(s.program.element_vars)
+        sub = (stage_es[i] if stage_es and 0 < stage_es[i] < batch_elements
+               else 0)
+        whole = resident[i] if resident else {}
+        if whole:
+            # the reader is keyed on the stage's own program, so every
+            # call over a resident field reuses one compile
+            batched_fn = functools.partial(
+                _read_in_place, fn=batched_fn, rows=batch_elements,
+                element_keys=element_keys, sub=sub)
+        elif sub:
             batched_fn = mempipe.reblock_batched_fn(
-                batched_fn, tuple(s.program.element_vars), stage_es[i]
+                batched_fn, element_keys, sub
             )
         keep = keep_after[i]
 
         def run_stage(staged, carry):
             live: Dict[str, jax.Array] = dict(carry) if carry else {}
             env: Dict[str, jax.Array] = {}
+            start = None
             for name in s.program.inputs:
                 if name in chain.resolved[i]:
                     p_idx, out_name = chain.resolved[i][name]
@@ -440,9 +488,12 @@ def chain_stage_fns(
                     ]
                 elif name in shared_for_stage[i]:
                     env[name] = shared_for_stage[i][name]
+                elif name in whole:
+                    start = staged.pop(f"{s.name}.{name}")
                 else:
                     env[name] = staged.pop(f"{s.name}.{name}")
-            outs = batched_fn(env)
+            outs = (batched_fn(whole, start, env) if whole
+                    else batched_fn(env))
             for out_name, val in outs.items():
                 live[f"{s.name}.{out_name}"] = val
             return {q: v for q, v in live.items() if q in keep}
@@ -484,7 +535,11 @@ def run_chain(
     Host-streamed inputs come from ``inputs`` (full arrays, qualified
     "stage.input") or a deterministic synthetic stream; ``shared``
     supplies the batch-invariant operands by bare name (synthesized when
-    omitted).
+    omitted).  An input that is a ``jax.Array`` on the one device of
+    the stage that consumes it is read in place: each batch hands that
+    stage only its start row, and the stage program slices the batch
+    out of the whole array itself, which it never donates.  Every other
+    input is sliced on the host and put on its stage's devices.
 
     ``collect_outputs`` returns the whole chain outputs, one contiguous
     array per stream, for verification against an unchained reference;
@@ -662,9 +717,23 @@ def run_chain(
         for n, _ in chain.host_element_inputs(i)
     }
 
+    # a device array already on its consuming stage's one device is read
+    # in place by the stage program: no slice, no staging
+    in_place = frozenset(
+        q for q, i in owner.items()
+        if inputs is not None and isinstance(inputs[q], jax.Array)
+        and len(stage_elem[i].device_set) == 1
+        and inputs[q].sharding.device_set == stage_elem[i].device_set
+    )
+    resident = [
+        {n: inputs[f"{s.name}.{n}"] for n, _ in chain.host_element_inputs(i)
+         if f"{s.name}.{n}" in in_place}
+        for i, s in enumerate(chain.stages)
+    ]
+
     def stage_batch(batch):
         return {
-            k: jax.device_put(v, stage_elem[owner[k]])
+            k: v if k in in_place else jax.device_put(v, stage_elem[owner[k]])
             for k, v in batch.items()
         }
 
@@ -701,7 +770,8 @@ def run_chain(
     if len(stage_es) != len(chain.stages):
         stage_es = [E] * len(chain.stages)
     stage_fns = chain_stage_fns(chain, shared_for_stage, out_names,
-                                stage_es=stage_es, batch_elements=E)
+                                stage_es=stage_es, batch_elements=E,
+                                resident=resident)
 
     # multi-group handoff: before stage i consumes a batch, reshard the
     # HBM-resident streams it reads from producers on *other* groups
@@ -764,7 +834,8 @@ def run_chain(
     try:
         per_batch = mempipe.run_stage_pipelined(
             stage_fns,
-            _chain_batch_inputs(chain, E, n, seed, inputs, tracer=tracer),
+            _chain_batch_inputs(chain, E, n, seed, inputs,
+                                in_place=in_place, tracer=tracer),
             stage_fn=stage_batch,
             depths=depths,
             reduce_fn=reduce_fn,

@@ -50,6 +50,10 @@ COUNTER_HOST_OUT_HIDDEN_BYTES = "host_out_hidden_bytes"
 #: bytes each batch's stages take from inputs that already live on the
 #: device (no host link crossed), by qualified input name
 COUNTER_RESIDENT_IN_BYTES = "resident_in_bytes"
+#: of those inputs, the batches a stage program sliced out of the whole
+#: array itself (nothing sliced or staged on the host), by qualified
+#: input name
+COUNTER_RESIDENT_IN_PLACE = "resident_in_place"
 COUNTER_OCCUPANCY = "cu_occupancy"
 #: Serving-layer series (``repro.serve``).  All cumulative, like every
 #: counter here: queue depth at time t is submitted - admitted, plan-

@@ -501,6 +501,99 @@ def test_run_chain_auto_e_bounded_by_inputs(cfd_chain, rng):
     assert res.elements <= n
 
 
+# ---------------------------------------------------------------------------
+# run_chain on inputs that already live on the device
+# ---------------------------------------------------------------------------
+
+
+def _resident_case(case, rng):
+    """(chain, plan, host inputs, shared, the streams to put on the
+    device) for one chain shape."""
+    from repro import flow
+    from test_cfd import _bp3_source
+
+    if case == "reblocked":
+        chain, E, n = operators.build_cfd_chain(5), 16, 48
+        inputs, shared = _chain_inputs(chain, n, 5, rng)
+        plan = mchain.plan_chain(
+            chain, target=channels.CPU_HOST, batch_elements=E, n_eq=n,
+            prefetch_depth=1, stage_batch_elements=[E // 2, E, E // 4],
+        )
+        assert plan.stage_e(0) < E and plan.stage_e(2) < E
+        return chain, plan, inputs, shared, set(inputs)
+    if case == "bp3":
+        n, q, E, n_b = 4, 5, 8, 3
+        system = flow.compile(_bp3_source(n, q), name="bp3", max_stages=1,
+                              target=channels.CPU_HOST, batch_elements=E,
+                              n_eq=E * n_b)
+        shared = {v: rng.uniform(-1, 1, (q, n)).astype(np.float32)
+                  for v in ("B", "Bd")}
+    else:  # the paper's 13-stage pipeline
+        E, n_b = 4, 3
+        system = flow.compile(operators.CFD_PIPELINE_SRC.format(p=3),
+                              target=channels.CPU_HOST, batch_elements=E,
+                              n_eq=E * n_b)
+        shared = {name: rng.uniform(-1, 1, node.shape).astype(np.float32)
+                  for name, node in system.chain.shared_operands().items()}
+    chain = system.chain
+    inputs = {
+        f"{s.name}.{v}": rng.uniform(
+            -1, 1, (E * n_b,) + tuple(node.shape)).astype(np.float32)
+        for i, s in enumerate(chain.stages)
+        for v, node in chain.host_element_inputs(i)
+    }
+    on_device = set(inputs)
+    if case == "mixed":
+        on_device = {f"{chain.stages[0].name}.u"}
+        assert on_device < set(inputs)
+    return chain, system.plan, inputs, shared, on_device
+
+
+@pytest.mark.parametrize("case", ["bp3", "pipeline13", "mixed", "reblocked"])
+def test_resident_inputs_match_host_inputs(case, rng):
+    """A run whose element inputs are single-device ``jax.Array``s, read
+    in place by the stage programs, gives bitwise the outputs and the
+    checksums (device sums and float64 sums of the outputs) of the same
+    data streamed from numpy arrays."""
+    import jax
+
+    chain, plan, host, shared, on_device = _resident_case(case, rng)
+    assert len(chain.stages) == {"bp3": 1, "reblocked": 3}.get(case, 13)
+    device = {q: jax.device_put(a) if q in on_device else a
+              for q, a in host.items()}
+    for collect in (True, False):
+        tracer = trace.Tracer()
+        want, got = (simulation.run_chain(chain, plan, inputs=ins,
+                                          shared=shared, tracer=tr,
+                                          collect_outputs=collect)
+                     for ins, tr in ((host, None), (device, tracer)))
+        assert got.batches == want.batches > 1
+        assert tracer.totals("resident_in_place") == {
+            q: float(got.batches) for q in on_device}
+        assert got.checksums == want.checksums, (case, collect)
+        if collect:
+            assert sorted(got.outputs) == sorted(want.outputs)
+            for q, a in want.outputs.items():
+                assert got.outputs[q].tobytes() == a.tobytes(), (case, q)
+
+
+def test_resident_inputs_are_not_donated_and_sweeps_repeat(rng):
+    """The whole arrays read in place survive a call unchanged, and a
+    second call over the same field gives bitwise the first's
+    checksums."""
+    import jax
+
+    chain, plan, host, shared, _ = _resident_case("bp3", rng)
+    field = {q: jax.device_put(a) for q, a in host.items()}
+    first = simulation.run_chain(chain, plan, inputs=field, shared=shared)
+    for q, a in field.items():
+        assert not a.is_deleted(), q
+        assert np.asarray(a).tobytes() == host[q].tobytes(), q
+    again = simulation.run_chain(chain, plan, inputs=field, shared=shared)
+    assert again.checksums == first.checksums
+    assert all(np.isfinite(v) for v in first.checksums.values())
+
+
 def test_plan_infeasible_when_block_floor_exceeds_vmem():
     """Even the BE=1 block must fit on-chip, or the plan says so."""
     tiny = channels.ALVEO_U280.with_(vmem_bytes=8192)

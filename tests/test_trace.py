@@ -12,6 +12,9 @@ import concurrent.futures
 import json
 import os
 import pathlib
+import subprocess
+import sys
+import textwrap
 import threading
 
 import numpy as np
@@ -28,7 +31,7 @@ from repro.trace.attribution import (
     CAT_SLICE, CAT_SLOT, CAT_SYNC, CAT_TRANSFER, CAT_WAIT,
     COUNTER_CHANNEL_BYTES, COUNTER_HOST_OUT_BYTES,
     COUNTER_HOST_OUT_HIDDEN_BYTES, COUNTER_OCCUPANCY,
-    COUNTER_RESIDENT_IN_BYTES, host_channel_bytes,
+    COUNTER_RESIDENT_IN_BYTES, COUNTER_RESIDENT_IN_PLACE, host_channel_bytes,
 )
 
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
@@ -396,6 +399,112 @@ def test_host_inputs_count_no_resident_bytes(traced_run):
     assert tracer.totals(COUNTER_RESIDENT_IN_BYTES) == {}
     assert not [c for c in tracer.counters
                 if c.name == COUNTER_RESIDENT_IN_BYTES]
+
+
+@pytest.mark.parametrize("on_device", [("interp.u", "helmholtz.D"),
+                                       ("helmholtz.D",)],
+                         ids=["all", "one"])
+def test_resident_in_place_counts_each_batch_of_the_device_inputs(
+        cfd_chain, on_device):
+    """Inputs on the stage's own device are read in place, every batch;
+    the run matches the same data streamed from numpy arrays."""
+    host, plain, traced, tracer = _resident_runs(cfd_chain, on_device)
+    assert tracer.totals(COUNTER_RESIDENT_IN_PLACE) == {
+        q: float(N_B) for q in on_device}
+    assert traced.checksums == plain.checksums
+    # the same data as _resident_runs makes, all of it from numpy
+    _, shared = _chain_data(cfd_chain, E * N_B, np.random.default_rng(6))
+    streamed = run_chain(cfd_chain, plain.plan, inputs=host, shared=shared,
+                         n_eq=E * N_B, max_batches=N_B, pipeline_stages=True)
+    assert streamed.checksums == plain.checksums
+
+
+def test_host_inputs_read_nothing_in_place(traced_run):
+    _, tracer, _ = traced_run
+    assert tracer.totals(COUNTER_RESIDENT_IN_PLACE) == {}
+    assert not [c for c in tracer.counters
+                if c.name == COUNTER_RESIDENT_IN_PLACE]
+
+
+OFF_STAGE_SCRIPT = textwrap.dedent("""
+    import json
+    import numpy as np
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import trace
+    from repro.cfd import operators, simulation
+    from repro.memory import chain as mchain
+    from repro.memory import channels
+
+    assert jax.device_count() == 2, jax.devices()
+    p, E, n_b = 5, 16, 3
+    n = E * n_b
+    chain = operators.build_cfd_chain(p)
+    rng = np.random.default_rng(0)
+    host = {
+        "interp.u": rng.uniform(-1, 1, (n, p, p, p)).astype(np.float32),
+        "helmholtz.D": rng.uniform(-1, 1, (n, p, p, p)).astype(np.float32),
+    }
+    shared = {
+        name: rng.uniform(-1, 1, node.shape).astype(np.float32)
+        for name, node in sorted(chain.shared_operands().items())
+    }
+    both, first = jax.devices(), jax.devices()[:1]
+    cases = {
+        # sharded over both devices, run on both: a multi-device stage
+        "sharded": (both, NamedSharding(simulation.element_mesh(both),
+                                        P("elements"))),
+        # on the second device, run on the first: another stage's device
+        "other_device": (first, jax.devices()[1]),
+    }
+    out = {}
+    for case, (devices, where) in cases.items():
+        mesh = simulation.element_mesh(devices)
+        plan = mchain.plan_chain(
+            chain, target=channels.CPU_HOST, batch_elements=E, n_eq=n,
+            prefetch_depth=1, cu_count=len(devices),
+        )
+        kw = dict(shared=shared, mesh=mesh, collect_outputs=True)
+        want = simulation.run_chain(chain, plan, inputs=host, **kw)
+        tracer = trace.Tracer()
+        got = simulation.run_chain(
+            chain, plan, tracer=tracer,
+            inputs={q: jax.device_put(a, where) for q, a in host.items()},
+            **kw)
+        out[case] = {
+            "batches": got.batches,
+            "equal": got.checksums == want.checksums and all(
+                got.outputs[q].tobytes() == a.tobytes()
+                for q, a in want.outputs.items()),
+            "in_place": [c.name for c in tracer.counters
+                         if c.name == "resident_in_place"],
+            "resident_bytes": tracer.totals("resident_in_bytes"),
+        }
+    print(json.dumps(out))
+""")
+
+
+def test_inputs_off_the_stage_device_keep_the_sliced_path():
+    """A device array sharded over several devices, or placed on another
+    device than its stage's, is sliced and staged as before: nothing is
+    read in place, its bytes still count as resident, and the run
+    matches the host run bitwise."""
+    from conftest import subprocess_env
+
+    res = subprocess.run(
+        [sys.executable, "-c", OFF_STAGE_SCRIPT],
+        env=subprocess_env(2), capture_output=True, text=True, timeout=420,
+    )
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert sorted(out) == ["other_device", "sharded"]
+    for case, got in out.items():
+        assert got["batches"] == N_B, case
+        assert got["equal"] is True, case
+        assert got["in_place"] == [], case
+        assert sorted(got["resident_bytes"]) == ["helmholtz.D",
+                                                 "interp.u"], case
 
 
 @pytest.mark.parametrize("collect_outputs", [False, True],

@@ -3,10 +3,7 @@
   tab2_ladder      Fig. 15 / Table 2: the optimization ladder
                    (baseline -> double-buffer -> dataflow 1/2/3/7)
   fig16_precision  Fig. 16 / Table 4: precision x polynomial degree
-  fig17_multicu    Fig. 17 / Table 5: CU replication (element-sharding)
   fig19_kernels    Fig. 19: Inverse Helmholtz / Interpolation / Gradient
-  memplan_ladder   Figs. 14-15: the same ladder driven by MemoryPlans
-                   (repro.memory), plus the machine's DSE winner
   chain_ladder     Sec. 5: the composed interpolation -> gradient ->
                    inverse-Helmholtz application planned as one
                    ProgramChain (inter-stage streams HBM-resident) vs
@@ -34,7 +31,6 @@ import numpy as np
 sys.path.insert(0, "src")
 
 from repro.cfd import operators, reference  # noqa: E402
-from repro.cfd.simulation import SimConfig, run_simulation  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.precision import POLICIES, enable_x64  # noqa: E402
 
@@ -191,28 +187,6 @@ def fig16_precision() -> None:
                      f"{flops / t / 1e9:.3f}GOPS;mse={mse:.2e}")
 
 
-def fig17_multicu() -> None:
-    """CU replication / batching: elements per dispatch (the paper's E)
-    and double-buffering on/off.  On this 1-core container replication
-    cannot reduce wall time -- the paper's own conclusion when host
-    bandwidth is the limit; the accounting structure is the deliverable."""
-    for E in (256, 512, 1024):
-        cfg = SimConfig(p=11, n_eq=4 * E, batch_elements=E)
-        run_simulation(cfg, max_batches=2)  # warm
-        res = run_simulation(cfg, max_batches=4)
-        flops = res.elements * reference.paper_flops_per_element(11)
-        _row(f"fig17/batch_{E}", res.wall_s / res.batches * 1e6,
-             f"{flops / res.wall_s / 1e9:.3f}GFLOPS")
-    for db in (False, True):
-        cfg = SimConfig(p=11, n_eq=2048, batch_elements=512,
-                        double_buffer=db)
-        run_simulation(cfg, max_batches=2)
-        res = run_simulation(cfg, max_batches=4)
-        flops = res.elements * reference.paper_flops_per_element(11)
-        _row(f"fig17/double_buffer_{db}", res.wall_s / res.batches * 1e6,
-             f"{flops / res.wall_s / 1e9:.3f}GFLOPS")
-
-
 def fig19_kernels() -> None:
     rng = np.random.default_rng(2)
     E = 512
@@ -241,63 +215,6 @@ def fig19_kernels() -> None:
         {"Dx": Dx, "Dy": Dy, "Dz": Dz, "u": ug})["gx"])
     fl_g = E * 2 * (nx * nx * ny * nz + ny * ny * nx * nz + nz * nz * nx * ny)
     _row("fig19/gradient", tg * 1e6, f"{fl_g / tg / 1e9:.3f}GFLOPS")
-
-
-def memplan_ladder() -> None:
-    """The paper's baseline -> double-buffer -> dataflow ladder, but every
-    rung generated from a MemoryPlan instead of hand-rolled driver knobs.
-    Rows report measured us/batch plus the plan's predicted us/batch; the
-    last row is the DSE winner for this machine."""
-    from repro.memory import channels as mchan, dse
-
-    target = mchan.detect_target()
-    E, n_b = 512, 8
-    n_eq = E * n_b
-    rungs = [
-        ("baseline", {"prefetch_depth": 0}),
-        ("double_buffer", {"prefetch_depth": 1}),
-        ("prefetch_4", {"prefetch_depth": 4}),
-        ("dataflow", {"prefetch_depth": 1, "backend": "staged"}),
-    ]
-    for name, kw in rungs:
-        plan = dse.make_plan(
-            11, target=target, batch_elements=E, n_eq=n_eq, **kw
-        )
-        cfg = SimConfig(
-            p=11, n_eq=n_eq, batch_elements=E,
-            backend=kw.get("backend", "xla"),
-            prefetch_depth=kw["prefetch_depth"],
-        )
-        run_simulation(cfg, plan=plan, max_batches=2)  # warm
-        # min over repetitions: robust against CPU frequency/cache drift
-        best = min(
-            (run_simulation(cfg, plan=plan, max_batches=n_b)
-             for _ in range(3)),
-            key=lambda r: r.wall_s,
-        )
-        flops = best.elements * reference.paper_flops_per_element(11)
-        _row(
-            f"memplan_ladder/{name}", best.wall_s / best.batches * 1e6,
-            f"{flops / best.wall_s / 1e9:.3f}GFLOPS;"
-            f"pred={plan.cost.t_pipelined * 1e6:.0f}us",
-        )
-    # "this machine's winner": only CU counts that exist here, and report
-    # the candidate that was actually measured (not just predicted)
-    space = dse.DesignSpace(cu_counts=(jax.device_count(),))
-    ranked = dse.explore(
-        11, target=target, n_eq=n_eq, space=space, measure_top=1
-    )
-    best = next((c for c in ranked if c.verified), ranked[0])
-    meas = best.measured_s_per_element
-    _row(
-        "memplan_ladder/dse_best",
-        (meas if meas is not None else best.predicted_s_per_element)
-        * best.plan.batch_elements * 1e6,
-        f"backend={best.plan.backend};E={best.plan.batch_elements};"
-        f"K={best.plan.prefetch_depth};CU={best.plan.cu_count};"
-        f"{'measured' if meas is not None else 'predicted-only'};"
-        f"pred={best.predicted_s_per_element * 1e6:.4f}us/elem",
-    )
 
 
 def _sharded_worker(p: int, E: int, n_b: int) -> None:
@@ -450,7 +367,7 @@ def chain_ladder() -> None:
 
     from repro.cfd.simulation import run_chain
     from repro.memory import chain as mchain
-    from repro.memory import channels as mchan, dse
+    from repro.memory import channels as mchan
 
     p, E, n_b = 7, 256, 6
     n_eq = E * n_b
@@ -484,7 +401,7 @@ def chain_ladder() -> None:
                             f"chain_ladder/{name}")
 
     # unchained baseline: each stage a separate dispatch with a host
-    # round-trip between (what three standalone MemoryPlans execute)
+    # round-trip between (what three one-stage chain plans execute)
     interp, grad, helm = (s.compiled for s in chain.stages)
 
     def unchained_batch(b):
@@ -564,15 +481,15 @@ def chain_ladder() -> None:
     )
 
     # the residency claim, in bytes: chain host streams vs the sum of
-    # three standalone plans at the same E
+    # three standalone (one-stage chain) plans at the same E
     plan = mchain.plan_chain(
         chain, target=target, batch_elements=E, prefetch_depth=1,
         n_eq=n_eq,
     )
     standalone = sum(
-        dse.make_plan(
-            s.program, target=target, batch_elements=E,
-            operator_name=s.name,
+        mchain.plan_chain(
+            mchain.ProgramChain([(s.name, s.compiled)]), target=target,
+            batch_elements=E,
         ).host_stream_bytes
         for s in chain.stages
     )
@@ -822,9 +739,7 @@ def lm_throughput() -> None:
 BENCHES = {
     "tab2_ladder": tab2_ladder,
     "fig16_precision": fig16_precision,
-    "fig17_multicu": fig17_multicu,
     "fig19_kernels": fig19_kernels,
-    "memplan_ladder": memplan_ladder,
     "chain_ladder": chain_ladder,
     "flow_ladder": flow_ladder,
     "lm_throughput": lm_throughput,

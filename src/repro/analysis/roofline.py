@@ -28,7 +28,7 @@ from typing import Dict, Optional
 
 # ---- TPU v5e target constants --------------------------------------------
 # Shared with the memory planner: repro.memory.channels.TPU_V5E is the
-# single source of truth, so roofline analysis and MemoryPlan costing can
+# single source of truth, so roofline analysis and ChainPlan costing can
 # never disagree on peak numbers.
 from ..memory.channels import TPU_V5E as _TPU_V5E
 

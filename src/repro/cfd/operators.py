@@ -11,27 +11,13 @@ the kernel dispatch that ~180 lines of builder code used to encode.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple, Union
+from typing import Optional, Tuple, Union
 
 from .. import flow
 from ..core import api, dsl
 from ..core.emit import CompiledProgram
-from ..core.precision import POLICIES
 from ..kernels.helmholtz import ops as helmholtz_ops
-from ..memory import layout
 from ..memory.chain import ChainPlan, ProgramChain
-from ..memory.plan import MemoryPlan
-
-
-def pallas_block_elements(plan: Optional[MemoryPlan] = None) -> int:
-    """Resolve the Pallas kernel's block size from a MemoryPlan.
-
-    The plan carries the VMEM-budgeted block (``block_elements``, a
-    divisor of its E); without one the kernel default stands.
-    """
-    if plan is not None and plan.block_elements:
-        return plan.block_elements
-    return helmholtz_ops.DEFAULT_BLOCK_ELEMENTS
 
 
 def build_inverse_helmholtz(
@@ -42,8 +28,6 @@ def build_inverse_helmholtz(
     optimize: bool = True,
     max_groups: Optional[int] = None,
     block_elements: Optional[int] = None,
-    plan: Optional[MemoryPlan] = None,
-    donate_args: Sequence[str] = (),
 ) -> CompiledProgram:
     """Compile the Inverse Helmholtz operator (paper Fig. 2).
 
@@ -51,21 +35,17 @@ def build_inverse_helmholtz(
       * ``xla``    -- factorized einsum chain, one jitted program.
       * ``staged`` -- one jitted stage per scheduled group (dataflow view).
       * ``pallas`` -- the fused TPU kernel (kernels/helmholtz); on CPU use
-        kernel tests' interpret mode instead.  Its ``block_elements``
-        defaults to the plan's VMEM-budgeted block when a MemoryPlan is
-        given (explicit ``block_elements`` still wins).
+        kernel tests' interpret mode instead.  ``block_elements`` is the
+        kernel's element block (a plan's VMEM-budgeted choice is
+        ``ChainPlan.stages[i].block_elements``); None keeps the kernel
+        default.
     """
     pallas_impl = None
     if backend == "pallas":
-        be = (
-            block_elements if block_elements is not None
-            else pallas_block_elements(plan)
-        )
         pallas_impl = helmholtz_ops.make_pallas_impl(
-            block_elements=be,
-            vmem_limit_bytes=(
-                layout.vmem_budget_bytes(plan.target) if plan is not None
-                else None
+            block_elements=(
+                block_elements if block_elements is not None
+                else helmholtz_ops.DEFAULT_BLOCK_ELEMENTS
             ),
         )
     return api.compile_cfdlang(
@@ -76,7 +56,6 @@ def build_inverse_helmholtz(
         backend=backend,
         max_groups=max_groups,
         pallas_impl=pallas_impl,
-        donate_args=donate_args,
     )
 
 
@@ -197,7 +176,6 @@ def build_cfd_chain(
     *,
     policy="float32",
     backends: Union[str, Tuple[str, str, str]] = "xla",
-    helmholtz_plan: Optional[MemoryPlan] = None,
     chain_plan: Optional[ChainPlan] = None,
 ) -> ProgramChain:
     """The paper's full application as one ProgramChain:
@@ -223,16 +201,9 @@ def build_cfd_chain(
     """
     blocks = {}
     blk = chain_stage_block_elements(chain_plan, "helmholtz")
-    if blk is None and helmholtz_plan is not None and (
-            helmholtz_plan.block_elements):
-        blk = helmholtz_plan.block_elements
     if blk:
         blocks["helmholtz"] = blk
     return compile_cfd_pipeline(
         p, policy=policy, backends=backends, stage_blocks=blocks
     ).chain
 
-
-def flops_per_element(p: int) -> int:
-    """Paper Eq. (2)."""
-    return (12 * p + 1) * p ** 3

@@ -5,13 +5,14 @@ Implements the paper's section 3.1 quantities on the TPU mesh:
   * **batch**: ``E`` elements processed per dispatch.  The paper sizes E
     so a batch fills one 256 MB HBM pseudo-channel; here the sizing (and
     every other memory decision) comes from an explicit
-    :class:`repro.memory.MemoryPlan` -- the driver holds no hardcoded
-    batch size.
+    :class:`repro.memory.ChainPlan` -- no batch size is hardcoded
+    here.  A single operator runs as a one-stage chain, so
+    :func:`run_chain` is the one executor.
   * **N_b = N_eq / E** batches, **I = N_b / N_cu** iterations, where the
     CU count is the number of mesh devices the element axis is sharded
     over (CU replication == data parallelism over elements).
   * **transfer pipelining**: batch k+K..k+1 transfer host->device while
-    batch k computes, through the generic K-deep engine in
+    batch k computes, through the skewed dispatch rings of
     ``repro.memory.pipeline`` (K=1 is the ping/pong channel pair of
     Fig. 14a; K=0 is the serial baseline).
 """
@@ -34,50 +35,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..memory import chain as memchain
 from ..memory import channels as memchannels
-from ..memory import dse as memdse
 from ..memory import pipeline as mempipe
 from ..memory.placement import DeviceTopology
-from ..memory.plan import MemoryPlan
-from .operators import build_inverse_helmholtz, flops_per_element
-
-
-@dataclasses.dataclass
-class SimConfig:
-    p: int = 11
-    n_eq: int = 2_000_000          # paper: 2M elements simulated
-    #: E -- None lets the MemoryPlan auto-size it from the channel model
-    batch_elements: Optional[int] = None
-    policy: str = "float32"
-    backend: str = "xla"
-    double_buffer: bool = True
-    #: K batches staged ahead; None derives it from ``double_buffer``
-    prefetch_depth: Optional[int] = None
-    seed: int = 0
-
-    @property
-    def depth(self) -> int:
-        if self.prefetch_depth is not None:
-            return self.prefetch_depth
-        return 1 if self.double_buffer else 0
-
-    @property
-    def n_batches(self) -> int:
-        if self.batch_elements is None:
-            raise ValueError(
-                "batch_elements unset -- resolve a MemoryPlan first "
-                "(simulation.plan_config) or set it explicitly"
-            )
-        return self.n_eq // self.batch_elements
-
-    def bytes_per_element(self, bytes_per_scalar: int = 4) -> int:
-        # u, D in; v out  (S shared, amortized)
-        return 3 * self.p ** 3 * bytes_per_scalar
-
-    @classmethod
-    def batch_for_channel(cls, p: int, channel_bytes: int = 256 * 2 ** 20,
-                          bytes_per_scalar: int = 4) -> int:
-        """The paper's E: elements whose I/O fits one HBM channel."""
-        return channel_bytes // (3 * p ** 3 * bytes_per_scalar)
 
 
 def element_mesh(devices=None) -> Mesh:
@@ -86,151 +45,8 @@ def element_mesh(devices=None) -> Mesh:
     return Mesh(np.array(devices), ("elements",))
 
 
-def plan_config(
-    cfg: SimConfig,
-    *,
-    target: Optional[memchannels.MemoryTarget] = None,
-    cu_count: int = 1,
-) -> MemoryPlan:
-    """Resolve the memory architecture for this simulation config.
-
-    Explicit ``cfg.batch_elements`` is honored; otherwise the planner
-    auto-sizes E against the target's pseudo-channel capacity.
-    """
-    return memdse.make_plan(
-        cfg.p,
-        target=target if target is not None else memchannels.detect_target(),
-        policy=cfg.policy,
-        backend=cfg.backend,
-        batch_elements=cfg.batch_elements,
-        prefetch_depth=cfg.depth,
-        cu_count=cu_count,
-        n_eq=cfg.n_eq,
-    )
-
-
-def _batch_generator(
-    p: int, batch_elements: int, n_batches: int, seed: int
-) -> Iterator[Dict[str, np.ndarray]]:
-    """Deterministic, resumable synthetic element stream ([-1,1] data,
-    matching the paper's range normalization)."""
-    for b in range(n_batches):
-        rng = np.random.default_rng(seed + b)
-        yield {
-            "D": rng.uniform(-1, 1, (batch_elements, p, p, p)).astype(np.float32),
-            "u": rng.uniform(-1, 1, (batch_elements, p, p, p)).astype(np.float32),
-        }
-
-
-@dataclasses.dataclass
-class SimResult:
-    batches: int
-    elements: int
-    wall_s: float
-    checksum: float
-    plan: Optional[MemoryPlan] = None
-
-    @property
-    def gflops(self) -> float:
-        return 0.0 if self.wall_s == 0 else (
-            self.elements * 1e-9 / self.wall_s
-        )
-
-
-def run_simulation(
-    cfg: SimConfig,
-    *,
-    mesh: Optional[Mesh] = None,
-    max_batches: Optional[int] = None,
-    S: Optional[np.ndarray] = None,
-    plan: Optional[MemoryPlan] = None,
-    tracer=None,
-) -> SimResult:
-    """Run the batched Inverse-Helmholtz simulation under a MemoryPlan.
-
-    The plan supplies E, the prefetch depth, and donation hints; pass one
-    explicitly (e.g. a DSE winner) or let ``plan_config`` derive it.
-    Returns wall time and a checksum; GFLOPS is derived with the paper's
-    op-count model by the caller (benchmarks/).
-
-    ``tracer`` (``repro.trace.Tracer``; None = off) records the staging/
-    dispatch/sync spans of the K-deep engine plus per-channel host byte
-    counters from the plan's buffer table.
-    """
-    mesh = mesh or element_mesh()
-    if plan is None:
-        plan = plan_config(cfg, cu_count=int(mesh.devices.size))
-    E = plan.batch_elements
-    depth = plan.prefetch_depth
-
-    # donation is an accelerator-path optimization; the CPU runtime warns
-    # and ignores it, so only forward the hint off-host.  The plan also
-    # supplies the Pallas kernel's VMEM-budgeted block_elements.
-    donate = plan.donation if jax.default_backend() != "cpu" else ()
-    compiled = build_inverse_helmholtz(
-        cfg.p, policy=cfg.policy, backend=cfg.backend, donate_args=donate,
-        plan=plan,
-    )
-    rng = np.random.default_rng(cfg.seed + 2 ** 31)
-    if S is None:
-        S = rng.uniform(-1, 1, (cfg.p, cfg.p)).astype(np.float32)
-
-    elem_sharding = NamedSharding(mesh, P("elements"))
-    repl_sharding = NamedSharding(mesh, P())
-    S_dev = jax.device_put(S, repl_sharding)
-
-    n_total = cfg.n_eq // E
-    n = n_total if max_batches is None else min(max_batches, n_total)
-
-    def stage(batch):
-        return {
-            k: jax.device_put(v, elem_sharding) for k, v in batch.items()
-        }
-
-    if tracer:
-        from ..trace.attribution import (COUNTER_CHANNEL_BYTES,
-                                         host_channel_bytes)
-
-        ch_bytes = {
-            str(c): float(b)
-            for c, b in host_channel_bytes(plan.buffers).items()
-        }
-        inner_stage = stage
-
-        def stage(batch):
-            tracer.bump(COUNTER_CHANNEL_BYTES, ch_bytes)
-            return inner_stage(batch)
-
-    def compute(staged):
-        return compiled.batched_fn({"S": S_dev, **staged})
-
-    t0 = time.perf_counter()
-    sums = mempipe.run_pipelined(
-        compute,
-        _batch_generator(cfg.p, E, n, cfg.seed),
-        stage_fn=stage,
-        depth=depth,
-        reduce_fn=lambda out: jnp.sum(out["v"]),
-        tracer=tracer,
-        stage_name=plan.operator,
-    )
-    wall = time.perf_counter() - t0
-    checksum = 0.0
-    for s in sums:
-        checksum += float(s)
-    return SimResult(
-        batches=n, elements=n * E, wall_s=wall, checksum=checksum, plan=plan
-    )
-
-
-def achieved_gflops(res: SimResult, p: int) -> float:
-    """GFLOPS under the paper's Eq. (2)-(3) accounting."""
-    n_op = res.elements * flops_per_element(p)
-    return n_op / res.wall_s / 1e9 if res.wall_s > 0 else 0.0
-
-
 # ---------------------------------------------------------------------------
-# multi-operator chain driver (interpolation -> gradient -> Helmholtz)
+# the chain executor (one stage or many: interpolation -> gradient -> Helmholtz)
 # ---------------------------------------------------------------------------
 
 
@@ -545,7 +361,7 @@ def run_chain(
     array per stream, for verification against an unchained reference;
     by default only a checksum per output crosses back (the plan's
     host-out streams are still priced -- the reduction is a measurement
-    convenience, as in ``run_simulation``).  The outputs are assembled in
+    convenience).  The outputs are assembled in
     place as batches retire: each retired batch's transfer stays on the
     dispatch thread (device memory is freed at retire), and its copy
     into the call's output rows runs on a pool of worker threads while

@@ -158,8 +158,9 @@ class CompiledProgram:
     schedule: Optional[Schedule] = None
     stage_fns: Optional[List[Callable]] = None
     backend: str = "xla"
-    #: inputs whose device buffers XLA may reuse for outputs (a
-    #: MemoryPlan hint; the driver must not reuse them after a call)
+    #: inputs whose device buffers XLA may reuse for outputs (a stage
+    #: plan's ``in`` streams qualify: each staged batch is consumed
+    #: once; the caller must not reuse them after a call)
     donate_args: Tuple[str, ...] = ()
 
     def __call__(self, **env):
@@ -181,7 +182,8 @@ def _batched_callable(
     jit: bool = True,
 ) -> Callable:
     """Batched callable; with ``jit`` the list-form function is jitted so
-    per-array donation hints (from a MemoryPlan) can be applied."""
+    per-array donation hints (a stage plan's ``in`` streams) can be
+    applied."""
     names = list(prog.inputs)
     elem = set(prog.element_vars)
     unknown = [n for n in donate_args if n not in names]
@@ -269,7 +271,7 @@ def compile_program(
     batched program as a fused kernel; used when ``backend='pallas'``.
 
     ``donate_args``: input names whose buffers XLA may alias for outputs
-    (a ``repro.memory`` MemoryPlan hint; ``xla`` backend only).
+    (a stage plan's ``in`` streams qualify; ``xla`` backend only).
     """
     if donate_args and (backend != "xla" or not jit):
         raise ValueError(

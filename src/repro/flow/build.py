@@ -599,7 +599,7 @@ class CompiledSystem:
     streams: Tuple[StreamInfo, ...]
     sharing: Dict[str, "liveness.SharingPlan"]
     stage_groups: Tuple[Group, ...]
-    candidates: Optional[list] = None   # ChainCandidate ranking (dse=True)
+    candidates: Optional[list] = None   # dse.Candidate ranking (dse=True)
 
     @property
     def stage_names(self) -> Tuple[str, ...]:

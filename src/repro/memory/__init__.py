@@ -1,22 +1,26 @@
 """Automatic memory-architecture planning (the paper's core contribution).
 
-Turns a compiled tensor program + schedule into an explicit
-:class:`~repro.memory.plan.MemoryPlan`: which pseudo-channel each stream
-lives in, how big a batch (E) is, how deep the prefetch pipeline runs,
-and what it is predicted to cost -- then explores that design space
-CHARM-style and verifies the winners by measurement.
+Turns a compiled tensor program -- a :class:`~repro.memory.chain.ProgramChain`
+of one or more stages; a single operator is a one-stage chain -- into an
+explicit :class:`~repro.memory.chain.ChainPlan`: which pseudo-channel each
+stream lives in, how big a batch (E) is, how deep each stage's prefetch
+ring runs, where each stage is placed, and what it is predicted to cost
+-- then explores that design space CHARM-style and verifies the winners
+by measurement.
 
   channels  -- per-target memory datasheets (shared with analysis.roofline)
+  plan      -- BufferSpec, CostBreakdown and the three-term cost model
+               (predict_cost) beneath the planner and the explorer
   layout    -- stream->buffer assignment, packing, auto batch sizing,
                VMEM block sizing (the Pallas kernel's block_elements)
-  pipeline  -- generic K-deep prefetch/double-buffer transfer engine
-  chain     -- multi-operator ProgramChain planning (inter-stage streams
+  placement -- device topology and per-stage CU groups
+  chain     -- the planner: ProgramChain -> ChainPlan (inter-stage streams
                stay resident in HBM; one co-sized E for the pipeline)
   fusion    -- cost-driven stage fusion: the stage count as a DSE axis
                (merge adjacent stages when the handoff beats the roofline)
-  dse       -- design-space explorer + analytic cost model + the
-               measured-feedback CostCorrection
-  plan      -- the MemoryPlan dataclasses and the Fig.-14-style report
+  pipeline  -- the skewed K-deep dispatch rings the executor runs on
+  dse       -- design-space explorer over chain plans + the measured-
+               feedback CostCorrection
 """
 from . import chain, channels, dse, fusion, layout, pipeline, placement, plan
 from .chain import (ChainPlan, ChainStage, PipelineSpec, ProgramChain,
@@ -27,11 +31,10 @@ from .channels import (ALVEO_U280, CPU_HOST, TPU_V5E, MemoryTarget,
                        UnknownTargetError, detect_target, resolve_target)
 from .placement import (DeviceTopology, PlacementError, PlacementPlan,
                         StagePlacement, place_chain)
-from .dse import (Candidate, ChainCandidate, ChainDesignSpace,
-                  CostCorrection, DesignSpace, explore, explore_chain,
-                  fit_correction, format_chain_ranking, make_plan,
+from .dse import (Candidate, ChainDesignSpace, CostCorrection,
+                  explore_chain, fit_correction, format_chain_ranking,
                   measure_chain_plan, pareto_front)
-from .plan import BufferSpec, CostBreakdown, MemoryPlan
+from .plan import BufferSpec, CostBreakdown
 
 __all__ = [
     "chain", "channels", "dse", "layout", "pipeline", "placement", "plan",
@@ -40,12 +43,11 @@ __all__ = [
     "DeviceTopology", "PlacementError", "PlacementPlan", "StagePlacement",
     "place_chain",
     "PipelineSpec", "derive_pipeline",
-    "Candidate", "DesignSpace", "explore", "make_plan", "pareto_front",
-    "ChainCandidate", "ChainDesignSpace", "CostCorrection",
+    "Candidate", "pareto_front", "ChainDesignSpace", "CostCorrection",
     "explore_chain", "fit_correction", "format_chain_ranking",
     "measure_chain_plan",
     "ProgramChain", "ChainStage", "ChainPlan", "plan_chain",
     "fit_contention", "apply_profile_contention",
     "FusionSpec", "fuse_chain", "fuse_chain_auto", "fusion",
-    "BufferSpec", "CostBreakdown", "MemoryPlan",
+    "BufferSpec", "CostBreakdown",
 ]

@@ -4,9 +4,9 @@ applications, not single operators).
 
 A :class:`ProgramChain` is an ordered sequence of compiled programs
 (e.g. interpolation -> gradient -> inverse Helmholtz) with *bindings*
-that wire a producer stage's output to a consumer stage's input.  The
-chain planner then makes the three decisions the single-program planner
-cannot:
+that wire a producer stage's output to a consumer stage's input.  A single
+operator is a one-stage chain, ``ProgramChain([(name, compiled)])``, so
+this is the only planner.  It makes three decisions:
 
   * **inter-stage residency** -- a bound producer->consumer stream never
     crosses the host link: it is written to HBM once by the producer and
@@ -21,7 +21,7 @@ cannot:
     (batch-invariant) operands with the same name are placed once.
 
 The result is a :class:`ChainPlan`: per-stage buffers/costs plus chain
-aggregates, rendered by ``report()`` like the single-program plan.
+aggregates, rendered by ``report()`` (the paper's Fig. 14 dump).
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ from . import layout
 from .channels import MemoryTarget, detect_target
 from .placement import DeviceTopology, PlacementPlan, place_chain
 from .plan import (BufferSpec, CostBreakdown, channels_used,
-                   hbm_stream_bytes, host_stream_bytes)
+                   hbm_stream_bytes, host_stream_bytes, predict_cost)
 
 
 @dataclasses.dataclass
@@ -882,9 +882,6 @@ def plan_chain(
     changes are priced as an explicit re-block term billed to the
     consumer (bytes through the slower side's link).
     """
-    # local import: dse depends on this module for chain exploration
-    from .dse import predict_cost
-
     if fuse not in (None, "off", "auto"):
         raise ValueError(f"unknown fuse mode {fuse!r}; use 'auto' or 'off'")
     if fuse != "off" and (

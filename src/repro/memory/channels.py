@@ -7,7 +7,7 @@ module is the portable version of that datasheet: a frozen
 :class:`MemoryTarget` per device family, used by
 
   * ``memory.layout``   -- buffer placement / batch sizing (E),
-  * ``memory.dse``      -- the design-space cost model,
+  * ``memory.plan``     -- the three-term cost model (``predict_cost``),
   * ``analysis.roofline`` -- which imports its TPU constants from here so
     the planner and the roofline can never disagree on peak numbers.
 

@@ -1,13 +1,17 @@
 """Design-space exploration over memory architectures (CHARM-style CDSE).
 
-Sweeps the planner's knobs -- backend, precision policy, batch size E,
-prefetch depth K, CU replication -- and scores every candidate plan with
-a three-term analytic cost model (compute / device-memory / host-link,
-the same terms as ``analysis.roofline`` and sharing its target constants
-through ``memory.channels``).  Returns a ranked candidate list plus the
-Pareto front over (predicted time, resident device memory); the top
+Sweeps the planner's knobs for a :class:`~repro.memory.chain.ProgramChain`
+-- per-stage backends, precision policy, batch size E, per-stage prefetch
+depth and CU replication over a device topology -- plans every point with
+:func:`~repro.memory.chain.plan_chain`, and ranks the candidates by the
+predicted time of the three-term cost model (``memory.plan.predict_cost``:
+compute / device-memory / host-link, sharing its target constants with
+``analysis.roofline`` through ``memory.channels``).  A single operator is
+a one-stage chain.  Returns a ranked candidate list; :func:`pareto_front`
+keeps the menu over (predicted time, resident device memory).  The top
 candidates can optionally be *verified by measurement* through the real
-simulation driver, mirroring the paper's predict-then-build loop.
+chain executor, mirroring the paper's predict-then-build loop, and the
+measurements fed back as a per-term :class:`CostCorrection`.
 
 The model is deliberately monotone: more bandwidth or more FLOP/s never
 predicts a slower plan (tested), so sweeps over hypothetical machines
@@ -16,15 +20,16 @@ predicts a slower plan (tested), so sweeps over hypothetical machines
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+import itertools
+from typing import Dict, List, Optional, Sequence, Tuple
 
-from ..core import dsl, ir, rewrite
 from ..core.precision import POLICIES
-from ..core.schedule import Schedule, schedule as make_schedule
 from . import layout
+from .chain import ChainPlan, ProgramChain, plan_chain, snap_stage_elements
 from .channels import MemoryTarget, detect_target
-from .plan import (BufferSpec, CostBreakdown, MemoryPlan, channels_used,
-                   hbm_stream_bytes, host_stream_bytes)
+from .fusion import fuse_chain_auto
+from .placement import DeviceTopology, place_chain
+from .plan import CostBreakdown
 
 #: Cost-model epoch.  Bump this whenever the analytic model's terms
 #: change meaning (new term, re-derived constant, different bottleneck
@@ -34,206 +39,14 @@ from .plan import (BufferSpec, CostBreakdown, MemoryPlan, channels_used,
 #: model can never steer the current one.
 COST_MODEL_VERSION = 1
 
-#: Throughput of each scalar policy relative to the target's native
-#: matmul peak (TPU: bf16 MXU; f32 runs at half rate, f64 and the
-#: integer-emulated fixed-point formats far below).
-POLICY_EFFICIENCY = {
-    "bfloat16": 1.0,
-    "float32": 0.5,
-    "float64": 0.125,
-    "fixed32_q8.24": 0.25,
-    "fixed64_q24.40": 0.0625,
-}
-
-
-def _resolve_program(
-    p_or_prog: Union[int, ir.Program], operator_name: Optional[str]
-) -> Tuple[ir.Program, str]:
-    """An int selects the paper's Inverse-Helmholtz operator at degree p."""
-    if isinstance(p_or_prog, ir.Program):
-        return p_or_prog, operator_name or "program"
-    p = int(p_or_prog)
-    prog = rewrite.optimize(
-        dsl.parse(
-            dsl.INVERSE_HELMHOLTZ_SRC.format(p=p),
-            element_vars=("u", "D", "v"),
-        )
-    )
-    return prog, operator_name or f"inverse_helmholtz_p{p}"
-
-
-def predict_cost(
-    target: MemoryTarget,
-    *,
-    policy: str,
-    batch_elements: int,
-    flops_per_element: int,
-    host_bytes: int,
-    hbm_bytes: int,
-    channels_used: int,
-    prefetch_depth: int,
-    cu_count: int,
-    n_batches: Optional[int] = None,
-) -> CostBreakdown:
-    """Per-batch time under the three-term overlap model.
-
-    Device bandwidth is what the *assigned channels* deliver (the paper's
-    point: unmapped pseudo-channels are wasted bandwidth); the host link
-    is shared across replicated CUs.
-    """
-    eff = POLICY_EFFICIENCY.get(policy, 0.25)
-    t_compute = (
-        batch_elements * flops_per_element / (target.peak_flops * eff * cu_count)
-    )
-    bw = target.channel_bw * min(max(1, channels_used), target.n_channels)
-    t_hbm = hbm_bytes / (bw * cu_count)
-    t_host = host_bytes / target.host_link_bw
-    t_over = target.dispatch_overhead_s
-    t_serial = t_host + max(t_compute, t_hbm) + t_over
-    if prefetch_depth == 0:
-        t_pipelined = t_serial
-    else:
-        t_pipelined = max(t_host, t_compute, t_hbm) + t_over
-        if n_batches:
-            # pipeline fill: K transfers before the first compute (never
-            # more than the batches that exist beyond the first)
-            fill = min(prefetch_depth, n_batches - 1)
-            t_pipelined += fill * t_host / n_batches
-    return CostBreakdown(
-        t_compute=t_compute, t_hbm=t_hbm, t_host=t_host, t_overhead=t_over,
-        t_serial=t_serial, t_pipelined=t_pipelined,
-    )
-
-
-def make_plan(
-    p_or_prog: Union[int, ir.Program],
-    *,
-    target: Optional[MemoryTarget] = None,
-    policy: str = "float32",
-    backend: str = "xla",
-    batch_elements: Optional[int] = None,
-    prefetch_depth: int = 1,
-    cu_count: int = 1,
-    n_eq: Optional[int] = None,
-    channel_bytes: Optional[int] = None,
-    operator_name: Optional[str] = None,
-    _schedule: Optional[Schedule] = None,
-) -> MemoryPlan:
-    """Plan the memory architecture for one design point.
-
-    ``batch_elements=None`` auto-sizes E from the channel capacity (the
-    paper's rule); ``channel_bytes`` overrides the target's channel size
-    (e.g. the paper's 256 MB).  Deterministic: same arguments, same plan.
-    """
-    target = target if target is not None else detect_target()
-    if isinstance(policy, str):
-        if policy not in POLICIES:
-            raise ValueError(
-                f"unknown policy {policy!r}; known: {sorted(POLICIES)}"
-            )
-        pol = POLICIES[policy]
-    else:
-        pol = policy
-    bps = pol.bits // 8
-    prog, name = _resolve_program(p_or_prog, operator_name)
-
-    sched = _schedule
-    if sched is None and backend == "staged":
-        sched = make_schedule(prog, bytes_per_scalar=bps)
-
-    blk_cap = layout.vmem_block_elements(prog, target, bytes_per_scalar=bps)
-    pad = 0
-    if batch_elements is not None:
-        e = batch_elements
-    else:
-        e = layout.auto_batch_elements(
-            prog, target, bytes_per_scalar=bps,
-            channel_bytes=channel_bytes, n_eq=n_eq,
-        )
-        # auto-sized E is padded to a block multiple so a prime-ish
-        # channel quotient never forces the Pallas block divisor tiny
-        e, pad = layout.pad_batch_for_block(e, blk_cap, limit=n_eq)
-    e = max(1, int(e))
-    if n_eq is not None:
-        e = min(e, max(1, n_eq))  # a batch never exceeds the problem
-    bufs = layout.build_buffers(
-        prog, target, bytes_per_scalar=bps, batch_elements=e,
-        prefetch_depth=prefetch_depth, schedule=sched,
-    )
-
-    flops_pe = prog.total_flops()
-    n_batches = max(1, n_eq // e) if n_eq else None
-    cost = predict_cost(
-        target, policy=pol.name, batch_elements=e,
-        flops_per_element=flops_pe, host_bytes=host_stream_bytes(bufs),
-        hbm_bytes=hbm_stream_bytes(bufs), channels_used=channels_used(bufs),
-        prefetch_depth=prefetch_depth, cu_count=cu_count,
-        n_batches=n_batches,
-    )
-
-    # on-chip block: largest divisor of E whose fused-kernel working set
-    # fits the VMEM budget (drives the Pallas kernel's block_elements)
-    blk = layout.largest_divisor_leq(e, blk_cap)
-    blk_ws = layout.block_working_set_bytes(
-        prog, blk, target, bytes_per_scalar=bps
-    )
-
-    feasible, reason = True, ""
-    resident = sum(b.resident_bytes for b in bufs)
-    if resident > target.usable_hbm_bytes:
-        feasible = False
-        reason = (
-            f"resident {resident / 2**20:.0f} MiB exceeds usable HBM "
-            f"{target.usable_hbm_bytes / 2**20:.0f} MiB"
-        )
-    elif blk_ws > target.vmem_bytes:
-        # even the BE=1 floor cannot fit on-chip: no fused kernel can run
-        feasible = False
-        reason = (
-            f"block working set {blk_ws} B (BE={blk}) exceeds on-chip "
-            f"{target.vmem_bytes} B"
-        )
-    elif sched is not None:
-        ws = max(g.working_set(bps) for g in sched.groups)
-        if ws > target.vmem_bytes:
-            feasible = False
-            reason = (
-                f"stage working set {ws} B exceeds on-chip "
-                f"{target.vmem_bytes} B"
-            )
-
-    return MemoryPlan(
-        operator=name, target=target, policy=pol.name, backend=backend,
-        batch_elements=e, prefetch_depth=prefetch_depth, cu_count=cu_count,
-        buffers=bufs, cost=cost, feasible=feasible,
-        infeasible_reason=reason, flops_per_element=flops_pe,
-        block_elements=blk, block_working_set_bytes=blk_ws,
-        batch_pad_elements=pad,
-    )
-
-
-# ---------------------------------------------------------------------------
-# exploration
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class DesignSpace:
-    """The sweep axes (defaults mirror the paper's evaluation grid)."""
-
-    backends: Tuple[str, ...] = ("xla", "staged")
-    policies: Tuple[str, ...] = ("float32", "bfloat16")
-    #: divisors of the auto-sized E to try (1 = the paper's full channel)
-    batch_divisors: Tuple[int, ...] = (1, 2, 4)
-    prefetch_depths: Tuple[int, ...] = (0, 1, 2, 4)
-    cu_counts: Tuple[int, ...] = (1, 2, 4)
-
 
 @dataclasses.dataclass
 class Candidate:
-    """One explored design point, ranked by predicted time/element."""
+    """One explored design point, ranked by predicted time/element.
+    ``plan.cost.bottleneck`` is the bottleneck stage's dominating term,
+    the label :func:`fit_correction` attributes measured ratios to."""
 
-    plan: MemoryPlan
+    plan: ChainPlan
     predicted_s_per_element: float
     measured_s_per_element: Optional[float] = None
     #: prediction after the measured-feedback correction (calibrate=True)
@@ -291,9 +104,8 @@ def fit_correction(cands: Sequence[Candidate]) -> CostCorrection:
     """Fit the per-term correction from every measured candidate
     (identity when nothing was measured).  Each measured run's
     measured/predicted ratio is attributed to the cost term its plan
-    predicts as the bottleneck.  Accepts single-op and chain candidates
-    alike: a ChainPlan's ``cost.bottleneck`` is its bottleneck stage's
-    dominating term."""
+    predicts as the bottleneck (a ChainPlan's ``cost.bottleneck`` is its
+    bottleneck stage's dominating term)."""
     ratios: List[float] = []
     by_term: Dict[str, List[float]] = {}
     for c in cands:
@@ -342,95 +154,8 @@ def apply_correction(
     return cands
 
 
-def explore(
-    p_or_prog: Union[int, ir.Program] = 11,
-    *,
-    target: Optional[MemoryTarget] = None,
-    n_eq: int = 1 << 16,
-    space: Optional[DesignSpace] = None,
-    measure_top: int = 0,
-    measure_batches: int = 4,
-    operator_name: Optional[str] = None,
-    calibrate: bool = False,
-) -> List[Candidate]:
-    """Sweep the design space; return candidates ranked best-first.
-
-    Infeasible plans rank after all feasible ones (kept for the report).
-    ``measure_top`` verifies the k best measurable candidates against the
-    real simulation driver and stores seconds/element alongside the
-    prediction.  ``calibrate`` additionally fits the measured-feedback
-    :class:`CostCorrection` from those runs and re-ranks every candidate
-    by its corrected prediction (the paper's predict-then-build loop).
-    """
-    if calibrate and not measure_top:
-        raise ValueError(
-            "calibrate=True fits the correction from measured runs; "
-            "set measure_top > 0"
-        )
-    target = target if target is not None else detect_target()
-    space = space or DesignSpace()
-    prog, name = _resolve_program(p_or_prog, operator_name)
-
-    sched_cache: Dict[int, Schedule] = {}
-    cands: List[Candidate] = []
-    for policy in space.policies:
-        bps = POLICIES[policy].bits // 8
-        auto_e = layout.auto_batch_elements(
-            prog, target, bytes_per_scalar=bps, n_eq=n_eq
-        )
-        # the sweep explores divisors of the *padded* auto-E, so every
-        # candidate batch stays block-composite
-        auto_e, _ = layout.pad_batch_for_block(
-            auto_e,
-            layout.vmem_block_elements(prog, target, bytes_per_scalar=bps),
-            limit=n_eq,
-        )
-        e_cands = sorted({max(1, auto_e // d) for d in space.batch_divisors})
-        for backend in space.backends:
-            sched = None
-            if backend == "staged":
-                if bps not in sched_cache:
-                    sched_cache[bps] = make_schedule(
-                        prog, bytes_per_scalar=bps
-                    )
-                sched = sched_cache[bps]
-            for e in e_cands:
-                for depth in space.prefetch_depths:
-                    for cu in space.cu_counts:
-                        plan = make_plan(
-                            prog, target=target, policy=policy,
-                            backend=backend, batch_elements=e,
-                            prefetch_depth=depth, cu_count=cu, n_eq=n_eq,
-                            operator_name=name, _schedule=sched,
-                        )
-                        cands.append(
-                            Candidate(
-                                plan=plan,
-                                predicted_s_per_element=(
-                                    plan.cost.t_pipelined / plan.batch_elements
-                                ),
-                            )
-                        )
-
-    cands.sort(
-        key=lambda c: (
-            not c.plan.feasible,
-            c.predicted_s_per_element,
-            c.plan.resident_bytes,
-        )
-    )
-    if measure_top:
-        _measure_candidates(
-            cands, p_or_prog, measure_top, n_eq=n_eq,
-            max_batches=measure_batches,
-        )
-        if calibrate:
-            apply_correction(cands, fit_correction(cands))
-    return cands
-
-
 # ---------------------------------------------------------------------------
-# chain exploration (multi-operator programs)
+# exploration
 # ---------------------------------------------------------------------------
 
 
@@ -456,29 +181,9 @@ class ChainDesignSpace:
     max_search_nodes: int = 20000
 
 
-@dataclasses.dataclass
-class ChainCandidate:
-    """One explored chain design point (ranked like Candidate; the
-    ``plan`` attribute makes :func:`pareto_front` and the measured-
-    feedback :func:`apply_correction` work unchanged -- ``ChainCost``
-    exposes the bottleneck stage's dominating term as its
-    ``bottleneck``)."""
-
-    plan: "chain_mod.ChainPlan"
-    predicted_s_per_element: float
-    measured_s_per_element: Optional[float] = None
-    #: prediction after the measured-feedback correction (calibrate=True)
-    corrected_s_per_element: Optional[float] = None
-
-    @property
-    def verified(self) -> bool:
-        """True once this design point has a measured run behind it."""
-        return self.measured_s_per_element is not None
-
-
 def measure_chain_plan(
-    chain: "chain_mod.ProgramChain",
-    plan: "chain_mod.ChainPlan",
+    chain: ProgramChain,
+    plan: ChainPlan,
     *,
     max_batches: int = 4,
 ) -> Optional[float]:
@@ -527,8 +232,6 @@ def _search_stage_placements(
     kept set and its whole subtree is cut.  Returns the up-to-
     ``max_placements`` best ``(cu_counts, prefetch_depths)`` vectors.
     """
-    from .placement import place_chain
-
     n = len(stage_costs)
     # branch on cu only: the proxy score depends on depths solely
     # through "is any inter-stage ring open", so enumerating per-stage
@@ -645,9 +348,6 @@ def _search_hetero_placements(
     menu, ``plan_chain`` is the judge.  Returns up to ``max_placements``
     ``(cu_counts, prefetch_depths, stage_groups, stage_elements)``.
     """
-    from . import chain as chain_mod  # lazy: chain imports predict_cost
-    from .placement import place_chain
-
     if not group_costs:
         return []
     n = len(next(iter(group_costs.values())))
@@ -667,7 +367,7 @@ def _search_hetero_placements(
                 if cu < 1 or cu > size or e % cu:
                     continue
                 for d in divisors:
-                    e_s = chain_mod.snap_stage_elements(
+                    e_s = snap_stage_elements(
                         e, max(1, e // d), cu
                     )
                     m = max(1, e // e_s)
@@ -760,7 +460,7 @@ def _search_hetero_placements(
 
 
 def explore_chain(
-    chain: "chain_mod.ProgramChain",
+    chain: ProgramChain,
     *,
     target: Optional[MemoryTarget] = None,
     n_eq: int = 1 << 16,
@@ -773,7 +473,7 @@ def explore_chain(
     fuse: Optional[str] = None,
     max_stages: Optional[int] = None,
     fuse_barriers: Sequence[str] = (),
-) -> List[ChainCandidate]:
+) -> List[Candidate]:
     """Sweep chain plans: per-stage backend combinations and *joint
     per-stage placements* under one shared (divisor-scaled) E.
 
@@ -790,8 +490,8 @@ def explore_chain(
     uniform (cu, depth) grid plus the ``max_placements`` best joint
     per-stage vectors found by :func:`_search_stage_placements` over
     ``topology`` (default: just enough devices for the largest swept CU
-    count).  Ranked best-first with infeasible plans last, exactly like
-    :func:`explore`.  Depth>0 candidates are priced with the
+    count).  Ranked best-first by predicted time per element, with
+    infeasible plans last (kept for the report).  Depth>0 candidates are priced with the
     contention-aware cross-batch overlap term
     (``ChainCost.t_overlapped``: slowest contended stage + amortized
     fill/drain), so replication and stage pipelining competing for the
@@ -820,11 +520,6 @@ def explore_chain(
     guided leaders), and every run measured here is recorded back into
     the store.  ``calibrate``'s freshly-fit correction still wins last
     when both are given."""
-    import itertools
-
-    from . import chain as chain_mod  # local: chain imports predict_cost
-    from .placement import DeviceTopology
-
     if calibrate and not measure_top:
         raise ValueError(
             "calibrate=True fits the correction from measured runs; "
@@ -841,8 +536,6 @@ def explore_chain(
         fuse != "off" and max_stages is not None
         and max_stages < len(chain.stages)
     ):
-        from .fusion import fuse_chain_auto  # lazy: fusion imports chain
-
         fused_plan = fuse_chain_auto(
             chain, mode="auto", max_stages=max_stages,
             barriers=tuple(fuse_barriers), target=target,
@@ -859,7 +552,7 @@ def explore_chain(
         )
     )
     sched_cache: Dict = {}  # (stage idx, bps) -> Schedule, shared by all points
-    cands: List[ChainCandidate] = []
+    cands: List[Candidate] = []
     for policy in space.policies:
         bps = POLICIES[policy].bits // 8
         auto_e = chain.auto_batch_elements(
@@ -877,8 +570,8 @@ def explore_chain(
         e_cands = sorted({max(1, auto_e // d) for d in space.batch_divisors})
         for backends in combos:
             for e in e_cands:
-                def make_plan_at(cus, depths, groups=None, stage_es=None):
-                    return chain_mod.plan_chain(
+                def plan_at(cus, depths, groups=None, stage_es=None):
+                    return plan_chain(
                         chain, target=target, policy=policy,
                         backends=backends, batch_elements=e,
                         prefetch_depth=list(depths), cu_count=list(cus),
@@ -895,7 +588,7 @@ def explore_chain(
 
                 # reference plan: per-stage cost terms at cu=1 feed the
                 # placement search (device terms scale as 1/cu)
-                ref = make_plan_at((1,) * n_stages, (1,) * n_stages)
+                ref = plan_at((1,) * n_stages, (1,) * n_stages)
                 vectors = {
                     ((1,) * n_stages, (1,) * n_stages, None, None): ref,
                 }
@@ -912,7 +605,7 @@ def explore_chain(
                     # per-group references: every stage priced on each
                     # kind group's own datasheet at cu=1
                     group_refs = {
-                        gi: make_plan_at(
+                        gi: plan_at(
                             (1,) * n_stages, (1,) * n_stages,
                             groups=(gi,) * n_stages,
                         )
@@ -949,7 +642,7 @@ def explore_chain(
                         vectors.setdefault((cus, depths, None, None), None)
                 for (cus, depths, gis, es), plan in vectors.items():
                     if plan is None:
-                        plan = make_plan_at(
+                        plan = plan_at(
                             cus, depths, groups=gis, stage_es=es
                         )
                     if fusion_spec is not None:
@@ -957,7 +650,7 @@ def explore_chain(
                             plan, fusion=fusion_spec
                         )
                     cands.append(
-                        ChainCandidate(
+                        Candidate(
                             plan=plan,
                             predicted_s_per_element=(
                                 plan.cost.t_pipelined
@@ -1025,68 +718,8 @@ def pareto_front(cands: Sequence[Candidate]) -> List[Candidate]:
     return front
 
 
-def measure_plan(
-    plan: MemoryPlan,
-    p: int,
-    *,
-    n_eq: Optional[int] = None,
-    max_batches: int = 4,
-) -> Optional[float]:
-    """Verify a plan by running the real driver; seconds per element.
-
-    Returns None when the plan is not runnable here (CU count exceeds
-    local devices, or the policy has no runtime on the CPU); any other
-    backend's failure propagates.
-    """
-    import jax
-
-    from ..cfd.simulation import SimConfig, run_simulation  # lazy: no cycle
-
-    if plan.cu_count > len(jax.devices()):
-        return None
-    cfg = SimConfig(
-        p=p, n_eq=n_eq or plan.batch_elements * max_batches,
-        batch_elements=plan.batch_elements, policy=plan.policy,
-        backend=plan.backend, prefetch_depth=plan.prefetch_depth,
-    )
-    try:
-        run_simulation(cfg, plan=plan, max_batches=1)  # warm compile
-        res = run_simulation(cfg, plan=plan, max_batches=max_batches)
-    except Exception:
-        if jax.default_backend() != "cpu":
-            raise  # a chip that refuses a plan is a fault, not a rank
-        return None  # e.g. bf16 dot unsupported on the CPU runtime
-    return res.wall_s / res.elements if res.elements else None
-
-
-def _measure_candidates(
-    cands: List[Candidate],
-    p_or_prog,
-    top_k: int,
-    *,
-    n_eq: int,
-    max_batches: int,
-) -> None:
-    if not isinstance(p_or_prog, int):
-        return  # measurement needs the named operator builder
-    measured = 0
-    for c in cands:
-        if measured >= top_k:
-            break
-        if not c.plan.feasible:
-            continue
-        got = measure_plan(
-            c.plan, p_or_prog,
-            n_eq=min(n_eq, c.plan.batch_elements * max_batches),
-            max_batches=max_batches,
-        )
-        if got is not None:
-            c.measured_s_per_element = got
-            measured += 1
-
-
 def format_chain_ranking(
-    cands: Sequence[ChainCandidate], limit: int = 10
+    cands: Sequence[Candidate], limit: int = 10
 ) -> str:
     """Compact leaderboard for chain sweeps (per-stage backends and
     per-stage (cu, depth) placements)."""
@@ -1120,28 +753,5 @@ def format_chain_ranking(
             f"{c.predicted_s_per_element * 1e6:>13.4f} "
             f"{meas} {p.resident_bytes / 2**20:>13.1f} "
             f"{'yes' if p.feasible else 'no':>9}"
-        )
-    return "\n".join(lines)
-
-
-def format_ranking(cands: Sequence[Candidate], limit: int = 10) -> str:
-    """Compact leaderboard for logs/benchmarks."""
-    hdr = (
-        f"{'#':>3} {'backend':<8} {'policy':<16} {'E':>8} {'K':>2} "
-        f"{'CU':>3} {'pred us/elem':>13} {'meas us/elem':>13} "
-        f"{'resident MiB':>13} {'feasible':>9}"
-    )
-    lines = [hdr, "-" * len(hdr)]
-    for i, c in enumerate(cands[:limit]):
-        meas = (
-            f"{c.measured_s_per_element * 1e6:13.4f}"
-            if c.measured_s_per_element is not None else f"{'-':>13}"
-        )
-        lines.append(
-            f"{i:>3} {c.plan.backend:<8} {c.plan.policy:<16} "
-            f"{c.plan.batch_elements:>8} {c.plan.prefetch_depth:>2} "
-            f"{c.plan.cu_count:>3} {c.predicted_s_per_element * 1e6:>13.4f} "
-            f"{meas} {c.plan.resident_bytes / 2**20:>13.1f} "
-            f"{'yes' if c.plan.feasible else 'no':>9}"
         )
     return "\n".join(lines)

@@ -1,33 +1,29 @@
-"""Buffer layout: map a program's streams onto the channel model.
+"""Buffer layout primitives: map a program's streams onto the channel model.
 
-Performs the paper's section-3 sizing decisions explicitly:
+The paper's section-3 sizing decisions, as the pieces the chain planner
+(``memory.chain.plan_chain``) builds every stage's buffer table from:
 
   * **stream discovery** -- element-streamed inputs/outputs vs. shared
-    (batch-invariant) operands, straight from ``ir.Program.element_vars``;
-    with a staged schedule, per-group intermediates become HBM round-trip
-    buffers too (``core.schedule`` exposes their byte counts).
+    (batch-invariant) operands, straight from ``ir.Program.element_vars``
+    (:func:`element_streams`, :func:`stream_bytes_per_element` -- the
+    bytes the paper's batch rule divides a pseudo-channel by,
+    ``channel_bytes // (3 * p**3 * bytes)`` for the inverse Helmholtz
+    operator).
   * **packing/padding** -- each element record is padded to the target's
     burst quantum (the paper packs p^3 scalars into 256-bit HBM words).
-  * **batch sizing** -- E is derived so one batch's combined stream I/O
-    fills one pseudo-channel, exactly the rule behind
-    ``SimConfig.batch_for_channel`` but computed from the program instead
-    of hardcoded in the driver.
   * **channel assignment** -- round-robin placement of every replica
-    (ping/pong copies for a K-deep prefetch) over the pseudo-channels.
+    (ping/pong copies for a K-deep prefetch) over the pseudo-channels,
+    through one shared :class:`ChannelAllocator` so all stages of a
+    multi-operator program place their buffers without conflicts.
   * **VMEM block sizing** -- the largest per-dispatch element block whose
     working set fits the target's on-chip memory, which is what drives
     the Pallas kernel's ``block_elements`` (the paper's PLM sizing).
-
-``ProgramChain`` planning (``memory.chain``) reuses these primitives with
-a shared :class:`ChannelAllocator` so all stages of a multi-operator
-program place their buffers without conflicts.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 from ..core import ir
-from ..core.schedule import Schedule
 from .channels import MemoryTarget, channels_for, pad_to_burst
 from .plan import BufferSpec
 
@@ -47,30 +43,9 @@ def element_streams(prog: ir.Program):
 
 def stream_bytes_per_element(prog: ir.Program, bytes_per_scalar: int) -> int:
     """Unpadded host-stream bytes per element (in + out), the quantity
-    ``SimConfig.batch_for_channel`` divides a channel by."""
+    the paper's batch rule divides a channel by."""
     ins, outs, _ = element_streams(prog)
     return sum(v.size for _, v in ins + outs) * bytes_per_scalar
-
-
-def auto_batch_elements(
-    prog: ir.Program,
-    target: MemoryTarget,
-    *,
-    bytes_per_scalar: int,
-    channel_bytes: Optional[int] = None,
-    n_eq: Optional[int] = None,
-) -> int:
-    """The paper's E: largest batch whose stream I/O fits one channel.
-
-    ``n_eq`` caps E at the problem size (no point staging a batch larger
-    than the whole simulation).
-    """
-    cb = channel_bytes if channel_bytes is not None else target.channel_bytes
-    per = stream_bytes_per_element(prog, bytes_per_scalar)
-    e = max(1, cb // per)
-    if n_eq is not None:
-        e = min(e, max(1, n_eq))
-    return int(e)
 
 
 class ChannelAllocator:
@@ -104,10 +79,6 @@ class ChannelAllocator:
         return ids
 
 
-#: Backwards-compatible alias (pre-chain name).
-_ChannelAllocator = ChannelAllocator
-
-
 def make_buffer(
     name: str,
     node: ir.Node,
@@ -120,8 +91,8 @@ def make_buffer(
     alloc: ChannelAllocator,
     group: str = "",
 ) -> BufferSpec:
-    """Size, pad, and channel-assign one stream (shared by single-program
-    and chain planning)."""
+    """Size, pad, and channel-assign one stream (the chain planner's
+    buffer table is built from these)."""
     eb = node.size * bytes_per_scalar
     pb = pad_to_burst(eb, target)
     bb = pb * batch_elements if role != "shared" else pb
@@ -131,58 +102,6 @@ def make_buffer(
         element_bytes=eb, padded_bytes=pb, batch_bytes=bb,
         replicas=replicas, channels=ch, group=group,
     )
-
-
-def build_buffers(
-    prog: ir.Program,
-    target: MemoryTarget,
-    *,
-    bytes_per_scalar: int,
-    batch_elements: int,
-    prefetch_depth: int,
-    schedule: Optional[Schedule] = None,
-) -> Tuple[BufferSpec, ...]:
-    """Assign every stream of the program to sized, channel-mapped buffers."""
-    ins, outs, shared = element_streams(prog)
-    alloc = ChannelAllocator(target.n_channels)
-    bufs: List[BufferSpec] = []
-
-    # K-deep prefetch keeps K staged batches, one computing, and -- since
-    # JAX allocates fresh buffers instead of swapping a ping/pong pair in
-    # place -- one retiring batch whose async compute has not yet freed
-    # it.  Peak input residency is therefore K+2 (K=1 is the paper's
-    # ping/pong pair plus the retiring slot).
-    in_replicas = prefetch_depth + 2 if prefetch_depth > 0 else 1
-    out_replicas = 2 if prefetch_depth > 0 else 1  # result drains while next computes
-
-    def add(name, node, role, replicas, group=""):
-        bufs.append(
-            make_buffer(
-                name, node, role, replicas, target=target,
-                bytes_per_scalar=bytes_per_scalar,
-                batch_elements=batch_elements, alloc=alloc, group=group,
-            )
-        )
-
-    for name, node in ins:
-        add(name, node, "in", in_replicas)
-    for name, node in outs:
-        add(name, node, "out", out_replicas)
-    for name, node in shared:
-        add(name, node, "shared", 1)
-
-    # staged backend: group-boundary intermediates are HBM round-trips
-    if schedule is not None:
-        out_uids = {v.uid for v in prog.outputs.values()}
-        input_uids = {v.uid for v in prog.inputs.values()}
-        for g in schedule.groups:
-            streamed = [
-                n for n in g.out_streams
-                if n.uid not in out_uids and n.uid not in input_uids
-            ]
-            for i, node in enumerate(streamed):
-                add(f"{g.name}.s{i}", node, "inter", 1, group=g.name)
-    return tuple(bufs)
 
 
 # ---------------------------------------------------------------------------

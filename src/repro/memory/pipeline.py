@@ -10,25 +10,25 @@ the dispatch queue never drains, and (3) dispatches the stages of a
 multi-operator chain *skewed* -- stage i of batch k in the same breath
 as stage i+1 of batch k-1 -- so no stage's dispatch ring ever idles
 waiting for the whole previous batch to finish.  This module packages
-those tricks behind two generic drivers so every workload (CFD
-simulation, benchmarks, tests) uses the identical machinery instead of
-hand-rolling the loop.
+those tricks behind one generic ring so every workload (CFD
+simulation, serving, benchmarks, tests) uses the identical machinery
+instead of hand-rolling the loop.
 
 ``depth`` is the plan's prefetch K: 0 = fully serial (stage, compute,
 sync -- the paper's baseline), 1 = classic double buffering, K>1 = deeper
 staging that also rides out host-side jitter.
 
-:func:`run_pipelined` is the single-stage K-deep engine;
-:func:`run_stage_pipelined` generalizes it to a whole chain with one
-dispatch ring per stage (per-stage depths), handing HBM-resident
-inter-stage values from producer to consumer without host round-trips.
+:func:`run_stage_pipelined` runs a chain with one dispatch ring per
+stage (per-stage depths; one stage is the single-operator K-deep
+engine), handing HBM-resident inter-stage values from producer to
+consumer without host round-trips.
 Its multi-device mode (``place_fns``, built from a
 :class:`~repro.memory.placement.PlacementPlan` via
 :func:`placement_meshes`) runs one dispatch ring per *device group*:
 each stage shards its element batch over its own group's mesh and the
 HBM-resident handoff is resharded between groups as it crosses.
 
-:class:`StagePipelineDriver` is the reentrant core both build on: the
+:class:`StagePipelineDriver` is the reentrant core it builds on: the
 same skewed ring as a feed/tick state machine, so a long-running caller
 (``repro.serve``) can push batches as they arrive, idle the ring dry,
 and resume -- with optional per-batch error capture instead of the
@@ -38,8 +38,8 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
-                    Sequence, Tuple, Union)
+from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple, Union)
 
 import jax
 
@@ -56,28 +56,6 @@ _CAT_SYNC = "sync"
 _HOST_TRACK = 0
 
 
-def prefetch(
-    batches: Iterable[Any],
-    stage_fn: Callable[[Any], Any],
-    depth: int,
-) -> Iterator[Any]:
-    """Yield staged batches while keeping up to ``depth`` staged ahead.
-
-    ``stage_fn`` starts the (async) host->device transfer; with JAX's
-    asynchronous dispatch the transfer of staged-ahead batches proceeds
-    while the consumer computes on the current one.
-    """
-    if depth < 0:
-        raise ValueError(f"prefetch depth must be >= 0, got {depth}")
-    q: deque = deque()
-    for item in batches:
-        q.append(stage_fn(item))
-        if len(q) > depth:
-            yield q.popleft()
-    while q:
-        yield q.popleft()
-
-
 def _traced_stage_fn(stage_fn: Callable[[Any], Any], tracer) -> Callable:
     """Wrap the staging fn so each host->device stage gets a host-track
     span (batch index = call order, which is staging order)."""
@@ -91,67 +69,6 @@ def _traced_stage_fn(stage_fn: Callable[[Any], Any], tracer) -> Callable:
             return stage_fn(item)
 
     return staged
-
-
-def run_pipelined(
-    compute_fn: Callable[[Any], Any],
-    batches: Iterable[Any],
-    *,
-    stage_fn: Callable[[Any], Any] = lambda x: x,
-    depth: int = 1,
-    reduce_fn: Optional[Callable[[Any], Any]] = None,
-    defer_sync: Optional[bool] = None,
-    tracer=None,
-    stage_name: str = "compute",
-) -> List[Any]:
-    """Run every batch through ``compute_fn`` with K-deep staging.
-
-    Returns the realized (host-side) per-batch results, in order.
-
-    ``reduce_fn`` maps a device result to the (small) value to realize --
-    e.g. a checksum scalar -- so full batches never transfer back.
-    ``defer_sync`` delays each host sync by one batch so compute k+1 is
-    enqueued before blocking on k (defaults to on whenever ``depth > 0``;
-    forcing it off gives the paper's serial baseline).
-
-    ``tracer`` (a ``repro.trace.Tracer``; None/NULL = off) records one
-    staging span per batch on the host track, one dispatch span per
-    batch on track 1, and one sync span per retire.  Disabled tracing
-    costs one truthiness check per site -- results are identical either
-    way (spans only observe).
-    """
-    if defer_sync is None:
-        defer_sync = depth > 0
-    if tracer:
-        tracer.name_track(_HOST_TRACK, "host")
-        tracer.name_track(1, stage_name)
-        stage_fn = _traced_stage_fn(stage_fn, tracer)
-
-    def sync_get(value: Any, j: int) -> Any:
-        if tracer:
-            with tracer.span(f"sync b{j}", _CAT_SYNC, _HOST_TRACK, batch=j):
-                return jax.device_get(value)
-        return jax.device_get(value)
-
-    results: List[Any] = []
-    pending: Optional[Tuple[Any, int]] = None
-    for j, staged in enumerate(prefetch(batches, stage_fn, depth)):
-        sp = (tracer.begin(f"b{j}", _CAT_DISPATCH, 1, batch=j)
-              if tracer else None)
-        out = compute_fn(staged)
-        if reduce_fn is not None:
-            out = reduce_fn(out)
-        if sp is not None:
-            tracer.end(sp)
-        if not defer_sync:
-            results.append(sync_get(out, j))
-            continue
-        if pending is not None:
-            results.append(sync_get(*pending))
-        pending = (out, j)
-    if pending is not None:
-        results.append(sync_get(*pending))
-    return results
 
 
 def placement_meshes(
@@ -263,12 +180,13 @@ def run_stage_pipelined(
     results are returned in batch order.
 
     ``depths`` is one dispatch-ring depth per stage (an int applies
-    chain-wide): ``depths[0]`` stages host batches ahead exactly like
-    :func:`run_pipelined`; ``depths[i>0]`` lets stage i run that many
+    chain-wide): ``depths[0]`` stages that many host batches ahead of
+    the one computing; ``depths[i>0]`` lets stage i run that many
     batches behind stage i-1, so with any positive inter-stage depth the
     dispatch order interleaves stage i of batch k with stage i+1 of
     batch k-1 (software pipelining).  All inter-stage depths 0 degrades
-    to the back-to-back schedule of :func:`run_pipelined`.
+    to the back-to-back schedule: every stage of batch k before batch
+    k+1.
 
     Every batch still passes through every stage exactly once with
     identical inputs, so results are bitwise-equal to the serial

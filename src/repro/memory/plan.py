@@ -1,11 +1,16 @@
-"""MemoryPlan: the explicit memory architecture for one compiled program.
+"""The plan's building blocks and the analytic cost model beneath them.
 
-This is the artifact the paper's Olympus flow produces implicitly when it
-instantiates Fig. 14: which array lives in which pseudo-channel, how many
-ping/pong replicas each stream keeps resident, how big a batch (E) is,
-and what the transfer/compute overlap is predicted to cost.  The plan is
-pure data (frozen dataclasses) so it can be diffed, cached, and compared
-across DSE candidates; ``report()`` renders the human-readable dump.
+A plan is the artifact the paper's Olympus flow produces implicitly when
+it instantiates Fig. 14: which array lives in which pseudo-channel, how
+many ping/pong replicas each stream keeps resident, how big a batch (E)
+is, and what the transfer/compute overlap is predicted to cost.  This
+module holds the pieces every plan is made of -- :class:`BufferSpec`
+(one buffer and its channels), :class:`CostBreakdown` (one stage's
+predicted per-batch time) and :func:`predict_cost`, the three-term model
+that prices it -- so the chain planner (``memory.chain``) and the
+explorer above it (``memory.dse``) price plans with one model, and the
+planner never imports the explorer.  Everything here is pure data or
+pure arithmetic.
 """
 from __future__ import annotations
 
@@ -13,6 +18,17 @@ import dataclasses
 from typing import Iterable, Optional, Tuple
 
 from .channels import MemoryTarget
+
+#: Throughput of each scalar policy relative to the target's native
+#: matmul peak (TPU: bf16 MXU; f32 runs at half rate, f64 and the
+#: integer-emulated fixed-point formats far below).
+POLICY_EFFICIENCY = {
+    "bfloat16": 1.0,
+    "float32": 0.5,
+    "float64": 0.125,
+    "fixed32_q8.24": 0.25,
+    "fixed64_q24.40": 0.0625,
+}
 
 
 def host_stream_bytes(buffers: Iterable["BufferSpec"]) -> int:
@@ -107,136 +123,46 @@ class CostBreakdown:
         }
         return max(terms, key=terms.get)
 
-    @property
-    def overlap_speedup(self) -> float:
-        """Predicted serial/pipelined ratio for this stage."""
-        return self.t_serial / self.t_pipelined if self.t_pipelined else 1.0
 
+def predict_cost(
+    target: MemoryTarget,
+    *,
+    policy: str,
+    batch_elements: int,
+    flops_per_element: int,
+    host_bytes: int,
+    hbm_bytes: int,
+    channels_used: int,
+    prefetch_depth: int,
+    cu_count: int,
+    n_batches: Optional[int] = None,
+) -> CostBreakdown:
+    """Per-batch time under the three-term overlap model.
 
-@dataclasses.dataclass(frozen=True)
-class MemoryPlan:
-    """The complete memory architecture for one operator + target."""
-
-    operator: str               # e.g. "inverse_helmholtz_p11"
-    target: MemoryTarget
-    policy: str
-    backend: str
-    batch_elements: int         # E -- elements per dispatched batch
-    prefetch_depth: int         # K -- batches staged ahead (0 = serial)
-    cu_count: int               # replicated compute units (mesh devices)
-    buffers: Tuple[BufferSpec, ...]
-    cost: CostBreakdown
-    feasible: bool = True
-    infeasible_reason: str = ""
-    flops_per_element: int = 0
-    #: largest element block whose fused-kernel working set fits on-chip
-    #: memory (drives the Pallas kernel's ``block_elements``); divides E.
-    block_elements: int = 0
-    block_working_set_bytes: int = 0
-    #: elements added to (or, negative, trimmed from) the auto-sized E so
-    #: it is a multiple of the VMEM block (0 when E was given explicitly
-    #: or already composite).  Padded tail elements are host-side filler.
-    batch_pad_elements: int = 0
-
-    # -- aggregates ---------------------------------------------------------
-    @property
-    def resident_bytes(self) -> int:
-        """Device memory held while the pipeline is in flight."""
-        return sum(b.resident_bytes for b in self.buffers)
-
-    @property
-    def host_stream_bytes(self) -> int:
-        """Host-link bytes moved per batch (in + out streams, padded)."""
-        return host_stream_bytes(self.buffers)
-
-    @property
-    def hbm_stream_bytes(self) -> int:
-        """Device-memory traffic per batch (intermediates cross twice)."""
-        return hbm_stream_bytes(self.buffers)
-
-    @property
-    def channels_used(self) -> int:
-        """Distinct pseudo-channels this plan's buffers map to."""
-        return channels_used(self.buffers)
-
-    @property
-    def signature(self) -> str:
-        """Stable short id of what would execute (operator, backend,
-        policy, E, K, CU) -- the profile-store key for single-op runs."""
-        import hashlib
-
-        parts = [
-            self.operator, self.backend, self.policy,
-            str(self.batch_elements), str(self.prefetch_depth),
-            str(self.cu_count), str(self.flops_per_element),
-        ]
-        return hashlib.sha1("|".join(parts).encode()).hexdigest()[:12]
-
-    @property
-    def donation(self) -> Tuple[str, ...]:
-        """Input buffers safe to donate to XLA (each staged batch is
-        consumed exactly once, so its device buffer can be reused for
-        outputs).  Only meaningful for the jitted ``xla`` backend."""
-        if self.backend != "xla":
-            return ()
-        return tuple(sorted(b.name for b in self.buffers if b.role == "in"))
-
-    def batches_for(self, n_eq: int) -> int:
-        """Batches needed to cover an ``n_eq``-element problem."""
-        return max(1, n_eq // self.batch_elements)
-
-    # -- the "Fig. 14" dump -------------------------------------------------
-    def report(self) -> str:
-        """Human-readable plan dump (the paper's Fig. 14 analog)."""
-        t = self.target
-        c = self.cost
-        mib = 2 ** 20
-        lines = [
-            f"MemoryPlan {self.operator}  target={t.name}  "
-            f"backend={self.backend}  policy={self.policy}",
-            f"  E={self.batch_elements} elements/batch   "
-            f"prefetch K={self.prefetch_depth}   CUs={self.cu_count}   "
-            f"feasible={'yes' if self.feasible else 'NO: ' + self.infeasible_reason}",
-            f"  channels: {self.channels_used}/{t.n_channels} used "
-            f"({t.channel_bytes // mib} MiB each)   "
-            f"resident {self.resident_bytes / mib:.1f} MiB "
-            f"of {t.usable_hbm_bytes / mib:.0f} MiB usable",
-            f"  host stream {self.host_stream_bytes / mib:.1f} MiB/batch   "
-            f"hbm traffic {self.hbm_stream_bytes / mib:.1f} MiB/batch",
-        ]
-        if self.block_elements:
-            lines.append(
-                f"  vmem block BE={self.block_elements} elements   "
-                f"working set {self.block_working_set_bytes / mib:.2f} MiB "
-                f"of {t.vmem_bytes / mib:.0f} MiB VMEM"
-            )
-        if self.batch_pad_elements:
-            lines.append(
-                f"  E auto-padded {self.batch_pad_elements:+d} elements "
-                f"(from {self.batch_elements - self.batch_pad_elements}) "
-                "to keep the VMEM block divisor composite"
-            )
-        lines += [
-            "",
-            f"  {'buffer':<14} {'role':<7} {'elem B':>7} {'padded':>7} "
-            f"{'batch MiB':>10} {'repl':>5}  channels",
-        ]
-        for b in self.buffers:
-            ch = ",".join(str(i) for i in b.channels[:6])
-            if len(b.channels) > 6:
-                ch += f",..x{len(b.channels)}"
-            lines.append(
-                f"  {b.name:<14} {b.role:<7} {b.element_bytes:>7} "
-                f"{b.padded_bytes:>7} {b.batch_bytes / mib:>10.2f} "
-                f"{b.replicas:>5}  [{ch}]"
-            )
-        lines += [
-            "",
-            f"  predicted/batch: compute {c.t_compute * 1e3:.3f} ms   "
-            f"hbm {c.t_hbm * 1e3:.3f} ms   host {c.t_host * 1e3:.3f} ms"
-            f"   -> {c.bottleneck}-bound",
-            f"  serial {c.t_serial * 1e3:.3f} ms/batch   "
-            f"pipelined {c.t_pipelined * 1e3:.3f} ms/batch   "
-            f"(overlap speedup {c.overlap_speedup:.2f}x)",
-        ]
-        return "\n".join(lines)
+    Device bandwidth is what the *assigned channels* deliver (the paper's
+    point: unmapped pseudo-channels are wasted bandwidth); the host link
+    is shared across replicated CUs.  The model is monotone: more
+    bandwidth or more FLOP/s never predicts a slower batch.
+    """
+    eff = POLICY_EFFICIENCY.get(policy, 0.25)
+    t_compute = (
+        batch_elements * flops_per_element / (target.peak_flops * eff * cu_count)
+    )
+    bw = target.channel_bw * min(max(1, channels_used), target.n_channels)
+    t_hbm = hbm_bytes / (bw * cu_count)
+    t_host = host_bytes / target.host_link_bw
+    t_over = target.dispatch_overhead_s
+    t_serial = t_host + max(t_compute, t_hbm) + t_over
+    if prefetch_depth == 0:
+        t_pipelined = t_serial
+    else:
+        t_pipelined = max(t_host, t_compute, t_hbm) + t_over
+        if n_batches:
+            # pipeline fill: K transfers before the first compute (never
+            # more than the batches that exist beyond the first)
+            fill = min(prefetch_depth, n_batches - 1)
+            t_pipelined += fill * t_host / n_batches
+    return CostBreakdown(
+        t_compute=t_compute, t_hbm=t_hbm, t_host=t_host, t_overhead=t_over,
+        t_serial=t_serial, t_pipelined=t_pipelined,
+    )

@@ -2,7 +2,7 @@
 attribution and a persistent per-machine profile store.
 
 The observability layer of the tool flow: a :class:`Tracer` threads
-through ``pipeline.run_pipelined`` / ``run_stage_pipelined``,
+through ``pipeline.run_stage_pipelined``,
 ``simulation.run_chain`` and ``flow.CompiledSystem.run()``; the recorded
 spans/counters export to Chrome-trace JSON (:func:`write_chrome`, view
 in Perfetto), fold against the plan's cost model

@@ -24,8 +24,8 @@ existed load fine; their unstamped samples are simply ignored.
 
 The epoch is a declared version, and planner edits rarely remember to
 bump it -- so samples are *also* stamped with :func:`plan_code_digest`,
-a digest of the planner's own source (``memory.chain`` / ``memory.dse``
-/ ``memory.pipeline``).  When the plan *code* changes under an
+a digest of the planner's own source (``memory.plan`` / ``memory.chain``
+/ ``memory.dse`` / ``memory.pipeline``).  When the plan *code* changes under an
 unchanged ``COST_MODEL_VERSION``, queries stop surfacing the old
 samples and recording prunes them.  Samples without a ``src`` stamp
 (older store files) are tolerated: the digest gates code drift, it does
@@ -63,8 +63,9 @@ _PLAN_CODE_DIGEST: Optional[str] = None
 
 
 def plan_code_digest() -> str:
-    """Digest of the planner's own source code (``memory.chain``,
-    ``memory.dse``, ``memory.pipeline``), cached per process.  A sample
+    """Digest of the planner's own source code (``memory.plan``,
+    ``memory.chain``, ``memory.dse``, ``memory.pipeline``), cached per
+    process.  A sample
     calibrates the model *as coded*: when the planner changes without a
     ``COST_MODEL_VERSION`` bump, this digest changes and the old
     feedback ages out anyway."""
@@ -74,10 +75,10 @@ def plan_code_digest() -> str:
         import inspect
 
         try:
-            from ..memory import chain, dse, pipeline  # lazy: no cycle
+            from ..memory import chain, dse, pipeline, plan  # lazy: no cycle
 
             blob = "\n".join(
-                inspect.getsource(m) for m in (chain, dse, pipeline)
+                inspect.getsource(m) for m in (plan, chain, dse, pipeline)
             )
             _PLAN_CODE_DIGEST = hashlib.sha1(
                 blob.encode()

@@ -27,3 +27,14 @@ def subprocess_env(n_devices: int) -> dict:
         + os.pathsep + env.get("PYTHONPATH", "")
     )
     return env
+
+
+def helmholtz_chain(p, **kw):
+    """The inverse Helmholtz operator (paper Fig. 2) as a one-stage
+    ProgramChain: how a single operator is planned, explored and run."""
+    from repro.cfd import operators
+    from repro.memory.chain import ProgramChain
+
+    return ProgramChain(
+        [("helmholtz", operators.build_inverse_helmholtz(p, **kw))]
+    )

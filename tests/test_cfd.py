@@ -2,8 +2,10 @@
 import numpy as np
 import pytest
 
+from conftest import helmholtz_chain
 from repro.cfd import operators, reference, simulation
-from repro.cfd.simulation import SimConfig
+from repro.memory import channels
+from repro.memory.chain import plan_chain
 
 
 @pytest.mark.parametrize("backend", ["xla", "staged"])
@@ -53,29 +55,38 @@ def test_gradient(rng):
 
 
 def test_simulation_driver_batching():
-    cfg = SimConfig(p=5, n_eq=512, batch_elements=128)
-    assert cfg.n_batches == 4
-    res = simulation.run_simulation(cfg, max_batches=2)
+    chain = helmholtz_chain(5)
+    plan = plan_chain(chain, batch_elements=128, n_eq=512)
+    assert plan.batches_for(512) == 4
+    res = simulation.run_chain(chain, plan, n_eq=512, max_batches=2)
     assert res.elements == 256
-    assert np.isfinite(res.checksum)
+    assert np.isfinite(res.checksums["helmholtz.v"])
 
 
 def test_simulation_double_buffer_equivalence():
     """Ping/pong staging must not change results (paper Fig. 14a)."""
-    a = simulation.run_simulation(
-        SimConfig(p=5, n_eq=256, batch_elements=64, double_buffer=True),
-        max_batches=3,
+    chain = helmholtz_chain(5)
+    a, b = (
+        simulation.run_chain(
+            chain,
+            plan_chain(
+                chain, batch_elements=64, prefetch_depth=depth, n_eq=256
+            ),
+            n_eq=256, max_batches=3,
+        )
+        for depth in (1, 0)
     )
-    b = simulation.run_simulation(
-        SimConfig(p=5, n_eq=256, batch_elements=64, double_buffer=False),
-        max_batches=3,
-    )
-    assert abs(a.checksum - b.checksum) < 1e-3
+    assert abs(
+        a.checksums["helmholtz.v"] - b.checksums["helmholtz.v"]
+    ) < 1e-3
 
 
 def test_batch_for_channel_matches_paper_sizing():
     """Paper: E = elements whose I/O fits one 256 MB HBM pseudo-channel."""
-    E = SimConfig.batch_for_channel(11, bytes_per_scalar=8)
+    E = helmholtz_chain(11).auto_batch_elements(
+        channels.ALVEO_U280, bytes_per_scalar=8
+    )
+    assert channels.ALVEO_U280.channel_bytes == 256 * 2 ** 20
     assert E == (256 * 2 ** 20) // (3 * 11 ** 3 * 8)
 
 

@@ -9,10 +9,11 @@ import time
 import numpy as np
 import pytest
 
+from conftest import helmholtz_chain
 from repro import trace
 from repro.cfd import operators, simulation
 from repro.memory import chain as mchain
-from repro.memory import channels, dse, layout
+from repro.memory import channels, layout
 
 
 @pytest.fixture(scope="module")
@@ -99,8 +100,9 @@ def test_chain_plan_fewer_host_bytes_than_standalone(cfd_chain):
     t = channels.ALVEO_U280
     plan = mchain.plan_chain(cfd_chain, target=t, batch_elements=E)
     standalone = sum(
-        dse.make_plan(
-            s.program, target=t, batch_elements=E, operator_name=s.name
+        mchain.plan_chain(
+            mchain.ProgramChain([(s.name, s.compiled)]), target=t,
+            batch_elements=E,
         ).host_stream_bytes
         for s in cfd_chain.stages
     )
@@ -597,7 +599,9 @@ def test_resident_inputs_are_not_donated_and_sweeps_repeat(rng):
 def test_plan_infeasible_when_block_floor_exceeds_vmem():
     """Even the BE=1 block must fit on-chip, or the plan says so."""
     tiny = channels.ALVEO_U280.with_(vmem_bytes=8192)
-    plan = dse.make_plan(11, target=tiny, batch_elements=64)
+    plan = mchain.plan_chain(
+        helmholtz_chain(11), target=tiny, batch_elements=64
+    )
     assert not plan.feasible
     assert "block working set" in plan.infeasible_reason
     chain_plan = mchain.plan_chain(
@@ -616,20 +620,22 @@ def test_plan_block_elements_fits_vmem():
     """Acceptance: the plan-chosen Pallas block's working set fits the
     target's VMEM, divides E, and shows up in the report."""
     t = channels.TPU_V5E
-    plan = dse.make_plan(
-        11, target=t, backend="pallas", batch_elements=4096
+    plan = mchain.plan_chain(
+        helmholtz_chain(11), target=t, backends=("pallas",),
+        batch_elements=4096,
     )
-    assert plan.block_elements > 1
-    assert plan.batch_elements % plan.block_elements == 0
-    assert plan.block_working_set_bytes <= t.vmem_bytes
-    assert f"vmem block BE={plan.block_elements}" in plan.report()
+    sp = plan.stages[0]
+    assert sp.block_elements > 1
+    assert plan.batch_elements % sp.block_elements == 0
+    assert sp.block_working_set_bytes <= t.vmem_bytes
+    assert f"BE={sp.block_elements} (vmem ws" in plan.report()
     # maximal: the next power of two would blow the reserve budget
     from repro.kernels.helmholtz import ops as hops
     assert hops.block_working_set_bytes(
-        11, plan.block_elements, t
-    ) == plan.block_working_set_bytes
-    bigger = min(plan.block_elements * 2, plan.batch_elements)
-    if bigger > plan.block_elements:
+        11, sp.block_elements, t
+    ) == sp.block_working_set_bytes
+    bigger = min(sp.block_elements * 2, plan.batch_elements)
+    if bigger > sp.block_elements:
         assert hops.block_working_set_bytes(
             11, bigger, t
         ) > layout.vmem_budget_bytes(t)
@@ -648,13 +654,16 @@ def test_tpu_block_working_set_is_padded_and_double_buffered():
     def hops_ws(be, *, tiled):
         return hops.block_working_set_bytes(11, be, t if tiled else untiled)
 
-    plan = dse.make_plan(11, target=t, backend="pallas")
-    blk, p3 = plan.block_elements, 11 ** 3
+    plan = mchain.plan_chain(
+        helmholtz_chain(11), target=t, backends=("pallas",)
+    )
+    sp = plan.stages[0]
+    blk, p3 = sp.block_elements, 11 ** 3
     rows = -(-(-(-blk // 128)) // 8) * 8          # whole sublane tiles
     windows = 2 * 3 * p3 * rows * 128 * 4         # u, D, v; two buffers
     scratch = 2 * p3 * 8 * 128 * 4                # t/r, one chunk each
-    assert plan.block_working_set_bytes == windows + scratch + 11 * 11 * 4
-    assert plan.block_working_set_bytes <= layout.vmem_budget_bytes(t)
+    assert sp.block_working_set_bytes == windows + scratch + 11 * 11 * 4
+    assert sp.block_working_set_bytes <= layout.vmem_budget_bytes(t)
     assert blk >= 1024 and plan.batch_elements % blk == 0
     # padding is real: the tiled count is never below the unpadded one,
     # and a block under one tile of rows costs a whole tile
@@ -664,28 +673,19 @@ def test_tpu_block_working_set_is_padded_and_double_buffered():
     assert hops_ws(1, tiled=True) == hops_ws(1024, tiled=True)
 
 
-def test_pallas_block_resolution_prefers_plan():
-    plan = dse.make_plan(
-        5, target=channels.TPU_V5E, backend="pallas", batch_elements=1024
-    )
-    assert operators.pallas_block_elements(plan) == plan.block_elements
-    from repro.kernels.helmholtz.ops import DEFAULT_BLOCK_ELEMENTS
-    assert operators.pallas_block_elements() == DEFAULT_BLOCK_ELEMENTS
-
-
 def test_pallas_backend_runs_with_plan_block(rng):
     """The plan-driven block produces correct results through the
     compiled pallas path (interpret mode on CPU)."""
     p, E = 5, 8
-    plan = dse.make_plan(
-        p, target=channels.CPU_HOST, backend="pallas", batch_elements=E
+    plan = mchain.plan_chain(
+        helmholtz_chain(p), target=channels.CPU_HOST, backends=("pallas",),
+        batch_elements=E,
     )
-    assert plan.block_elements >= 1
-    assert E % plan.block_elements == 0
+    blk = plan.stages[0].block_elements
+    assert blk >= 1
+    assert E % blk == 0
     from repro.kernels.helmholtz import ops as hops
-    impl = hops.make_pallas_impl(
-        impl="interpret", block_elements=plan.block_elements
-    )
+    impl = hops.make_pallas_impl(impl="interpret", block_elements=blk)
     S = rng.uniform(-1, 1, (p, p)).astype(np.float32)
     D = rng.uniform(-1, 1, (E, p, p, p)).astype(np.float32)
     u = rng.uniform(-1, 1, (E, p, p, p)).astype(np.float32)
@@ -695,18 +695,43 @@ def test_pallas_backend_runs_with_plan_block(rng):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-def test_chain_plan_block_reaches_pallas_stage(monkeypatch):
-    """Regression: rebuilding the chain with its ChainPlan threads the
-    plan's per-stage VMEM block into the Pallas Helmholtz kernel (the
+#: (plan-only chain, planned backends, rebuild with the plan's block)
+#: for the composed CFD chain and the Helmholtz operator alone
+_PALLAS_REBUILDS = {
+    "cfd": (
+        operators.build_cfd_chain,
+        ("xla", "xla", "pallas"),
+        lambda p, plan: operators.build_cfd_chain(
+            p, backends=("xla", "xla", "pallas"), chain_plan=plan
+        ),
+    ),
+    "helmholtz": (
+        helmholtz_chain,
+        ("pallas",),
+        lambda p, plan: operators.build_inverse_helmholtz(
+            p, backend="pallas",
+            block_elements=operators.chain_stage_block_elements(
+                plan, "helmholtz"
+            ),
+        ),
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PALLAS_REBUILDS))
+def test_chain_plan_block_reaches_pallas_stage(monkeypatch, name):
+    """Regression: rebuilding with the ChainPlan threads the plan's
+    per-stage VMEM block into the Pallas Helmholtz kernel (the
     compiled-before-planning chain cannot know it)."""
+    build, backends, rebuild = _PALLAS_REBUILDS[name]
     p, E = 5, 192  # not divisible by the kernel default of 128
-    plan_only = operators.build_cfd_chain(p)
+    plan_only = build(p)
     plan = mchain.plan_chain(
         plan_only, target=channels.TPU_V5E, batch_elements=E,
-        backends=("xla", "xla", "pallas"),
+        backends=backends,
     )
     blk = operators.chain_stage_block_elements(plan, "helmholtz")
-    assert blk == plan.stages[2].block_elements
+    assert blk == plan.stages[-1].block_elements
     assert blk is not None and E % blk == 0
     assert operators.chain_stage_block_elements(plan, "nosuch") is None
     assert operators.chain_stage_block_elements(None, "helmholtz") is None
@@ -722,9 +747,7 @@ def test_chain_plan_block_reaches_pallas_stage(monkeypatch):
     monkeypatch.setattr(
         "repro.cfd.operators.helmholtz_ops.make_pallas_impl", spy
     )
-    operators.build_cfd_chain(
-        p, backends=("xla", "xla", "pallas"), chain_plan=plan
-    )
+    rebuild(p, plan)
     assert seen["block_elements"] == blk
 
 

@@ -1,12 +1,30 @@
 """DSE coverage: predict_cost monotonicity, pareto_front semantics, the
 measured-feedback CostCorrection, and explore-with-measurement smokes
-(single-op and chain)."""
+(a one-stage chain and the CFD chain)."""
 import dataclasses
 
 import pytest
 
+from conftest import helmholtz_chain
 from repro.cfd import operators
 from repro.memory import channels, dse
+from repro.memory.chain import plan_chain
+from repro.memory.plan import predict_cost
+
+#: the paper's evaluation grid: both backends and policies, E at the
+#: full channel and its halves, K up to 4, up to 4 CUs
+PAPER_SPACE = dse.ChainDesignSpace(
+    backends=("xla", "staged"), policies=("float32", "bfloat16"),
+    batch_divisors=(1, 2, 4), prefetch_depths=(0, 1, 2, 4),
+    cu_counts=(1, 2, 4),
+)
+
+#: the chains the explorer smokes run on: the inverse Helmholtz operator
+#: alone and the composed CFD application, both at p=5
+CHAINS = {
+    "helmholtz": lambda: helmholtz_chain(5),
+    "cfd": lambda: operators.build_cfd_chain(5),
+}
 
 
 BASE = dict(
@@ -18,7 +36,7 @@ BASE = dict(
 
 def _cost(**over):
     kw = {**BASE, **over}
-    return dse.predict_cost(channels.ALVEO_U280, **kw)
+    return predict_cost(channels.ALVEO_U280, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -73,7 +91,10 @@ def test_predict_cost_fill_term_bounded():
 
 
 def test_pareto_front_on_explored_candidates():
-    cands = dse.explore(7, target=channels.ALVEO_U280, n_eq=1 << 14)
+    cands = dse.explore_chain(
+        helmholtz_chain(7), target=channels.ALVEO_U280, n_eq=1 << 14,
+        space=PAPER_SPACE,
+    )
     front = dse.pareto_front(cands)
     assert front
     feas = [c for c in cands if c.plan.feasible]
@@ -104,10 +125,10 @@ def test_pareto_front_on_explored_candidates():
 
 
 def test_pareto_front_excludes_infeasible():
-    cands = dse.explore(
-        11,
+    cands = dse.explore_chain(
+        helmholtz_chain(11),
         target=channels.ALVEO_U280.with_(hbm_bytes=2 ** 26, n_channels=4),
-        n_eq=1 << 16,
+        n_eq=1 << 16, space=PAPER_SPACE,
     )
     front = dse.pareto_front(cands)
     assert all(c.plan.feasible for c in front)
@@ -118,8 +139,14 @@ def test_pareto_front_excludes_infeasible():
 # ---------------------------------------------------------------------------
 
 
+def _plan():
+    return plan_chain(
+        helmholtz_chain(5), target=channels.ALVEO_U280, batch_elements=64
+    )
+
+
 def _measured_cand(pred, meas, feasible=True):
-    plan = dse.make_plan(5, target=channels.ALVEO_U280, batch_elements=64)
+    plan = _plan()
     if not feasible:
         plan = dataclasses.replace(plan, feasible=False)
     return dse.Candidate(
@@ -147,10 +174,11 @@ def test_fit_correction_identity_without_measurements():
 
 
 def _cand_with_terms(pred, meas, t_compute, t_hbm, t_host):
-    plan = dse.make_plan(5, target=channels.ALVEO_U280, batch_elements=64)
-    cost = dataclasses.replace(
-        plan.cost, t_compute=t_compute, t_hbm=t_hbm, t_host=t_host
+    plan = _plan()
+    stage = dataclasses.replace(
+        plan.cost.stages[0], t_compute=t_compute, t_hbm=t_hbm, t_host=t_host
     )
+    cost = dataclasses.replace(plan.cost, stages=(stage,))
     return dse.Candidate(
         plan=dataclasses.replace(plan, cost=cost),
         predicted_s_per_element=pred, measured_s_per_element=meas,
@@ -182,11 +210,6 @@ def test_fit_correction_learns_per_term_factors():
     assert comp.corrected_s_per_element == pytest.approx(8e-6)
 
 
-def test_calibrate_requires_measurement():
-    with pytest.raises(ValueError, match="measure_top"):
-        dse.explore(5, target=channels.CPU_HOST, n_eq=64, calibrate=True)
-
-
 def test_apply_correction_reranks():
     slow = _measured_cand(1e-6, 5e-6)       # measured: actually slow
     fast = _measured_cand(2e-6, None)       # predicted-only
@@ -195,24 +218,6 @@ def test_apply_correction_reranks():
     # measured 5e-6, so the measured candidate wins the re-rank
     assert ranked[0] is slow
     assert fast.corrected_s_per_element == pytest.approx(1e-5)
-
-
-@pytest.mark.slow
-def test_explore_calibrate_smoke():
-    """Measure-then-calibrate on a tiny program: every candidate gains a
-    corrected prediction and feasible candidates stay ranked first."""
-    space = dse.DesignSpace(
-        backends=("xla",), policies=("float32",), batch_divisors=(1, 2),
-        prefetch_depths=(0, 1), cu_counts=(1,),
-    )
-    cands = dse.explore(
-        5, target=channels.CPU_HOST, n_eq=128, space=space,
-        measure_top=1, measure_batches=2, calibrate=True,
-    )
-    assert any(c.verified for c in cands)
-    assert all(c.corrected_s_per_element is not None for c in cands)
-    feas = [c.plan.feasible for c in cands]
-    assert feas == sorted(feas, reverse=True)
 
 
 # ---------------------------------------------------------------------------
@@ -245,7 +250,6 @@ def test_explore_chain_ranked_and_pareto():
     pred = [c.predicted_s_per_element for c in feas]
     assert pred == sorted(pred)
     assert all(c.plan.feasible for c in cands[: len(feas)])
-    # ChainPlan quacks enough like MemoryPlan for the same pareto code
     front = dse.pareto_front(cands)
     assert front and all(c.plan.feasible for c in front)
     # per-stage backends really vary across the sweep
@@ -312,23 +316,31 @@ def test_chain_cost_overlap_term():
     assert one.cost.t_overlapped == pytest.approx(one.cost.t_back_to_back)
 
 
-def test_explore_chain_calibrate_requires_measurement():
-    chain = operators.build_cfd_chain(5)
+@pytest.mark.parametrize("name", sorted(CHAINS))
+def test_explore_chain_calibrate_requires_measurement(name):
+    chain = CHAINS[name]()
     with pytest.raises(ValueError, match="measure_top"):
-        dse.explore_chain(chain, target=channels.CPU_HOST, calibrate=True)
+        dse.explore_chain(
+            chain, target=channels.CPU_HOST, n_eq=64, calibrate=True
+        )
 
 
 @pytest.mark.slow
-def test_explore_chain_calibrate_smoke():
+@pytest.mark.parametrize(
+    "name,n_eq,divisors",
+    [("helmholtz", 128, (1, 2)), ("cfd", 64, (1,))],
+    ids=["helmholtz", "cfd"],
+)
+def test_explore_chain_calibrate_smoke(name, n_eq, divisors):
     """Measure-then-calibrate on the real chain driver: every candidate
     gains a corrected prediction, feasible candidates stay ranked
     first."""
-    chain = operators.build_cfd_chain(5)
+    chain = CHAINS[name]()
     space = dse.ChainDesignSpace(
-        backends=("xla",), batch_divisors=(1,), prefetch_depths=(0, 1),
+        backends=("xla",), batch_divisors=divisors, prefetch_depths=(0, 1),
     )
     cands = dse.explore_chain(
-        chain, target=channels.CPU_HOST, n_eq=64, space=space,
+        chain, target=channels.CPU_HOST, n_eq=n_eq, space=space,
         measure_top=1, measure_batches=2, calibrate=True,
     )
     assert any(c.verified for c in cands)
@@ -338,15 +350,19 @@ def test_explore_chain_calibrate_smoke():
 
 
 @pytest.mark.slow
-def test_explore_chain_measures_matching_candidates():
+@pytest.mark.parametrize(
+    "name,n_eq", [("helmholtz", 256), ("cfd", 64)],
+    ids=["helmholtz", "cfd"],
+)
+def test_explore_chain_measures_matching_candidates(name, n_eq):
     """measure_top verifies the best candidates whose planned backends
     match how the chain was compiled, through the real run_chain."""
-    chain = operators.build_cfd_chain(5)
+    chain = CHAINS[name]()
     space = dse.ChainDesignSpace(
         backends=("xla",), batch_divisors=(1,), prefetch_depths=(0, 1),
     )
     cands = dse.explore_chain(
-        chain, target=channels.CPU_HOST, n_eq=64, space=space,
+        chain, target=channels.CPU_HOST, n_eq=n_eq, space=space,
         measure_top=1, measure_batches=2,
     )
     assert any(c.verified for c in cands)
@@ -354,7 +370,7 @@ def test_explore_chain_measures_matching_candidates():
     assert best.measured_s_per_element > 0
     # a plan whose backends differ from the compiled chain is refused
     staged_plan = dse.explore_chain(
-        chain, target=channels.CPU_HOST, n_eq=64,
+        chain, target=channels.CPU_HOST, n_eq=n_eq,
         space=dse.ChainDesignSpace(
             backends=("staged",), batch_divisors=(1,),
             prefetch_depths=(0,),

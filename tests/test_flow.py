@@ -587,8 +587,9 @@ def _check_derived_chain_validates(k, steps, e):
     )
     assert n_resident == len(chain.stages) - 1  # a linear pipeline
     standalone = sum(
-        dse.make_plan(
-            s.program, target=t, batch_elements=e, operator_name=s.name
+        mchain.plan_chain(
+            mchain.ProgramChain([(s.name, s.compiled)]), target=t,
+            batch_elements=e,
         ).host_stream_bytes
         for s in chain.stages
     )

@@ -12,9 +12,10 @@ import pathlib
 
 import pytest
 
+from conftest import helmholtz_chain
 from repro.cfd import operators
 from repro.memory import chain as mchain
-from repro.memory import channels, dse
+from repro.memory import channels
 
 GOLDEN_DIR = pathlib.Path(__file__).parent / "golden"
 
@@ -39,17 +40,18 @@ def _check(name: str, rendered: str) -> None:
 
 
 def test_golden_single_op_plan():
-    plan = dse.make_plan(
-        7, target=channels.ALVEO_U280, policy="float32",
+    """A single operator is a one-stage chain."""
+    plan = mchain.plan_chain(
+        helmholtz_chain(7), target=channels.ALVEO_U280, policy="float32",
         prefetch_depth=1, n_eq=1 << 16,
     )
     _check("plan_helmholtz_p7_alveo.txt", plan.report())
 
 
 def test_golden_staged_plan():
-    plan = dse.make_plan(
-        7, target=channels.ALVEO_U280, policy="float32",
-        backend="staged", prefetch_depth=2, n_eq=1 << 16,
+    plan = mchain.plan_chain(
+        helmholtz_chain(7, backend="staged"), target=channels.ALVEO_U280,
+        policy="float32", prefetch_depth=2, n_eq=1 << 16,
     )
     _check("plan_helmholtz_p7_staged_alveo.txt", plan.report())
 
@@ -57,8 +59,8 @@ def test_golden_staged_plan():
 def test_golden_bf16_plan():
     """Locks the policy-width threading: a bfloat16 plan's byte counts
     are half the float32 plan's."""
-    plan = dse.make_plan(
-        7, target=channels.ALVEO_U280, policy="bfloat16",
+    plan = mchain.plan_chain(
+        helmholtz_chain(7), target=channels.ALVEO_U280, policy="bfloat16",
         prefetch_depth=1, n_eq=1 << 16,
     )
     _check("plan_helmholtz_p7_bf16_alveo.txt", plan.report())

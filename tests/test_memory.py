@@ -1,16 +1,18 @@
 """repro.memory: planner determinism, paper batch sizing, DSE cost-model
-monotonicity, and end-to-end prefetch-pipeline equivalence."""
+monotonicity, and end-to-end prefetch-pipeline equivalence.  A single
+operator is planned, explored and run as a one-stage chain."""
 import warnings
 
 import jax
 import numpy as np
 import pytest
 
+from conftest import helmholtz_chain
 from repro.cfd import operators, simulation
-from repro.cfd.simulation import SimConfig
 from repro.core import dsl, emit, rewrite, schedule
 from repro.memory import channels, dse, layout
 from repro.memory import pipeline as mempipe
+from repro.memory.chain import plan_chain
 
 
 def _helmholtz_prog(p):
@@ -29,26 +31,28 @@ def _helmholtz_prog(p):
 
 def test_plan_determinism():
     """Same inputs -> identical plan (buffers, channels, cost, report)."""
-    a = dse.make_plan(11, target=channels.ALVEO_U280)
-    b = dse.make_plan(11, target=channels.ALVEO_U280)
+    a = plan_chain(helmholtz_chain(11), target=channels.ALVEO_U280)
+    b = plan_chain(helmholtz_chain(11), target=channels.ALVEO_U280)
     assert a == b
     assert a.report() == b.report()
 
 
 def test_auto_batch_matches_paper_channel_sizing():
-    """The planner's E equals SimConfig.batch_for_channel for the paper's
-    256 MB pseudo-channel (Alveo U280: 8 GiB HBM2 / 32 channels), up to
-    the block padding that keeps E a VMEM-block multiple (prime-ish
-    channel quotients must never force the Pallas block divisor tiny)."""
+    """The planner's E is the paper's: the elements whose u, D and v
+    fit one 256 MB pseudo-channel (Alveo U280: 8 GiB HBM2 / 32
+    channels), up to the block padding that keeps E a VMEM-block
+    multiple (prime-ish channel quotients must never force the Pallas
+    block divisor tiny)."""
     t = channels.ALVEO_U280
     assert t.channel_bytes == 256 * 2 ** 20
     for p in (7, 11):
-        plan = dse.make_plan(p, target=t, policy="float32")
+        plan = plan_chain(helmholtz_chain(p), target=t, policy="float32")
+        blk = plan.stages[0].block_elements
         base = plan.batch_elements - plan.batch_pad_elements
-        assert base == SimConfig.batch_for_channel(p, t.channel_bytes, 4)
+        assert base == t.channel_bytes // (3 * p ** 3 * 4)
         # padding did its job: E is block-composite, never block-starved
-        assert plan.batch_elements % plan.block_elements == 0
-        assert plan.block_elements * 2 >= layout.vmem_block_elements(
+        assert plan.batch_elements % blk == 0
+        assert blk * 2 >= layout.vmem_block_elements(
             rewrite.optimize(dsl.inverse_helmholtz_program(p)), t,
             bytes_per_scalar=4,
         )
@@ -57,7 +61,9 @@ def test_auto_batch_matches_paper_channel_sizing():
 
 
 def test_auto_batch_capped_by_problem_size():
-    plan = dse.make_plan(11, target=channels.ALVEO_U280, n_eq=1000)
+    plan = plan_chain(
+        helmholtz_chain(11), target=channels.ALVEO_U280, n_eq=1000
+    )
     assert plan.batch_elements == 1000
 
 
@@ -79,15 +85,19 @@ def test_pad_batch_for_block():
 
 
 def test_plan_buffers_and_channels():
-    plan = dse.make_plan(11, target=channels.ALVEO_U280, prefetch_depth=1)
+    chain = helmholtz_chain(11)
+    plan = plan_chain(chain, target=channels.ALVEO_U280, prefetch_depth=1)
     roles = {b.name: b.role for b in plan.buffers}
-    assert roles == {"D": "in", "u": "in", "v": "out", "S": "shared"}
+    assert roles == {
+        "helmholtz.D": "in", "helmholtz.u": "in", "helmholtz.v": "out",
+        "S": "shared",
+    }
     ins = [b for b in plan.buffers if b.role == "in"]
     # K=1 prefetch: ping/pong pair + the retiring batch JAX frees only
     # after its async compute completes = 3 resident replicas
     assert all(b.replicas == 3 for b in ins)
-    serial = dse.make_plan(
-        11, target=channels.ALVEO_U280, prefetch_depth=0
+    serial = plan_chain(
+        chain, target=channels.ALVEO_U280, prefetch_depth=0
     )
     assert all(
         b.replicas == 1 for b in serial.buffers if b.role == "in"
@@ -101,7 +111,10 @@ def test_plan_buffers_and_channels():
 
 
 def test_staged_plan_has_intermediate_buffers():
-    plan = dse.make_plan(11, target=channels.ALVEO_U280, backend="staged")
+    plan = plan_chain(
+        helmholtz_chain(11), target=channels.ALVEO_U280,
+        backends=("staged",),
+    )
     inters = [b for b in plan.buffers if b.role == "inter"]
     assert inters, "staged backend must expose group-boundary streams"
     # intermediates cross HBM twice (write + read back)
@@ -110,7 +123,7 @@ def test_staged_plan_has_intermediate_buffers():
 
 def test_infeasible_plan_reported_not_raised():
     tiny = channels.ALVEO_U280.with_(hbm_bytes=2 ** 20, n_channels=4)
-    plan = dse.make_plan(11, target=tiny, batch_elements=4096)
+    plan = plan_chain(helmholtz_chain(11), target=tiny, batch_elements=4096)
     assert not plan.feasible
     assert "exceeds" in plan.infeasible_reason
     assert "NO" in plan.report()
@@ -124,30 +137,32 @@ def test_infeasible_plan_reported_not_raised():
 def test_cost_monotone_in_bandwidth():
     """More bandwidth must never predict a slower plan."""
     base_t = channels.ALVEO_U280
+    chain = helmholtz_chain(11)
     points = [
-        dict(backend="xla", prefetch_depth=0),
-        dict(backend="xla", prefetch_depth=1),
-        dict(backend="xla", prefetch_depth=4, cu_count=4),
-        dict(backend="staged", prefetch_depth=1),
-        dict(backend="staged", prefetch_depth=2, policy="bfloat16"),
+        dict(backends=("xla",), prefetch_depth=0),
+        dict(backends=("xla",), prefetch_depth=1),
+        dict(backends=("xla",), prefetch_depth=4, cu_count=4),
+        dict(backends=("staged",), prefetch_depth=1),
+        dict(backends=("staged",), prefetch_depth=2, policy="bfloat16"),
     ]
     for kw in points:
-        prev = dse.make_plan(11, target=base_t, n_eq=1 << 16, **kw)
+        prev = plan_chain(chain, target=base_t, n_eq=1 << 16, **kw)
         for scale in (2.0, 4.0, 16.0):
             t = base_t.with_(
                 hbm_bw=base_t.hbm_bw * scale,
                 host_link_bw=base_t.host_link_bw * scale,
             )
-            cur = dse.make_plan(11, target=t, n_eq=1 << 16, **kw)
+            cur = plan_chain(chain, target=t, n_eq=1 << 16, **kw)
             assert cur.cost.t_pipelined <= prev.cost.t_pipelined * (1 + 1e-12)
             assert cur.cost.t_serial <= prev.cost.t_serial * (1 + 1e-12)
             prev = cur
 
 
 def test_cost_overlap_never_slower_than_serial():
+    chain = helmholtz_chain(11)
     for depth in (1, 2, 4):
-        plan = dse.make_plan(
-            11, target=channels.ALVEO_U280, prefetch_depth=depth,
+        plan = plan_chain(
+            chain, target=channels.ALVEO_U280, prefetch_depth=depth,
             n_eq=1 << 20,
         )
         assert plan.cost.t_pipelined <= plan.cost.t_serial * (1 + 1e-12)
@@ -155,7 +170,17 @@ def test_cost_overlap_never_slower_than_serial():
 
 
 def test_explore_returns_ranked_set():
-    cands = dse.explore(11, target=channels.ALVEO_U280, n_eq=1 << 16)
+    # the paper's evaluation grid: both backends and policies, E at the
+    # full channel and its halves, K up to 4, up to 4 CUs
+    space = dse.ChainDesignSpace(
+        backends=("xla", "staged"), policies=("float32", "bfloat16"),
+        batch_divisors=(1, 2, 4), prefetch_depths=(0, 1, 2, 4),
+        cu_counts=(1, 2, 4),
+    )
+    cands = dse.explore_chain(
+        helmholtz_chain(11), target=channels.ALVEO_U280, n_eq=1 << 16,
+        space=space,
+    )
     assert len(cands) > 20
     feas = [c for c in cands if c.plan.feasible]
     assert feas, "the paper's operating point must be feasible"
@@ -167,21 +192,6 @@ def test_explore_returns_ranked_set():
     assert front
     assert all(c.plan.feasible for c in front)
     assert set(id(c) for c in front) <= set(id(c) for c in cands)
-
-
-@pytest.mark.slow
-def test_explore_measures_top_candidate():
-    space = dse.DesignSpace(
-        backends=("xla",), policies=("float32",), batch_divisors=(1,),
-        prefetch_depths=(0, 1), cu_counts=(1,),
-    )
-    cands = dse.explore(
-        5, target=channels.CPU_HOST, n_eq=256, space=space, measure_top=1,
-        measure_batches=2,
-    )
-    assert any(c.verified for c in cands)
-    best = next(c for c in cands if c.verified)
-    assert best.measured_s_per_element > 0
 
 
 # ---------------------------------------------------------------------------
@@ -197,13 +207,20 @@ def test_prefetch_depth_semantics():
         staged_log.append(x)
         return x
 
-    for x in mempipe.prefetch(range(5), stage, depth=2):
+    def consume(x, carry):
         # when item k is consumed, items up to k+2 are already staged
         consumed.append(x)
         assert len(staged_log) >= min(5, len(consumed) + 2)
-    assert consumed == list(range(5))
+        return x
+
+    out = mempipe.run_stage_pipelined(
+        [consume], range(5), stage_fn=stage, depths=2
+    )
+    assert consumed == out == list(range(5))
     with pytest.raises(ValueError):
-        list(mempipe.prefetch(range(3), stage, depth=-1))
+        mempipe.run_stage_pipelined(
+            [consume], range(3), stage_fn=stage, depths=-1
+        )
 
 
 def test_pipelined_run_bitwise_matches_serial(rng):
@@ -220,15 +237,15 @@ def test_pipelined_run_bitwise_matches_serial(rng):
         for _ in range(4)
     ]
 
-    def compute(staged):
+    def compute(staged, carry):
         return c.batched_fn({"S": S, **staged})["v"]
 
     stage = lambda b: {k: jax.device_put(v) for k, v in b.items()}
-    serial = mempipe.run_pipelined(
-        compute, batches, stage_fn=stage, depth=0
+    serial = mempipe.run_stage_pipelined(
+        [compute], batches, stage_fn=stage, depths=0
     )
-    deep = mempipe.run_pipelined(
-        compute, batches, stage_fn=stage, depth=2
+    deep = mempipe.run_stage_pipelined(
+        [compute], batches, stage_fn=stage, depths=2
     )
     assert len(serial) == len(deep) == 4
     for a, b in zip(serial, deep):
@@ -385,24 +402,29 @@ def test_stage_pipelined_bitwise_matches_serial_on_device(rng):
 
 
 def test_simulation_driver_plan_resolves_batch():
-    """No hardcoded E: the planner sizes the batch from the channel model."""
-    cfg = SimConfig(p=5, n_eq=512)  # batch_elements unset
-    res = simulation.run_simulation(cfg, max_batches=2)
-    assert res.plan is not None
-    assert res.plan.batch_elements >= 1
-    assert res.elements == res.batches * res.plan.batch_elements
-    assert np.isfinite(res.checksum)
-    with pytest.raises(ValueError):
-        cfg.n_batches  # unresolved config cannot count batches
+    """No hardcoded E: the planner sizes the batch from the channel model
+    and the executor runs the batches the plan sized."""
+    chain = helmholtz_chain(5)
+    plan = plan_chain(chain, n_eq=512)  # batch_elements unset
+    assert plan.batch_elements >= 1
+    res = simulation.run_chain(chain, plan, n_eq=512, max_batches=2)
+    assert res.plan is plan
+    assert res.elements == res.batches * plan.batch_elements
+    assert res.checksums and all(
+        np.isfinite(v) for v in res.checksums.values()
+    )
 
 
 def test_simulation_checksum_invariant_to_prefetch_depth():
+    chain = helmholtz_chain(5)
     res = {}
     for depth in (0, 1, 3):
-        cfg = SimConfig(
-            p=5, n_eq=256, batch_elements=64, prefetch_depth=depth
+        plan = plan_chain(
+            chain, batch_elements=64, prefetch_depth=depth, n_eq=256
         )
-        res[depth] = simulation.run_simulation(cfg, max_batches=4).checksum
+        res[depth] = simulation.run_chain(
+            chain, plan, n_eq=256, max_batches=4
+        ).checksums["helmholtz.v"]
     assert res[0] == pytest.approx(res[1], abs=1e-6)
     assert res[0] == pytest.approx(res[3], abs=1e-6)
 
@@ -461,7 +483,6 @@ def test_roofline_shares_channel_constants():
 
 
 def test_layout_stream_bytes_match_simconfig_model():
+    """u, D in and v out: three p^3 records per element (S is shared)."""
     prog = _helmholtz_prog(11)
-    assert layout.stream_bytes_per_element(prog, 4) == SimConfig(
-        p=11
-    ).bytes_per_element(4)
+    assert layout.stream_bytes_per_element(prog, 4) == 3 * 11 ** 3 * 4

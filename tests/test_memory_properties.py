@@ -2,20 +2,39 @@
 cleanly where hypothesis is not installed):
 
   * padded record sizes are burst-multiples (and minimal),
-  * auto_batch_elements never overflows a pseudo-channel,
+  * the chain's auto_batch_elements never overflows a pseudo-channel,
   * channel assignment never double-books a channel within one replica
-    set, for single programs and for chains sharing one allocator.
+    set, for single programs (one-stage chains) and for chains sharing
+    one allocator.
 """
+import functools
+
 import pytest
 
 hypothesis = pytest.importorskip("hypothesis")
 
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
-from repro.core import ir  # noqa: E402
+from conftest import helmholtz_chain  # noqa: E402
+from repro.core import emit, ir  # noqa: E402
 from repro.memory import channels, layout  # noqa: E402
 from repro.memory.chain import ProgramChain, plan_chain  # noqa: E402
-from repro.memory.dse import make_plan  # noqa: E402
+
+
+#: the policy whose scalars are ``bps`` bytes wide
+POLICY_OF_WIDTH = {2: "bfloat16", 4: "float32", 8: "float64"}
+
+
+def _one_stage(prog):
+    """A random program as a one-stage chain (how the planner sees a
+    single operator)."""
+    return ProgramChain([("s", emit.compile_program(prog))])
+
+
+@functools.lru_cache(maxsize=None)
+def _helmholtz(p):
+    """The one-stage inverse Helmholtz chain, compiled once per degree."""
+    return helmholtz_chain(p)
 
 
 # -- strategies --------------------------------------------------------------
@@ -76,11 +95,11 @@ def test_pad_to_burst_is_minimal_burst_multiple(nbytes, burst):
        bps=st.sampled_from([2, 4, 8]))
 @settings(max_examples=50, deadline=None)
 def test_padded_records_are_burst_multiples(prog, target, bps):
-    bufs = layout.build_buffers(
-        prog, target, bytes_per_scalar=bps, batch_elements=7,
-        prefetch_depth=1,
+    plan = plan_chain(
+        _one_stage(prog), target=target, policy=POLICY_OF_WIDTH[bps],
+        batch_elements=7, prefetch_depth=1,
     )
-    for b in bufs:
+    for b in plan.buffers:
         assert b.padded_bytes % target.burst_bytes == 0
         assert b.padded_bytes >= b.element_bytes
         if b.role != "shared":
@@ -95,8 +114,8 @@ def test_padded_records_are_burst_multiples(prog, target, bps):
        n_eq=st.one_of(st.none(), st.integers(1, 1 << 22)))
 @settings(max_examples=50, deadline=None)
 def test_auto_batch_never_overflows_channel(prog, target, bps, n_eq):
-    e = layout.auto_batch_elements(
-        prog, target, bytes_per_scalar=bps, n_eq=n_eq
+    e = _one_stage(prog).auto_batch_elements(
+        target, bytes_per_scalar=bps, n_eq=n_eq
     )
     per = layout.stream_bytes_per_element(prog, bps)
     assert e >= 1
@@ -118,11 +137,11 @@ def test_auto_batch_never_overflows_channel(prog, target, bps, n_eq):
        depth=st.integers(0, 4))
 @settings(max_examples=50, deadline=None)
 def test_channels_never_double_booked(prog, target, depth):
-    bufs = layout.build_buffers(
-        prog, target, bytes_per_scalar=4, batch_elements=3,
+    plan = plan_chain(
+        _one_stage(prog), target=target, batch_elements=3,
         prefetch_depth=depth,
     )
-    for b in bufs:
+    for b in plan.buffers:
         assert len(b.channels) == len(set(b.channels)), b.name
         assert all(0 <= c < target.n_channels for c in b.channels)
 
@@ -141,12 +160,13 @@ def test_allocator_takes_are_duplicate_free(n_channels, takes):
        target=targets)
 @settings(max_examples=25, deadline=None)
 def test_plan_blocks_divide_e_and_fit_vmem(p, e, target):
-    plan = make_plan(p, target=target, batch_elements=e)
-    assert plan.block_elements >= 1
-    assert plan.batch_elements % plan.block_elements == 0
+    plan = plan_chain(_helmholtz(p), target=target, batch_elements=e)
+    sp = plan.stages[0]
+    assert sp.block_elements >= 1
+    assert plan.batch_elements % sp.block_elements == 0
     # the ALVEO-derived targets keep 43 MiB of PLM, so even the BE=1
     # floor fits; the chosen block must always respect the capacity
-    assert plan.block_working_set_bytes <= target.vmem_bytes
+    assert sp.block_working_set_bytes <= target.vmem_bytes
 
 
 @given(depth=st.integers(0, 3), e=st.integers(1, 512))

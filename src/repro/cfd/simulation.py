@@ -23,6 +23,7 @@ import dataclasses
 import functools
 import math
 import os
+import sys
 import threading
 import time
 import warnings
@@ -60,7 +61,10 @@ class ChainResult:
     checksums: Dict[str, float]
     plan: Optional[memchain.ChainPlan] = None
     #: full chain outputs, qualified "stage.output" (collect_outputs=True):
-    #: one contiguous array per stream, assembled in place as batches retire
+    #: one contiguous array per stream, assembled in place as batches
+    #: retire.  A later call may write into these arrays again once
+    #: nothing but the output pool references them: keep the result, the
+    #: dict or any view of an array to keep its bytes.
     outputs: Optional[Dict[str, np.ndarray]] = None
     #: whether stages were cross-batch pipelined (one dispatch ring per
     #: stage) or run back-to-back per batch (the serial baseline)
@@ -93,21 +97,54 @@ def _copy_executor() -> concurrent.futures.ThreadPoolExecutor:
         return _copy_pool
 
 
+#: the output arrays the last completed collecting call returned, which
+#: the next call writes into again once nothing else references them
+_out_pool: List[np.ndarray] = []
+_out_pool_lock = threading.Lock()
+
+
+def _sole_ref_count() -> int:
+    """What ``sys.getrefcount`` reads for an object that only one list
+    slot holds, read through that slot."""
+    held = [object()]
+    return sys.getrefcount(held[0])
+
+
+_SOLE_REF = _sole_ref_count()
+
+
+def _take_pooled(shape: Tuple[int, ...], dtype) -> Optional[np.ndarray]:
+    """Remove from the pool and return an array of exactly ``shape`` and
+    ``dtype`` that nothing outside the pool references (no result, dict
+    or view of it is alive), or None where there is none."""
+    with _out_pool_lock:
+        for i in range(len(_out_pool)):
+            if (_out_pool[i].shape == shape and _out_pool[i].dtype == dtype
+                    and sys.getrefcount(_out_pool[i]) == _SOLE_REF):
+                return _out_pool.pop(i)
+    return None
+
+
 class _OutputAssembly:
     """One call's collected outputs, assembled in place as batches retire.
 
     :meth:`put` (on the dispatch thread, after a batch's transfer) hands
     the batch's host arrays to the copy workers, which write them into
-    rows ``[k*E, (k+1)*E)`` of one array per output stream, allocated
-    when the first batch arrives; the batch's arrays are released as its
-    copies finish.  :meth:`join` waits for the copies still in flight;
-    :meth:`abort` cancels or waits for them when the call fails, so no
-    worker outlives the call."""
+    rows ``[k*E, (k+1)*E)`` of one array per output stream, chosen when
+    the first batch arrives: an array an earlier call returned that
+    nobody holds any more (:func:`_take_pooled`, already faulted in),
+    else a new one; the batch's arrays are released as its copies
+    finish.  :meth:`join` waits for the copies still in flight and makes
+    the call's outputs the pool; :meth:`abort` cancels or waits for them
+    when the call fails, so no worker outlives the call, and leaves the
+    pool as it was."""
 
     def __init__(self, n_batches: int, batch_elements: int) -> None:
         self.n = n_batches
         self.E = batch_elements
         self.outputs: Optional[Dict[str, np.ndarray]] = None
+        #: per output stream, the bytes of its array taken from the pool
+        self.reused_bytes: Dict[str, float] = {}
         #: (qualified output, bytes, future) per copy task
         self._copies: List[Tuple[str, int, concurrent.futures.Future]] = []
 
@@ -115,10 +152,14 @@ class _OutputAssembly:
         E = self.E
         got = {q: np.asarray(v) for q, v in got.items()}
         if self.outputs is None:
-            self.outputs = {
-                q: np.empty((self.n * E,) + v.shape[1:], v.dtype)
-                for q, v in got.items()
-            }
+            self.outputs = {}
+            for q, v in got.items():
+                shape = (self.n * E,) + v.shape[1:]
+                out = _take_pooled(shape, v.dtype)
+                self.reused_bytes[q] = float(out.nbytes if out is not None
+                                             else 0)
+                self.outputs[q] = (out if out is not None
+                                   else np.empty(shape, v.dtype))
         pool = _copy_executor()
         for q, v in got.items():
             dst = self.outputs[q][k * E:(k + 1) * E]
@@ -136,11 +177,15 @@ class _OutputAssembly:
         return done
 
     def join(self) -> Dict[str, np.ndarray]:
-        """Wait for every copy; returns the assembled outputs."""
+        """Wait for every copy; returns the assembled outputs, which
+        become the pool in place of what it held."""
         concurrent.futures.wait([f for _, _, f in self._copies])
         copies, self._copies = self._copies, []
         for _, _, f in copies:
             f.result()  # a failed copy raises here
+        if self.outputs is not None:
+            with _out_pool_lock:
+                _out_pool[:] = self.outputs.values()
         return self.outputs
 
     def abort(self) -> None:
@@ -367,7 +412,14 @@ def run_chain(
     into the call's output rows runs on a pool of worker threads while
     the ring goes on; the end of the call waits for the copies still in
     flight.  Outputs and checksums are bitwise those of concatenating
-    the per-batch results.
+    the per-batch results.  The copies write into host memory already
+    faulted in where they can: the arrays the last completed collecting
+    call returned stay in a process-wide pool, and a stream takes one of
+    exactly its shape and dtype that nothing outside the pool references
+    any more -- a caller that keeps the ``ChainResult``, its ``outputs``
+    or any view of an array keeps that array's bytes, and the call gets
+    a new one.  Every row of a taken array is written again.  A call
+    that fails puts nothing in the pool.
 
     ``tracer`` (``repro.trace.Tracer``; None = off) records the full
     span hierarchy -- chain run -> per-stage slot -> dispatch/handoff --
@@ -389,7 +441,10 @@ def run_chain(
     flight when the ring drained.  As it begins, the
     ``host_out_hidden_bytes`` counter records, per output stream, the
     bytes whose copy had already finished -- the share of the copy that
-    the ring's own work hid.
+    the ring's own work hid.  When the first batch retires, the
+    ``host_out_reused_bytes`` counter records, per output stream, the
+    bytes of the call's output that went into an array taken from the
+    pool.
     """
     prepare = None
     if tracer:
@@ -398,6 +453,7 @@ def run_chain(
                                          COUNTER_CHANNEL_BYTES,
                                          COUNTER_HOST_OUT_BYTES,
                                          COUNTER_HOST_OUT_HIDDEN_BYTES,
+                                         COUNTER_HOST_OUT_REUSED_BYTES,
                                          COUNTER_OCCUPANCY,
                                          COUNTER_PAD_ELEMENTS,
                                          host_channel_bytes)
@@ -633,6 +689,9 @@ def run_chain(
             else:
                 got = jax.device_get(outs)
             assembly.put(k, got)
+            if tracer and k == 0:
+                tracer.bump(COUNTER_HOST_OUT_REUSED_BYTES,
+                            assembly.reused_bytes)
     else:
         reduce_fn = lambda live: {
             q: jnp.sum(live[q]) for q in out_names

@@ -47,6 +47,10 @@ COUNTER_HOST_OUT_BYTES = "host_out_bytes"
 #: of those, the bytes already copied into the call's outputs when the
 #: end-of-call join began, by qualified output name
 COUNTER_HOST_OUT_HIDDEN_BYTES = "host_out_hidden_bytes"
+#: of those, the bytes written into an output array an earlier call
+#: returned (taken from the output pool, not newly allocated), by
+#: qualified output name
+COUNTER_HOST_OUT_REUSED_BYTES = "host_out_reused_bytes"
 #: bytes each batch's stages take from inputs that already live on the
 #: device (no host link crossed), by qualified input name
 COUNTER_RESIDENT_IN_BYTES = "resident_in_bytes"

@@ -4,7 +4,9 @@ unchained reference, and the plan-driven Pallas block size."""
 import concurrent.futures
 import os
 import sys
+import threading
 import time
+import weakref
 
 import numpy as np
 import pytest
@@ -395,14 +397,12 @@ def test_collected_outputs_many_workers_tiny_blocks(cfd_chain, rng,
         assert got.outputs[q].tobytes() == want.outputs[q].tobytes(), q
 
 
-def test_collected_run_failure_waits_for_copies(cfd_chain, rng, monkeypatch):
-    """A stage that raises on batch 2 propagates its exception, and only
-    after every copy of the batches retired before it has ended."""
-    p, E, n_b = 5, 16, 4
+class Boom(RuntimeError):
+    pass
 
-    class Boom(RuntimeError):
-        pass
 
+def _fail_on_batch_2(monkeypatch):
+    """Make every run_chain call's last stage raise ``Boom`` on batch 2."""
     inner = simulation.chain_stage_fns
 
     def failing_stage_fns(*args, **kwargs):
@@ -418,8 +418,15 @@ def test_collected_run_failure_waits_for_copies(cfd_chain, rng, monkeypatch):
 
         return fns[:-1] + [run_stage]
 
-    ex = _DelayedExecutor(2, delay_s=0.02)
     monkeypatch.setattr(simulation, "chain_stage_fns", failing_stage_fns)
+
+
+def test_collected_run_failure_waits_for_copies(cfd_chain, rng, monkeypatch):
+    """A stage that raises on batch 2 propagates its exception, and only
+    after every copy of the batches retired before it has ended."""
+    p, E, n_b = 5, 16, 4
+    _fail_on_batch_2(monkeypatch)
+    ex = _DelayedExecutor(2, delay_s=0.02)
     monkeypatch.setattr(simulation, "_copy_executor", lambda: ex)
     monkeypatch.setattr(simulation, "_COPY_BLOCK_BYTES", 4 * 4 * p ** 3)
     inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
@@ -434,6 +441,189 @@ def test_collected_run_failure_waits_for_copies(cfd_chain, rng, monkeypatch):
         assert all(f.done() for f in ex.futures)
     finally:
         ex.pool.shutdown(wait=True)
+
+
+# ---------------------------------------------------------------------------
+# the output pool: a call writes into arrays an earlier call returned once
+# nothing else holds them
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def out_pool(monkeypatch):
+    """An empty output pool of the test's own."""
+    pool = []
+    monkeypatch.setattr(simulation, "_out_pool", pool)
+    return pool
+
+
+def _data_ptrs(arrays):
+    return sorted(a.__array_interface__["data"][0] for a in arrays)
+
+
+def _collect(chain, plan, inputs, shared):
+    return simulation.run_chain(chain, plan, inputs=inputs, shared=shared,
+                                collect_outputs=True)
+
+
+@pytest.mark.parametrize("second", ["same_inputs", "other_inputs_nan_filled"])
+def test_collected_outputs_reuse_dropped_arrays(cfd_chain, rng, monkeypatch,
+                                                out_pool, second):
+    """Once the first result is dropped, a second call of the same shape
+    writes into the first call's arrays, and into every row of them: its
+    outputs and checksums are bitwise the concatenated reference's, also
+    where the reused arrays held NaN and the inputs changed."""
+    p, E, n_b = 5, 16, 5
+    # 3 rows a copy task: every batch in 6 blocks, the last a remainder
+    monkeypatch.setattr(simulation, "_COPY_BLOCK_BYTES", 3 * 4 * p ** 3)
+    plan = _collect_plan(cfd_chain, E, E * n_b)
+    inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
+    first = _collect(cfd_chain, plan, inputs, shared)
+    ptrs = _data_ptrs(first.outputs.values())
+    refs = [weakref.ref(v) for v in first.outputs.values()]
+    del first
+    if second == "other_inputs_nan_filled":
+        for i in range(len(out_pool)):
+            out_pool[i].fill(np.nan)
+        inputs = _chain_inputs(cfd_chain, E * n_b, p, rng)[0]
+    res = _collect(cfd_chain, plan, inputs, shared)
+    assert _data_ptrs(res.outputs.values()) == ptrs
+    assert sorted(id(r()) for r in refs) == sorted(map(id,
+                                                       res.outputs.values()))
+    ref = _unchained_per_batch(cfd_chain, inputs, shared, E, n_b)
+    for q, parts in ref.items():
+        want = np.concatenate(parts)
+        assert res.outputs[q].tobytes() == want.tobytes(), q
+        assert res.checksums[q] == float(np.sum(want, dtype=np.float64)), q
+
+
+@pytest.mark.parametrize("keep", ["result", "slice"])
+def test_collected_outputs_kept_by_the_caller_are_not_reused(cfd_chain, rng,
+                                                             out_pool, keep):
+    """A caller that keeps the first result, or only a slice of one of
+    its outputs, keeps those bytes: the next call writes elsewhere, and
+    into the arrays nobody kept."""
+    p, E, n_b = 5, 16, 3
+    plan = _collect_plan(cfd_chain, E, E * n_b)
+    inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
+    first = _collect(cfd_chain, plan, inputs, shared)
+    before = {q: v.tobytes() for q, v in first.outputs.items()}
+    ptrs = set(_data_ptrs(first.outputs.values()))
+    q0 = sorted(first.outputs)[0]
+    if keep == "result":
+        kept = dict(first.outputs)
+    else:
+        kept = {q0: first.outputs[q0][3:7]}
+        before = {q0: first.outputs[q0][3:7].tobytes()}
+    del first
+    res = _collect(cfd_chain, plan,
+                   _chain_inputs(cfd_chain, E * n_b, p, rng)[0], shared)
+    for q, v in kept.items():
+        assert v.tobytes() == before[q], q
+        assert not any(np.shares_memory(v, w) for w in res.outputs.values())
+    reused = ptrs & set(_data_ptrs(res.outputs.values()))
+    assert len(reused) == (0 if keep == "result" else 2)
+
+
+@pytest.mark.parametrize("change", ["shape", "dtype"])
+def test_output_pool_misses_on_shape_or_dtype(out_pool, change):
+    """Another shape or dtype allocates new arrays; the pool then holds
+    the newest call's arrays and nothing else."""
+    E = 4
+
+    def assemble(n, dtype):
+        asm = simulation._OutputAssembly(n, E)
+        for k in range(n):
+            asm.put(k, {"s.a": np.full((E, 3), k, dtype),
+                        "s.b": np.full((E, 2, 2), -k, dtype)})
+        return asm.join(), asm.reused_bytes
+
+    first, reused = assemble(2, np.float32)
+    assert reused == {"s.a": 0.0, "s.b": 0.0}
+    old = set(_data_ptrs(first.values()))
+    del first
+    second, reused = assemble(*((3, np.float32) if change == "shape"
+                                else (2, np.float64)))
+    assert reused == {"s.a": 0.0, "s.b": 0.0}
+    assert not old & set(_data_ptrs(second.values()))
+    assert sorted(map(id, out_pool)) == sorted(map(id, second.values()))
+    n = len(second["s.a"]) // E
+    assert np.array_equal(second["s.a"][:, 0], np.repeat(np.arange(n), E))
+
+
+def test_failed_call_puts_nothing_in_the_output_pool(cfd_chain, rng,
+                                                     monkeypatch, out_pool):
+    """A call that fails after taking the pooled arrays and writing part
+    of them returns none of them to the pool."""
+    p, E, n_b = 5, 16, 4
+    plan = _collect_plan(cfd_chain, E, E * n_b)
+    inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
+    first = _collect(cfd_chain, plan, inputs, shared)
+    pooled = sorted(map(id, first.outputs.values()))
+    del first
+    taken = []
+    abort = simulation._OutputAssembly.abort
+
+    def recording_abort(self):
+        taken.extend(map(id, self.outputs.values()))
+        abort(self)
+
+    monkeypatch.setattr(simulation._OutputAssembly, "abort", recording_abort)
+    _fail_on_batch_2(monkeypatch)
+    with pytest.raises(Boom, match="batch 2"):
+        _collect(cfd_chain, plan, inputs, shared)
+    assert sorted(taken) == pooled  # batches 0 and 1 went into them
+    assert out_pool == []
+
+
+def test_concurrent_calls_never_share_an_output_array(cfd_chain, rng,
+                                                      out_pool):
+    """Two calls in flight at once, with one call's worth of arrays in
+    the pool, each get arrays of their own and the right bytes."""
+    p, E, n_b = 5, 16, 3
+    plan = _collect_plan(cfd_chain, E, E * n_b)
+    inputs, shared = _chain_inputs(cfd_chain, E * n_b, p, rng)
+    first = _collect(cfd_chain, plan, inputs, shared)
+    want = {q: v.tobytes() for q, v in first.outputs.items()}
+    del first
+    with concurrent.futures.ThreadPoolExecutor(2) as ex:
+        futures = [ex.submit(_collect, cfd_chain, plan, inputs, shared)
+                   for _ in range(2)]
+        results = [f.result(timeout=300) for f in futures]
+    arrays = [v for res in results for v in res.outputs.values()]
+    assert len(set(_data_ptrs(arrays))) == len(arrays) == 6
+    for i, a in enumerate(arrays):
+        assert not any(np.shares_memory(a, b) for b in arrays[i + 1:])
+    for res in results:
+        for q, v in res.outputs.items():
+            assert v.tobytes() == want[q], q
+
+
+def test_pooled_array_is_taken_once(out_pool):
+    """Stress: more threads than cores race to take from the pool, with a
+    short switch interval; none gets an array another got."""
+    threads = 2 * (os.cpu_count() or 1) + 1
+    out_pool.extend(np.empty((8, 3), np.float32) for _ in range(4 * threads))
+    barrier = threading.Barrier(threads)
+    keep = []  # every taken array stays alive, so no id is used twice
+
+    def take_all():
+        barrier.wait(timeout=60)
+        got = []
+        while (a := simulation._take_pooled((8, 3), np.float32)) is not None:
+            got.append(id(a))
+            keep.append(a)
+        return got
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with concurrent.futures.ThreadPoolExecutor(threads) as ex:
+            futures = [ex.submit(take_all) for _ in range(threads)]
+            ids = [i for f in futures for i in f.result(timeout=60)]
+    finally:
+        sys.setswitchinterval(interval)
+    assert len(ids) == len(set(ids)) == 4 * threads and out_pool == []
 
 
 def test_run_chain_warns_on_backend_mismatch(cfd_chain, rng):

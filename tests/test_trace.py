@@ -30,7 +30,8 @@ from repro.trace.attribution import (
     CAT_CHECKSUM, CAT_CONCAT, CAT_DISPATCH, CAT_PREPARE, CAT_RETIRE,
     CAT_SLICE, CAT_SLOT, CAT_SYNC, CAT_TRANSFER, CAT_WAIT,
     COUNTER_CHANNEL_BYTES, COUNTER_HOST_OUT_BYTES,
-    COUNTER_HOST_OUT_HIDDEN_BYTES, COUNTER_OCCUPANCY,
+    COUNTER_HOST_OUT_HIDDEN_BYTES, COUNTER_HOST_OUT_REUSED_BYTES,
+    COUNTER_OCCUPANCY,
     COUNTER_RESIDENT_IN_BYTES, COUNTER_RESIDENT_IN_PLACE, host_channel_bytes,
 )
 
@@ -341,6 +342,31 @@ def test_host_out_hidden_bytes_counts_finished_copies(cfd_chain, monkeypatch,
     assert res.batches == 4
     want = out if copies == "inline" else {q: 0.0 for q in out}
     assert hidden == want
+
+
+def test_host_out_reused_bytes_on_a_second_call(cfd_chain, monkeypatch):
+    """The first call of a shape reuses nothing; a second one, after the
+    first result was dropped, writes every byte it brings back into the
+    first call's arrays."""
+    monkeypatch.setattr(simulation, "_out_pool", [])
+    plan = mchain.plan_chain(
+        cfd_chain, target=channels.ALVEO_U280, batch_elements=64,
+        prefetch_depth=1, n_eq=256,
+    )
+    inputs, shared = _chain_data(cfd_chain, 256, np.random.default_rng(9))
+    totals = []
+    for _ in range(2):
+        tracer = trace.Tracer()
+        run_chain(cfd_chain, plan, inputs=inputs, shared=shared, n_eq=256,
+                  max_batches=4, pipeline_stages=True, tracer=tracer,
+                  collect_outputs=True)
+        assert len([c for c in tracer.counters
+                    if c.name == COUNTER_HOST_OUT_REUSED_BYTES]) == 1
+        totals.append((tracer.totals(COUNTER_HOST_OUT_BYTES),
+                       tracer.totals(COUNTER_HOST_OUT_REUSED_BYTES)))
+    (_, first), (out, second) = totals
+    assert not any(first.values())
+    assert second == out and len(out) == 3
 
 
 def test_collected_run_is_lifo_and_schema_valid(collected_run):
